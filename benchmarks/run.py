@@ -23,10 +23,10 @@ import json
 import sys
 import time
 
-# 8 virtual CPU devices (merged into XLA_FLAGS before the first jax
-# import; an explicit device count in the env is respected) so the
-# serve_slo entry can sweep mesh sizes up to 8 on a CPU-only runner
-from repro.launch.xla_env import force_host_device_count
+# 8 virtual CPU devices on a CPU-only run (merged into XLA_FLAGS before
+# the first jax import; an explicit device count in the env is respected)
+# so the serve_slo entry can sweep mesh sizes up to 8
+from repro.launch.xla_env import force_host_device_count, setup_compile_cache
 
 force_host_device_count(8)
 
@@ -81,6 +81,7 @@ def main(argv=None) -> None:
                     help="write rows + variant dispatch/flops records "
                          "as JSON (the BENCH_pipelines.json baseline)")
     args = ap.parse_args(argv)
+    setup_compile_cache()
     print("name,us_per_call,derived,unit")
     t0 = time.time()
     ran = []

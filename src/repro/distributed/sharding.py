@@ -15,15 +15,10 @@ from jax.sharding import PartitionSpec as P
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """Version-portable shard_map: jax.shard_map (newer releases, with its
-    ``check_vma`` knob) or jax.experimental.shard_map (``check_rep``)."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
+    """``jax.shard_map`` with the repo's default of no varying-manual-axes
+    check."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 # logical axis -> tuple of candidate mesh axes (first present ones used)
 DEFAULT_RULES: dict[str, tuple[str, ...]] = {

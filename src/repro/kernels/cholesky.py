@@ -22,33 +22,30 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import interpret_default
+from repro.kernels.common import (interpret_default, iota, put_col,
+                                  take_col, take_row)
 
 
 def _cholesky_kernel(a_ref, l_ref, *, n: int):
     a = a_ref[0]
-    rows = jax.lax.broadcasted_iota(jnp.int32, (n,), 0)
+    rows = iota((n, 1), 0)
 
     def outer(k, a):
         # ---- point region (non-critical: rsqrt) ----
-        akk = a[k, k]
-        inv = jax.lax.rsqrt(akk)
+        colk = take_col(a, k)
+        inv = jax.lax.rsqrt(take_row(colk, k))
         # ---- vector region: scale column k below the diagonal ----
-        col = a[:, k] * inv
-        col = jnp.where(rows >= k, col, 0.0)      # implicit mask (F4)
+        col = jnp.where(rows >= k, colk * inv, 0.0)  # implicit mask (F4)
         # ---- matrix region (critical): masked rank-1 update ----
         # inductive domain: rows>k & cols>k — the RI stream's mask
         live = rows > k
-        upd = col[:, None] * col[None, :]
-        mask = live[:, None] & live[None, :]
-        a = a - jnp.where(mask, upd, 0.0)
+        mask = live & (iota((1, n), 1) > k)
+        a = a - jnp.where(mask, col * col.T, 0.0)
         # write the finished L column back (ordered dep to next k)
-        a = a.at[:, k].set(jnp.where(rows >= k, col, a[:, k]))
-        return a
+        return put_col(a, k, jnp.where(rows >= k, col, colk))
 
     a = jax.lax.fori_loop(0, n, outer, a)
-    tri = rows[:, None] >= rows[None, :]
-    l_ref[0] = jnp.where(tri, a, 0.0)
+    l_ref[0] = jnp.where(rows >= iota((1, n), 1), a, 0.0)
 
 
 def cholesky_pallas(a: jax.Array, *, interpret: bool | None = None

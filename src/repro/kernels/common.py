@@ -16,10 +16,11 @@ import functools
 import os
 
 import jax
-from jax.experimental.pallas import tpu as pltpu
+import jax.numpy as jnp
 
 __all__ = ["interpret_default", "on_tpu", "resolve_backend", "cdiv",
-           "round_up", "tpu_compiler_params", "sample_spd"]
+           "round_up", "sample_spd", "iota", "eye", "take_col", "take_row",
+           "put_col", "put_row", "dot"]
 
 
 def sample_spd(rng, b: int, n: int):
@@ -29,31 +30,79 @@ def sample_spd(rng, b: int, n: int):
     a = rng.standard_normal((b, n, n)).astype(np.float32)
     return a @ a.swapaxes(-1, -2) + n * np.eye(n, dtype=np.float32)
 
-# jax renamed TPUCompilerParams -> CompilerParams across 0.4/0.5; resolve
-# whichever this jaxlib ships so kernels stay version-portable.
-_COMPILER_PARAMS_CLS = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
-
-
-def tpu_compiler_params(**kwargs):
-    """Version-portable pltpu compiler-params constructor."""
-    return _COMPILER_PARAMS_CLS(**kwargs)
-
 
 @functools.cache
 def on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except RuntimeError:
-        return False
+    """True when JAX's default backend is a TPU.  A backend that fails to
+    initialise raises here rather than reading as "not a TPU"."""
+    return jax.devices()[0].platform == "tpu"
 
 
 def interpret_default() -> bool:
-    """Pallas interpret mode unless we are actually on TPU."""
+    """Pallas interpret mode exactly when the backend is not a TPU.
+
+    ``REPRO_PALLAS_INTERPRET`` may choose the mode off a TPU; asking for
+    interpret mode on a TPU is an error, because it would silently run
+    every kernel in the Python interpreter instead of on the chip.
+    """
     env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return not on_tpu()
+    forced = env is not None and env not in ("0", "false", "False")
+    if on_tpu():
+        if forced:
+            raise RuntimeError(
+                "REPRO_PALLAS_INTERPRET asks for interpret mode on a TPU "
+                "backend; unset it to run the kernels natively")
+        return False
+    return forced or env is None
+
+
+# ---------------------------------------------------------------------------
+# In-kernel helpers the TPU compiler (Mosaic) can lower.  Mosaic cannot
+# slice a VALUE at a traced offset (``a[k, k]``, ``a[:, k]``,
+# ``.at[:, k].set``), which is exactly what the fine-grain step functions
+# do at their loop-carried index.  These read and write one row or column
+# as a masked reduction / select over 2-D iotas instead.  A read sums one
+# selected term and zeros, so it returns the element bit for bit.
+# ---------------------------------------------------------------------------
+
+def iota(shape, dim: int):
+    """int32 index along ``dim`` (TPU iotas must be at least 2-D)."""
+    return jax.lax.broadcasted_iota(jnp.int32, shape, dim)
+
+
+def eye(n: int):
+    """float32 identity built from iotas (no constant array in VMEM)."""
+    return (iota((n, n), 0) == iota((n, n), 1)).astype(jnp.float32)
+
+
+def take_col(a, k):
+    """``a[:, k:k+1]`` for a traced ``k``: shape (r, 1)."""
+    return jnp.sum(jnp.where(iota(a.shape, 1) == k, a, 0.0), axis=1,
+                   keepdims=True)
+
+
+def take_row(a, k):
+    """``a[k:k+1, :]`` for a traced ``k``: shape (1, c)."""
+    return jnp.sum(jnp.where(iota(a.shape, 0) == k, a, 0.0), axis=0,
+                   keepdims=True)
+
+
+def put_col(a, k, col):
+    """``a.at[:, k].set(col)`` for a traced ``k``; ``col`` is (r, 1)."""
+    return jnp.where(iota(a.shape, 1) == k, col, a)
+
+
+def put_row(a, k, row):
+    """``a.at[k, :].set(row)`` for a traced ``k``; ``row`` is (1, c)."""
+    return jnp.where(iota(a.shape, 0) == k, row, a)
+
+
+def dot(a, b):
+    """In-kernel float32 matmul at full precision.  Without ``HIGHEST``
+    the TPU may feed the MXU bfloat16-rounded operands, whose ~3
+    significant digits the solvers' float32 tolerances do not allow."""
+    return jnp.dot(a, b, precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
 
 
 def resolve_backend(backend: str | None) -> str:

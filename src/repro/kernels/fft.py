@@ -9,15 +9,13 @@ ordered dependence chain — stage s+1 consumes everything stage s
 produced — so it stays inside one kernel rather than round-tripping HBM
 per stage.
 
-Twiddle storage is CHUNKED: stage ``s`` only has ``2**s`` distinct
-twiddles (w_span^off for off < span/2), so the table packs stage ``s``
-at offset ``2**s - 1`` for a total of ``n - 1`` complex entries.  The
-old layout materialized all ``stages * n/2`` repeated entries plus two
-equally-sized butterfly index tables — at the paper's 1024-point size
-that is ~11x the VMEM footprint, which is what capped the registered
-sizes at 128.  Butterfly partners and per-stage twiddle offsets are now
-recomputed in-kernel from an iota with shift/mask arithmetic (a pattern
-state machine, not a stored stream).
+The twiddle table is CHUNKED: stage ``s`` only has ``2**s`` distinct
+twiddles (w_span^off for off < span/2), so ``fft_tables`` packs stage
+``s`` at offset ``2**s - 1`` for a total of ``n - 1`` complex entries.
+The TPU compiler lowers no in-kernel gather, so ``stage_twiddles``
+expands it host-side into one row per stage (``stages x n`` floats,
+40 KiB per plane at n = 1024), and the kernel finds each butterfly
+partner by a static lane rotation of the signal.
 """
 from __future__ import annotations
 
@@ -55,67 +53,74 @@ def fft_tables(n: int):
     return rev, w_re, w_im
 
 
-def _fft_kernel(xr_ref, xi_ref, rev_ref, wr_ref, wi_ref, or_ref, oi_ref,
-                *, n: int, stages: int):
-    rev = rev_ref[...]
-    xr = jnp.take(xr_ref[0], rev)
-    xi = jnp.take(xi_ref[0], rev)
-    b_idx = jax.lax.broadcasted_iota(jnp.int32, (n // 2,), 0)
+def stage_twiddles(n: int):
+    """Per-lane twiddle rows (stages, n), re and im: row ``s`` holds, at
+    lane ``i``, the stage-``s`` twiddle of ``i``'s butterfly, expanded
+    host-side from the chunked table so the kernel reads it with no
+    gather (the TPU compiler lowers no in-kernel gather)."""
+    stages = int(np.log2(n))
+    _, w_re, w_im = fft_tables(n)
+    lanes = np.arange(n)
+    idx = np.stack([(1 << s) - 1 + (lanes & ((1 << s) - 1))
+                    for s in range(stages)])
+    return w_re[idx], w_im[idx]
 
-    def stage(s, x):
-        xr, xi = x
-        half = jnp.left_shift(1, s)
-        off = jnp.bitwise_and(b_idx, half - 1)
-        # butterfly partners: i = (b >> s) << (s+1) | off, j = i + half
-        ii = jnp.left_shift(jnp.right_shift(b_idx, s), s + 1) + off
-        jj = ii + half
-        # chunked twiddle gather: stage s lives at offset 2**s - 1
-        widx = (half - 1) + off
-        wr = jnp.take(wr_ref[...], widx)
-        wi = jnp.take(wi_ref[...], widx)
-        ur, ui = jnp.take(xr, ii), jnp.take(xi, ii)
-        vr, vi = jnp.take(xr, jj), jnp.take(xi, jj)
+
+def _fft_kernel(xr_ref, xi_ref, wr_ref, wi_ref, or_ref, oi_ref, *, n: int,
+                stages: int):
+    xr = xr_ref[0]                        # (1, n), bit-reversed order
+    xi = xi_ref[0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
+    # The stage loop is unrolled: each stage's butterfly span is then a
+    # static lane rotation.  Lane i pairs with i + half when bit s of i
+    # is clear (the butterfly's top u) and with i - half when it is set
+    # (its bottom v); both ends share the twiddle of row s.
+    for s in range(stages):
+        half = 1 << s
+        bottom = jnp.bitwise_and(lane, half) != 0
+        wr = wr_ref[s:s + 1, :]
+        wi = wi_ref[s:s + 1, :]
+        ur = jnp.where(bottom, jnp.roll(xr, half, axis=1), xr)
+        ui = jnp.where(bottom, jnp.roll(xi, half, axis=1), xi)
+        vr = jnp.where(bottom, xr, jnp.roll(xr, n - half, axis=1))
+        vi = jnp.where(bottom, xi, jnp.roll(xi, n - half, axis=1))
         # twiddle multiply (critical vector region)
         tr = wr * vr - wi * vi
         ti = wr * vi + wi * vr
-        xr = xr.at[ii].set(ur + tr).at[jj].set(ur - tr)
-        xi = xi.at[ii].set(ui + ti).at[jj].set(ui - ti)
-        return xr, xi
-
-    xr, xi = jax.lax.fori_loop(0, stages, stage, (xr, xi))
+        xr = jnp.where(bottom, ur - tr, ur + tr)
+        xi = jnp.where(bottom, ui - ti, ui + ti)
     or_ref[0] = xr
     oi_ref[0] = xi
 
 
 def fft_pallas(x_re: jax.Array, x_im: jax.Array, *,
                interpret: bool | None = None):
-    """(B, N) re/im -> (re, im) of the DFT.  VMEM per lane is O(N)
-    (signal + bit-reversal + chunked twiddles), so the paper's
-    1024-point size stays resident."""
+    """(B, N) re/im -> (re, im) of the DFT.  VMEM per lane is O(N log N)
+    (signal + per-stage twiddle rows), so the paper's 1024-point size
+    stays resident.  The bit-reversal permutation is applied to the
+    input by XLA on the way in."""
     b, n = x_re.shape
     stages = int(np.log2(n))
-    rev, wr, wi = fft_tables(n)
+    rev, _, _ = fft_tables(n)
+    wr, wi = stage_twiddles(n)
     if interpret is None:
         interpret = interpret_default()
-    row = lambda i: (i, 0)          # noqa: E731
-    tab = lambda i: (0,)            # noqa: E731
-    return pl.pallas_call(
+    # unit middle axis: a (1, n) block of a (B, n) array breaks the
+    # TPU's (8, 128) block rule, a (1, 1, n) block of (B, 1, n) does not
+    planes = lambda x: jnp.take(x, jnp.asarray(rev), axis=1)[:, None, :]
+    row = pl.BlockSpec((1, 1, n), lambda i: (i, 0, 0),
+                       memory_space=pltpu.VMEM)
+    tab = pl.BlockSpec((stages, n), lambda i: (0, 0),
+                       memory_space=pltpu.VMEM)
+    fr, fi = pl.pallas_call(
         functools.partial(_fft_kernel, n=n, stages=stages),
         grid=(b,),
-        in_specs=[
-            pl.BlockSpec((1, n), row, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, n), row, memory_space=pltpu.VMEM),
-            pl.BlockSpec((n,), tab, memory_space=pltpu.VMEM),
-            pl.BlockSpec((max(n - 1, 1),), tab, memory_space=pltpu.VMEM),
-            pl.BlockSpec((max(n - 1, 1),), tab, memory_space=pltpu.VMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, n), row, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, n), row, memory_space=pltpu.VMEM),
-        ],
+        in_specs=[row, row, tab, tab],
+        out_specs=[row, row],
         out_shape=[
-            jax.ShapeDtypeStruct((b, n), x_re.dtype),
-            jax.ShapeDtypeStruct((b, n), x_im.dtype),
+            jax.ShapeDtypeStruct((b, 1, n), x_re.dtype),
+            jax.ShapeDtypeStruct((b, 1, n), x_im.dtype),
         ],
         interpret=interpret,
-    )(x_re, x_im, jnp.asarray(rev), jnp.asarray(wr), jnp.asarray(wi))
+    )(planes(x_re), planes(x_im), jnp.asarray(wr), jnp.asarray(wi))
+    return fr[:, 0], fi[:, 0]

@@ -14,7 +14,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import cdiv, interpret_default, tpu_compiler_params
+from repro.kernels.common import cdiv, interpret_default
 
 
 def _gemm_kernel(x_ref, y_ref, o_ref, acc_ref, *, k_steps: int):
@@ -56,7 +56,7 @@ def gemm_pallas(x: jax.Array, y: jax.Array, *, bm: int = 128, bn: int = 128,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

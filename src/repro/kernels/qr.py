@@ -15,40 +15,39 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import interpret_default
+from repro.kernels.common import (eye, interpret_default, iota, take_col,
+                                  take_row)
 
 
 def _qr_kernel(a_ref, q_ref, r_ref, *, m: int, n: int):
     r = a_ref[0]
-    q = jnp.eye(m, dtype=r.dtype)
-    rows = jax.lax.broadcasted_iota(jnp.int32, (m,), 0)
+    rows = iota((m, 1), 0)
+    q = eye(m)
 
     def outer(k, carry):
         q, r = carry
         # ---- householder region (non-critical: norm, sqrt, div) ----
-        x = jnp.where(rows >= k, r[:, k], 0.0)          # masked column
-        xk = r[k, k]
-        sigma = jnp.sum(x * x)
+        x = jnp.where(rows >= k, take_col(r, k), 0.0)   # masked column
+        xk = take_row(x, k)
+        sigma = jnp.sum(x * x, axis=0, keepdims=True)
         norm = jnp.sqrt(sigma)
         alpha = jnp.where(xk >= 0, -norm, norm)
         v = x - alpha * (rows == k).astype(r.dtype)
-        vnorm2 = jnp.maximum(jnp.sum(v * v), 1e-30)
+        vnorm2 = jnp.maximum(jnp.sum(v * v, axis=0, keepdims=True), 1e-30)
         tau = 2.0 / vnorm2
         # degenerate column: no reflection
         tau = jnp.where(norm < 1e-30, 0.0, tau)
-        # ---- critical region 1: R update (MXU: v^T R then outer) ----
-        w = tau * (v @ r)                                # (n,)
-        r = r - v[:, None] * w[None, :]
+        # ---- critical region 1: R update (v^T R then outer) ----
+        w = tau * jnp.sum(v * r, axis=0, keepdims=True)  # (1, n)
+        r = r - v * w
         # ---- critical region 2: Q accumulation ----
-        u = tau * (q @ v)                                # (m,)
-        q = q - u[:, None] * v[None, :]
+        u = tau * jnp.sum(q * v.T, axis=1, keepdims=True)  # (m, 1)
+        q = q - u * v.T
         return q, r
 
     q, r = jax.lax.fori_loop(0, min(n, m - 1) if m > 1 else 0, outer, (q, r))
-    rows_n = jax.lax.broadcasted_iota(jnp.int32, (m, n), 0)
-    cols_n = jax.lax.broadcasted_iota(jnp.int32, (m, n), 1)
     q_ref[0] = q
-    r_ref[0] = jnp.where(rows_n <= cols_n, r, 0.0)
+    r_ref[0] = jnp.where(iota((m, n), 0) <= iota((m, n), 1), r, 0.0)
 
 
 def qr_pallas(a: jax.Array, *, interpret: bool | None = None):

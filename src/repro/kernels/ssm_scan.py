@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import cdiv, interpret_default, tpu_compiler_params
+from repro.kernels.common import cdiv, interpret_default
 
 
 def _ssm_kernel(x_ref, a_ref, b_ref, c_ref, y_ref, hout_ref, h_ref, *,
@@ -117,7 +117,7 @@ def ssm_scan_pallas(x: jax.Array, a: jax.Array, b: jax.Array, c: jax.Array,
             jax.ShapeDtypeStruct((bs, h, n, p), x.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -18,30 +18,28 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import interpret_default
+from repro.kernels.common import eye, interpret_default, put_col, take_col
 
 
 def _rotate_pair(mat, p, q, cs, sn):
-    colp = jax.lax.dynamic_slice(mat, (0, p), (mat.shape[0], 1))
-    colq = jax.lax.dynamic_slice(mat, (0, q), (mat.shape[0], 1))
-    newp = cs * colp - sn * colq
-    newq = sn * colp + cs * colq
-    mat = jax.lax.dynamic_update_slice(mat, newp, (0, p))
-    return jax.lax.dynamic_update_slice(mat, newq, (0, q))
+    colp = take_col(mat, p)
+    colq = take_col(mat, q)
+    mat = put_col(mat, p, cs * colp - sn * colq)
+    return put_col(mat, q, sn * colp + cs * colq)
 
 
-def _svd_kernel(a_ref, u_ref, s_ref, v_ref, *, m: int, n: int, sweeps: int):
+def _svd_kernel(a_ref, u_ref, s_ref, v_ref, *, n: int, sweeps: int):
     a = a_ref[0].astype(jnp.float32)
-    v = jnp.eye(n, dtype=jnp.float32)
+    v = eye(n)
 
     def pair_body(p, q, av):
         a, v = av
-        colp = jax.lax.dynamic_slice(a, (0, p), (m, 1))[:, 0]
-        colq = jax.lax.dynamic_slice(a, (0, q), (m, 1))[:, 0]
+        colp = take_col(a, p)
+        colq = take_col(a, q)
         # ---- non-critical point region: rotation parameters ----
-        alpha = jnp.sum(colp * colp)
-        beta = jnp.sum(colq * colq)
-        gamma = jnp.sum(colp * colq)
+        alpha = jnp.sum(colp * colp, axis=0, keepdims=True)
+        beta = jnp.sum(colq * colq, axis=0, keepdims=True)
+        gamma = jnp.sum(colp * colq, axis=0, keepdims=True)
         small = jnp.abs(gamma) <= 1e-12 * jnp.sqrt(alpha * beta) + 1e-30
         zeta = (beta - alpha) / (2.0 * jnp.where(small, 1.0, gamma))
         t = jnp.sign(zeta) / (jnp.abs(zeta) + jnp.sqrt(1.0 + zeta * zeta))
@@ -63,8 +61,8 @@ def _svd_kernel(a_ref, u_ref, s_ref, v_ref, *, m: int, n: int, sweeps: int):
         return jax.lax.fori_loop(0, n - 1, outer, av)
 
     a, v = jax.lax.fori_loop(0, sweeps, sweep, (a, v))
-    s = jnp.sqrt(jnp.sum(a * a, axis=0))
-    u = a / jnp.maximum(s, 1e-30)[None, :]
+    s = jnp.sqrt(jnp.sum(a * a, axis=0, keepdims=True))     # (1, n)
+    u = a / jnp.maximum(s, 1e-30)
     u_ref[0] = u.astype(u_ref.dtype)
     s_ref[0] = s.astype(s_ref.dtype)
     v_ref[0] = v.astype(v_ref.dtype)
@@ -76,23 +74,26 @@ def svd_pallas(a: jax.Array, *, sweeps: int = 12,
     assert m >= n
     if interpret is None:
         interpret = interpret_default()
-    return pl.pallas_call(
-        functools.partial(_svd_kernel, m=m, n=n, sweeps=sweeps),
+    u, s, v = pl.pallas_call(
+        functools.partial(_svd_kernel, n=n, sweeps=sweeps),
         grid=(b,),
         in_specs=[pl.BlockSpec((1, m, n), lambda i: (i, 0, 0),
                                memory_space=pltpu.VMEM)],
         out_specs=[
             pl.BlockSpec((1, m, n), lambda i: (i, 0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, n), lambda i: (i, 0),
+            # unit middle axis: a (1, n) block of a (B, n) array breaks
+            # the TPU's (8, 128) block rule, a (1, 1, n) block does not
+            pl.BlockSpec((1, 1, n), lambda i: (i, 0, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, n, n), lambda i: (i, 0, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, m, n), a.dtype),
-            jax.ShapeDtypeStruct((b, n), a.dtype),
+            jax.ShapeDtypeStruct((b, 1, n), a.dtype),
             jax.ShapeDtypeStruct((b, n, n), a.dtype),
         ],
         interpret=interpret,
     )(a)
+    return u, s[:, 0], v

@@ -15,24 +15,25 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import interpret_default
+from repro.kernels.common import (interpret_default, iota, put_row,
+                                  take_col, take_row)
 
 
 def _trisolve_kernel(l_ref, b_ref, y_ref, *, n: int, lower: bool):
     l = l_ref[0]
     y = b_ref[0]                       # (n, m) rhs, solved in place
-    rows = jax.lax.broadcasted_iota(jnp.int32, (n,), 0)
+    rows = iota((n, 1), 0)
 
     def outer(i, y):
         k = i if lower else n - 1 - i
+        lcol = take_col(l, k)
         # point region: reciprocal of the pivot (non-critical)
-        inv = 1.0 / l[k, k]
-        yk = y[k] * inv                # (m,) — the produced value
-        y = y.at[k].set(yk)
+        inv = 1.0 / take_row(lcol, k)
+        yk = take_row(y, k) * inv      # (1, m) — the produced value
+        y = put_row(y, k, yk)
         # critical region: masked AXPY over the remaining rows
         live = (rows > k) if lower else (rows < k)
-        upd = l[:, k][:, None] * yk[None, :]
-        return y - jnp.where(live[:, None], upd, 0.0)
+        return y - jnp.where(live, lcol * yk, 0.0)
 
     y = jax.lax.fori_loop(0, n, outer, y)
     y_ref[0] = y

@@ -14,6 +14,12 @@ Usage:
   python -m repro.launch.dryrun --all --out results.jsonl   (driver mode:
       one subprocess per cell so XLA state/memory is isolated)
 """
+import os
+
+# A CPU-only tool on placeholder devices: it and the per-cell children it
+# spawns (which inherit this environment) never take an accelerator.
+os.environ["JAX_PLATFORMS"] = "cpu"
+
 from repro.launch.xla_env import force_host_device_count
 
 force_host_device_count(512)

@@ -3,19 +3,28 @@ module never touches jax device state)."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with ``Auto`` axes.  JAX 0.9 makes ``Explicit``
+    axes by default, under which ``with_sharding_constraint`` rejects the
+    logical-axis specs of :mod:`repro.distributed.sharding`."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_debug_mesh(n_data: int = 1, n_model: int = 1):
     """Small mesh for tests (requires xla_force_host_platform_device_count
     to be set by the test before first jax use)."""
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return make_mesh((n_data, n_model), ("data", "model"))
 
 
 def make_lane_mesh(n_shards: int, axis: str = "data"):

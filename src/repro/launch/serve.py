@@ -14,6 +14,8 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs import ARCHS, get_config, get_smoke
 from repro.distributed import sharding as shd
+from repro.launch.mesh import make_mesh
+from repro.launch.xla_env import setup_compile_cache
 from repro.models import transformer as T
 from repro.serve import DecodeEngine, Request
 
@@ -21,17 +23,19 @@ from repro.serve import DecodeEngine, Request
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCHS, default="phi4-mini-3.8b")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                    default=True)
     ap.add_argument("--mesh", default="1x1")
     ap.add_argument("--pool", type=int, default=4)
     ap.add_argument("--max-len", type=int, default=128)
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--max-new", type=int, default=16)
     args = ap.parse_args(argv)
+    setup_compile_cache()
 
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
     d, m = (int(x) for x in args.mesh.split("x"))
-    mesh = jax.make_mesh((d, m), ("data", "model"))
+    mesh = make_mesh((d, m), ("data", "model"))
 
     with shd.axis_rules(mesh, shd.SERVE_RULES):
         p_abs = jax.eval_shape(

@@ -49,10 +49,10 @@ import time
 
 import numpy as np
 
-# launcher module: 8 virtual CPU devices (merged into XLA_FLAGS before
-# the first jax import; an explicit device count in the env wins) so
-# --mesh N and run_sharded_overload work standalone on a CPU-only host
-from repro.launch.xla_env import force_host_device_count
+# launcher module: 8 virtual CPU devices on a CPU-only run (merged into
+# XLA_FLAGS before the first jax import; an explicit device count in the
+# env wins) so --mesh N and run_sharded_overload work standalone there
+from repro.launch.xla_env import force_host_device_count, setup_compile_cache
 
 force_host_device_count(8)
 
@@ -822,6 +822,7 @@ def main(argv=None):
                     help="virtual ticks in the --pusch / --decode trace")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    setup_compile_cache()
     if args.budget_us is not None and not args.policy:
         ap.error("--budget-us requires --policy")
     if args.fault_seed is not None and args.fault_trace is None:

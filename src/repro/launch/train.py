@@ -18,6 +18,8 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import ARCHS, get_config, get_smoke
+from repro.launch import mesh as mesh_lib
+from repro.launch.xla_env import setup_compile_cache
 from repro.data.pipeline import DataConfig, TokenPipeline
 from repro.distributed import sharding as shd
 from repro.models import transformer as T
@@ -32,7 +34,7 @@ log = logging.getLogger("repro.launch.train")
 def make_mesh(spec: str):
     """'DxM' -> mesh over (data, model); '1x1' works on one device."""
     d, m = (int(x) for x in spec.split("x"))
-    return jax.make_mesh((d, m), ("data", "model"))
+    return mesh_lib.make_mesh((d, m), ("data", "model"))
 
 
 def shardings_for(mesh, cfg, seq: int, batch: int):
@@ -120,6 +122,7 @@ def main(argv=None):
     ap.add_argument("--ckpt", default="/tmp/repro_launch_train")
     ap.add_argument("--ckpt-every", type=int, default=100)
     args = ap.parse_args(argv)
+    setup_compile_cache()
     out = run(args)
     ls = out["losses"]
     if ls:

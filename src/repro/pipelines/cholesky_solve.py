@@ -26,8 +26,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.cholesky import cholesky_pallas
-from repro.kernels.common import (interpret_default, resolve_backend,
-                                  tpu_compiler_params)
+from repro.kernels.common import (dot, interpret_default, iota, put_col,
+                                  put_row, resolve_backend, take_col,
+                                  take_row)
 from repro.kernels.trisolve import trisolve_pallas
 
 # Relative pivot threshold (LAPACK pstrf-style): a pivot below
@@ -38,75 +39,89 @@ from repro.kernels.trisolve import trisolve_pallas
 DEFAULT_EPS = 1e-5
 
 
-def pivot_threshold(a, rows, *, eps: float):
-    """Scale-relative deficiency threshold from the initial diagonal."""
-    diag = jnp.where(rows[:, None] == rows[None, :], a, -jnp.inf)
-    return jnp.maximum(eps * jnp.max(diag), 1e-30)
+def pivot_threshold(a, *, eps: float):
+    """Scale-relative deficiency threshold from the initial diagonal,
+    as a (1, 1) array."""
+    diag = jnp.where(iota(a.shape, 0) == iota(a.shape, 1), a, -jnp.inf)
+    return jnp.maximum(eps * jnp.max(diag, axis=(0, 1), keepdims=True),
+                       1e-30)
 
 
-def factor_forward_step(k, a, y, rows, thresh):
+def factor_forward_step(k, a, y, thresh):
     """One fused outer iteration: finish column k of L, then immediately
     run the forward-substitution step that consumes it.
 
     a: (n, n) working matrix (lower triangle -> L in place)
     y: (n, m) right-hand sides being forward-solved in place
-    thresh: scalar deficiency threshold (see pivot_threshold)
+    thresh: deficiency threshold (see pivot_threshold)
 
     A pivot below ``thresh`` takes the rank-deficient path: unit diagonal,
     zeroed column, zeroed solution component — the solve proceeds on the
     numerically non-deficient subspace and every lane stays finite.
     """
+    n = a.shape[0]
+    rows = iota((n, 1), 0)
     # ---- point region (non-critical): guarded rsqrt of the pivot ----
-    akk = a[k, k]
+    colk = take_col(a, k)
+    akk = take_row(colk, k)
     ok = akk > thresh
     inv = jnp.where(ok, jax.lax.rsqrt(jnp.maximum(akk, thresh)), 0.0)
     # ---- vector region: scale column k; diagonal set to the pivot ----
-    col = a[:, k] * inv
+    col = colk * inv
     col = jnp.where(rows == k, jnp.where(ok, akk * inv, 1.0), col)
     col = jnp.where(rows >= k, col, 0.0)              # implicit mask (F4)
     # ---- matrix region (critical): masked rank-1 trailing update ----
     live = rows > k
-    upd = col[:, None] * col[None, :]
-    mask = live[:, None] & live[None, :]
-    a = a - jnp.where(mask, upd, 0.0)
-    a = a.at[:, k].set(jnp.where(rows >= k, col, a[:, k]))
+    mask = live & (iota((1, n), 1) > k)
+    a = a - jnp.where(mask, col * col.T, 0.0)
+    a = put_col(a, k, jnp.where(rows >= k, col, colk))
     # ---- fused forward substitution consuming the finished column ----
     # y[k] /= l[k,k];  y[j>k] -= l[j,k] * y[k]   (divide + masked AXPY)
-    yk = y[k] * inv                                   # deficient: x_k = 0
-    y = y.at[k].set(yk)
-    y = y - jnp.where(live[:, None], col[:, None] * yk[None, :], 0.0)
+    yk = take_row(y, k) * inv                         # deficient: x_k = 0
+    y = put_row(y, k, yk)
+    y = y - jnp.where(live, col * yk, 0.0)
     return a, y
 
 
-def back_substitution_step(i, l, y, rows, *, n: int):
+def back_substitution_step(i, lt, y, *, n: int):
     """Back-substitution outer iteration on U = L^T, k = n-1-i:
-    x[k] = y[k] / l[k,k];  y[j<k] -= l[k,j] * x[k]."""
+    x[k] = y[k] / l[k,k];  y[j<k] -= l[k,j] * x[k].  ``lt`` is L
+    transposed once by the caller, so row k of L is read as a column."""
     k = n - 1 - i
-    xk = y[k] / l[k, k]                   # diagonal already >= sqrt(eps)
-    y = y.at[k].set(xk)
-    row = l[k, :]                         # l[k, j] valid for j <= k
-    return y - jnp.where(rows[:, None] < k, row[:, None] * xk[None, :], 0.0)
+    ucol = take_col(lt, k)                # l[k, j] valid for j <= k
+    xk = take_row(y, k) / take_row(ucol, k)   # diagonal >= sqrt(eps)
+    y = put_row(y, k, xk)
+    return y - jnp.where(iota((n, 1), 0) < k, ucol * xk, 0.0)
 
 
-def _cholesky_solve_kernel(a_ref, b_ref, x_ref, *l_refs, n: int,
-                           eps: float):
-    a = a_ref[0]
-    y = b_ref[0]
-    rows = jax.lax.broadcasted_iota(jnp.int32, (n,), 0)
-    # symmetrize from the lower triangle: the upper half is never read
-    # (garbage/NaN lanes there cannot leak into the solve)
-    tril = rows[:, None] >= rows[None, :]
-    a = jnp.where(tril, a, a.T)
-    thresh = pivot_threshold(a, rows, eps=eps)
-
+def chol_solve_inline(a, y, *, eps: float):
+    """Fused factor + forward + back substitution of an SPD (n, n)
+    system already resident in VMEM — the shared tail of every fused
+    kernel whose chain ends in a Cholesky solve.  Returns the factored
+    working matrix (L in its lower triangle) and the solution."""
+    n = a.shape[0]
+    thresh = pivot_threshold(a, eps=eps)
     a, y = jax.lax.fori_loop(
-        0, n,
-        lambda k, c: factor_forward_step(k, c[0], c[1], rows, thresh),
+        0, n, lambda k, c: factor_forward_step(k, c[0], c[1], thresh),
         (a, y))
+    lt = a.T
     y = jax.lax.fori_loop(
-        0, n, lambda i, y_: back_substitution_step(i, a, y_, rows, n=n), y)
+        0, n, lambda i, y_: back_substitution_step(i, lt, y_, n=n), y)
+    return a, y
+
+
+def symmetrize_lower(a):
+    """Mirror the lower triangle over the upper: the upper half is never
+    read, so garbage/NaN lanes there cannot leak into the solve."""
+    tril = iota(a.shape, 0) >= iota(a.shape, 1)
+    return jnp.where(tril, a, a.T)
+
+
+def _cholesky_solve_kernel(a_ref, b_ref, x_ref, *l_refs, eps: float):
+    a, y = chol_solve_inline(symmetrize_lower(a_ref[0]), b_ref[0], eps=eps)
     x_ref[0] = y
     if l_refs:                    # factor output requested (return_l)
+        tril = iota(a.shape, 0) >= iota(a.shape, 1)
         l_refs[0][0] = jnp.where(tril, a, 0.0)
 
 
@@ -134,7 +149,7 @@ def cholesky_solve_pallas(a: jax.Array, b: jax.Array, *,
                                       memory_space=pltpu.VMEM))
         out_shape.append(jax.ShapeDtypeStruct((bsz, n, n), a.dtype))
     out = pl.pallas_call(
-        functools.partial(_cholesky_solve_kernel, n=n, eps=eps),
+        functools.partial(_cholesky_solve_kernel, eps=eps),
         grid=(bsz,),
         in_specs=[
             pl.BlockSpec((1, n, n), lambda i: (i, 0, 0),
@@ -149,8 +164,7 @@ def cholesky_solve_pallas(a: jax.Array, b: jax.Array, *,
     return (out[0], out[1]) if return_l else out[0]
 
 
-def _panel_factor_forward_step(j, carry, *, o, n: int, m: int, rows,
-                               cols_bs, thresh):
+def _panel_factor_forward_step(j, carry, *, o, thresh):
     """One column of the blocked panel factor, fused with the forward
     substitution row it finishes (the blocked analog of
     ``factor_forward_step``).
@@ -158,86 +172,101 @@ def _panel_factor_forward_step(j, carry, *, o, n: int, m: int, rows,
     carry: (c, y) with c the full-height (n, bs) column slab [cols
     o..o+bs) of the working matrix] and y the (n, m) right-hand sides.
     ``g = o + j`` is the global pivot; the rank-1 update is confined to
-    the REMAINING slab columns (cols_bs > j) — trailing columns outside
+    the REMAINING slab columns (cols > j) — trailing columns outside
     the slab get their whole panel's contribution later in one SYRK.
     """
     c, y = carry
+    n, bs = c.shape
+    rows = iota((n, 1), 0)
+    cols = iota((1, bs), 1)
     g = o + j
-    col = jax.lax.dynamic_slice(c, (0, j), (n, 1))[:, 0]
-    pivot = jnp.take(col, g)
+    col = take_col(c, j)
+    pivot = take_row(col, g)
     ok = pivot > thresh
     inv = jnp.where(ok, jax.lax.rsqrt(jnp.maximum(pivot, thresh)), 0.0)
     newcol = col * inv
     newcol = jnp.where(rows == g, jnp.where(ok, pivot * inv, 1.0), newcol)
     newcol = jnp.where(rows >= g, newcol, 0.0)          # implicit mask (F4)
     live = rows > g
-    # rank-1 update of the remaining panel columns only
-    w = jax.lax.dynamic_slice(newcol, (o,), cols_bs.shape)
-    w = jnp.where(cols_bs > j, w, 0.0)
-    c = c - jnp.where(live[:, None], newcol[:, None] * w[None, :], 0.0)
-    c = jax.lax.dynamic_update_slice(c, newcol[:, None], (0, j))
+    # rank-1 update of the remaining panel columns only: w[q] is
+    # newcol[o + q], the slab's own rows laid along its columns
+    w = jnp.sum(jnp.where(rows == o + cols, newcol, 0.0), axis=0,
+                keepdims=True)
+    w = jnp.where(cols > j, w, 0.0)
+    c = c - jnp.where(live, newcol * w, 0.0)
+    c = put_col(c, j, newcol)
     # fused forward substitution consuming the finished column
-    yg = jax.lax.dynamic_slice(y, (g, 0), (1, m)) * inv
-    y = jax.lax.dynamic_update_slice(y, yg, (g, 0))
-    y = y - jnp.where(live[:, None], newcol[:, None] * yg, 0.0)
+    yg = take_row(y, g) * inv
+    y = put_row(y, g, yg)
+    y = y - jnp.where(live, newcol * yg, 0.0)
     return c, y
 
 
+def _trailing_update(slab, pan, pt, *, o, bs: int):
+    """Rank-``bs`` SYRK of factored panel ``pan`` (n, bs) onto one
+    column slab: slab[r, j] -= sum_p pan[r, p] * pt[j, p] for rows r
+    below the panel (rows >= o + bs), where ``pt`` is the slab's own
+    (bs, bs) row block of the panel.  ``o`` may be a traced grid value."""
+    pm = jnp.where(iota((pan.shape[0], 1), 0) >= o + bs, pan, 0.0)
+    return slab - dot(pm, pt.T)
+
+
+def _row_block(ref, t, bs: int, *lead):
+    """Rows [t*bs, (t+1)*bs) of the (n, c) slab ``ref[lead]`` at a traced
+    ``t`` — a sublane-dynamic ref read, which Mosaic lowers (a value
+    slice would not)."""
+    return ref[(*lead, pl.ds(pl.multiple_of(t * bs, bs), bs), slice(None))]
+
+
 def _cholesky_solve_blocked_kernel(a_ref, b_ref, x_ref, a_scr, y_scr,
-                                   thr_scr, *, n: int, m: int, bs: int,
+                                   thr_scr, *, n: int, bs: int,
                                    eps: float):
     """One tile step of the right-looking blocked factor-solve.
 
     grid = (lanes, n // bs): the second grid dimension is the panel step
     (``dimension_semantics`` marks it "arbitrary" — ordered), the matrix
-    and right-hand sides stay resident in VMEM scratch across steps, so
-    nothing round-trips HBM between panel factor, triangular update, and
-    trailing SYRK — the tiled-Cholesky chaining of Buttari et al. inside
-    the paper's ordered-region model.
+    (as ``n // bs`` column slabs) and right-hand sides stay resident in
+    VMEM scratch across steps, so nothing round-trips HBM between panel
+    factor, triangular update, and trailing SYRK — the tiled-Cholesky
+    chaining of Buttari et al. inside the paper's ordered-region model.
     """
     step = pl.program_id(1)
     steps = n // bs
-    rows = jax.lax.broadcasted_iota(jnp.int32, (n,), 0)
-    cols_bs = jax.lax.broadcasted_iota(jnp.int32, (bs,), 0)
 
     @pl.when(step == 0)
     def _init():
-        a = a_ref[0]
-        tril = rows[:, None] >= rows[None, :]
-        a = jnp.where(tril, a, a.T)       # symmetrize: upper never read
-        a_scr[...] = a.astype(jnp.float32)
+        a = symmetrize_lower(a_ref[0]).astype(jnp.float32)
+        for p in range(steps):
+            a_scr[p] = a[:, p * bs:(p + 1) * bs]
         y_scr[...] = b_ref[0].astype(jnp.float32)
-        thr_scr[0] = pivot_threshold(a.astype(jnp.float32), rows, eps=eps)
+        thr_scr[...] = pivot_threshold(a, eps=eps)
 
-    a = a_scr[...]
-    y = y_scr[...]
     o = step * bs
-    thresh = thr_scr[0]
-
     # ---- panel factor + fused forward substitution (bs columns) ----
-    c = jax.lax.dynamic_slice(a, (0, o), (n, bs))
     c, y = jax.lax.fori_loop(
         0, bs,
-        functools.partial(_panel_factor_forward_step, o=o, n=n, m=m,
-                          rows=rows, cols_bs=cols_bs, thresh=thresh),
-        (c, y))
-    a = jax.lax.dynamic_update_slice(a, c, (0, o))
-    # ---- trailing SYRK (critical MXU region): one rank-bs GEMM applies
-    # the whole panel's update to the trailing submatrix ----
-    cm = jnp.where(rows[:, None] >= o + bs, c, 0.0)
-    a = a - jnp.dot(cm, cm.T, preferred_element_type=jnp.float32)
-    a_scr[...] = a
+        functools.partial(_panel_factor_forward_step, o=o,
+                          thresh=thr_scr[...]),
+        (a_scr[step], y_scr[...]))
+    a_scr[step] = c
     y_scr[...] = y
 
-    # ---- back substitution once the factor is complete (the local
-    # ``a``/``y`` ARE the just-written scratch contents; reading the
-    # refs back per iteration would re-copy the whole block) ----
+    # ---- trailing SYRK (critical MXU region): one rank-bs GEMM per
+    # trailing slab applies the whole panel's update ----
+    def _trail(t, carry):
+        a_scr[t] = _trailing_update(a_scr[t], c,
+                                    _row_block(a_scr, t, bs, step),
+                                    o=o, bs=bs)
+        return carry
+
+    jax.lax.fori_loop(step + 1, steps, _trail, 0)
+
+    # ---- back substitution once the factor is complete ----
     @pl.when(step == steps - 1)
     def _finish():
+        lt = jnp.concatenate([a_scr[p] for p in range(steps)], axis=1).T
         z = jax.lax.fori_loop(
-            0, n,
-            lambda i, z_: back_substitution_step(i, a, z_, rows, n=n),
-            y)
+            0, n, lambda i, z_: back_substitution_step(i, lt, z_, n=n), y)
         x_ref[0] = z.astype(x_ref.dtype)
 
 
@@ -265,7 +294,7 @@ def cholesky_solve_blocked(a: jax.Array, b: jax.Array, *,
         interpret = interpret_default()
     steps = n // bs
     return pl.pallas_call(
-        functools.partial(_cholesky_solve_blocked_kernel, n=n, m=m, bs=bs,
+        functools.partial(_cholesky_solve_blocked_kernel, n=n, bs=bs,
                           eps=eps),
         grid=(bsz, steps),
         in_specs=[
@@ -278,11 +307,11 @@ def cholesky_solve_blocked(a: jax.Array, b: jax.Array, *,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((bsz, n, m), b.dtype),
         scratch_shapes=[
-            pltpu.VMEM((n, n), jnp.float32),
+            pltpu.VMEM((steps, n, bs), jnp.float32),
             pltpu.VMEM((n, m), jnp.float32),
-            pltpu.SMEM((1,), jnp.float32),
+            pltpu.VMEM((1, 1), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(a, b)
@@ -295,7 +324,7 @@ def cholesky_solve_blocked(a: jax.Array, b: jax.Array, *,
 # The ``blocked`` kernel above tiles the *schedule* but still holds the
 # whole (n, n) matrix in one VMEM block, capping it near n = 512.  The
 # ``tiled`` kernel below tiles the *data*: the matrix lives in HBM (a
-# ``pltpu.ANY`` ref) and every grid cell DMAs exactly one (n, bs) column
+# ``pl.ANY`` ref) and every grid cell DMAs exactly one (n, bs) column
 # slab into VMEM scratch, so the per-cell working set is O(n*bs) and
 # n = 1024/2048 fit.  Grid = (lanes, steps + 1, tiles) with
 # steps = tiles = n // bs:
@@ -316,49 +345,6 @@ def cholesky_solve_blocked(a: jax.Array, b: jax.Array, *,
 # Cells with t < s are idle (no DMA, no compute) — the price of a
 # rectangular grid over a triangular iteration space, exactly the
 # paper's inductive-domain shape.
-
-def _tiled_trailing_update(slab, pan, t, *, o, bs: int, rows):
-    """Rank-``bs`` SYRK of factored panel ``pan`` onto column slab ``t``:
-    slab[r, j] -= sum_p pan[r, p] * pan[t*bs + j, p] for rows r below the
-    panel (rows >= o + bs).  ``o``/``t`` may be traced grid values."""
-    pt = jax.lax.dynamic_slice(pan, (t * bs, 0), (bs, pan.shape[1]))
-    pm = jnp.where(rows[:, None] >= o + bs, pan, 0.0)
-    return slab - jnp.dot(pm, pt.T, preferred_element_type=jnp.float32)
-
-
-def _tiled_backsub_step(slab, z, rt, *, bs: int, m: int, rows):
-    """Left-looking block step of the L^T back substitution on column
-    slab ``rt`` (slabs processed in reverse): subtract the contributions
-    of the already-solved components below, then solve the (bs, bs)
-    diagonal block.  Only THIS slab is touched — O(n*bs) working set."""
-    o = rt * bs
-    below = jnp.where(rows[:, None] >= o + bs, slab, 0.0)
-    corr = jnp.dot(below.T, z, preferred_element_type=jnp.float32)
-    zt = jax.lax.dynamic_slice(z, (o, 0), (bs, m)) - corr
-    lb = jax.lax.dynamic_slice(slab, (o, 0), (bs, slab.shape[1]))
-    rows_bs = jax.lax.broadcasted_iota(jnp.int32, (bs,), 0)
-    xt = jax.lax.fori_loop(
-        0, bs,
-        lambda i, zz: back_substitution_step(i, lb, zz, rows_bs, n=bs),
-        zt)
-    return jax.lax.dynamic_update_slice(z, xt, (o, 0))
-
-
-def _pan_read(pan_scr, half):
-    """Read one half of the double-buffered panel carry (``half`` is a
-    traced 0/1 value; refs cannot be selected dynamically, values can)."""
-    return jnp.where(half == 0, pan_scr[0], pan_scr[1])
-
-
-def _pan_write(pan_scr, half, val):
-    @pl.when(half == 0)
-    def _w0():
-        pan_scr[0] = val
-
-    @pl.when(half != 0)
-    def _w1():
-        pan_scr[1] = val
-
 
 # Per-cell VMEM ceiling for the tiled kernels: stay comfortably inside
 # a TPU core's ~16 MiB vector memory (double-buffered DMA slack left).
@@ -389,15 +375,15 @@ def tiled_vmem_floats(n: int, bs: int, m: int) -> int:
 
 
 def _tiled_factor_cell(i, s2, t, *, first_hbm, work_hbm, slab_scr,
-                       pan_scr, y_scr, sem, thresh, n: int, m: int,
-                       bs: int, rows, cols_bs):
+                       pan_scr, y_scr, sem, thresh, bs: int):
     """One factor-phase grid cell (panel at t == s2, trailing at
     t > s2) of the tiled right-looking Cholesky — shared by
     ``cholesky_solve_tiled`` and the factor phase of
     ``mmse_equalize_tiled``.  ``first_hbm`` is where a slab's FIRST read
     comes from (the raw input for the Cholesky pipeline, the work buffer
     itself for MMSE, whose Gram phase already wrote it); every later
-    read and every write go to ``work_hbm``."""
+    read and every write go to ``work_hbm``.  ``pan_scr`` is the
+    double-buffered panel carry, indexed by the panel step's parity."""
     @pl.when(t == s2)
     def _panel():
         @pl.when(s2 == 0)                 # first panel: no stash yet
@@ -409,14 +395,12 @@ def _tiled_factor_cell(i, s2, t, *, first_hbm, work_hbm, slab_scr,
             pan_scr[0] = slab_scr[...]
 
         half = s2 % 2
-        c = _pan_read(pan_scr, half)      # pre-updated panel slab
-        c, y = jax.lax.fori_loop(
+        c, y = jax.lax.fori_loop(         # from the pre-updated panel slab
             0, bs,
-            functools.partial(_panel_factor_forward_step, o=s2 * bs, n=n,
-                              m=m, rows=rows, cols_bs=cols_bs,
+            functools.partial(_panel_factor_forward_step, o=s2 * bs,
                               thresh=thresh),
-            (c, y_scr[...]))
-        _pan_write(pan_scr, half, c)      # trailing cells read this
+            (pan_scr[half], y_scr[...]))
+        pan_scr[half] = c                 # trailing cells read this
         y_scr[...] = y
         slab_scr[...] = c
         cp = pltpu.make_async_copy(
@@ -440,9 +424,10 @@ def _tiled_factor_cell(i, s2, t, *, first_hbm, work_hbm, slab_scr,
             cp.start()
             cp.wait()
 
-        pan = _pan_read(pan_scr, s2 % 2)
-        slab = _tiled_trailing_update(slab_scr[...], pan, t, o=s2 * bs,
-                                      bs=bs, rows=rows)
+        half = s2 % 2
+        slab = _trailing_update(slab_scr[...], pan_scr[half],
+                                _row_block(pan_scr, t, bs, half),
+                                o=s2 * bs, bs=bs)
         slab_scr[...] = slab
         cp = pltpu.make_async_copy(
             slab_scr, work_hbm.at[i, :, pl.ds(t * bs, bs)], sem)
@@ -451,36 +436,42 @@ def _tiled_factor_cell(i, s2, t, *, first_hbm, work_hbm, slab_scr,
 
         @pl.when(t == s2 + 1)             # double-buffered panel carry
         def _stash():
-            _pan_write(pan_scr, (s2 + 1) % 2, slab)
+            pan_scr[(s2 + 1) % 2] = slab
 
 
 def _tiled_backsub_cell(i, t, *, steps: int, work_hbm, slab_scr, y_scr,
-                        x_ref, sem, bs: int, m: int, rows):
+                        x_ref, sem, bs: int):
     """One back-substitution grid cell (reverse slab order) of the tiled
-    L^T solve, shared by the Cholesky and MMSE tiled kernels; the last
-    cell writes the solution block."""
+    L^T solve, shared by the Cholesky and MMSE tiled kernels: a
+    left-looking block step on column slab ``rt`` — subtract the
+    contributions of the already-solved components below, then solve
+    the (bs, bs) diagonal block.  Only THIS slab is touched, an O(n*bs)
+    working set; the last cell writes the solution block."""
     rt = steps - 1 - t
     cp = pltpu.make_async_copy(work_hbm.at[i, :, pl.ds(rt * bs, bs)],
                                slab_scr, sem)
     cp.start()
     cp.wait()
-    z = _tiled_backsub_step(slab_scr[...], y_scr[...], rt, bs=bs,
-                            m=m, rows=rows)
-    y_scr[...] = z
+    o = rt * bs
+    below = jnp.where(iota((slab_scr.shape[0], 1), 0) >= o + bs,
+                      slab_scr[...], 0.0)
+    zt = _row_block(y_scr, rt, bs) - dot(below.T, y_scr[...])
+    lbt = _row_block(slab_scr, rt, bs).T
+    xt = jax.lax.fori_loop(
+        0, bs, lambda k, zz: back_substitution_step(k, lbt, zz, n=bs), zt)
+    y_scr[pl.ds(pl.multiple_of(o, bs), bs), :] = xt
 
     @pl.when(t == steps - 1)
     def _finish():
-        x_ref[0] = z.astype(x_ref.dtype)
+        x_ref[0] = y_scr[...].astype(x_ref.dtype)
 
 
 def _cholesky_solve_tiled_kernel(thr_ref, a_hbm, b_ref, x_ref, l_hbm,
                                  slab_scr, pan_scr, y_scr, sem, *,
-                                 n: int, m: int, bs: int, steps: int):
+                                 bs: int, steps: int):
     i = pl.program_id(0)
     s = pl.program_id(1)                  # panel step; == steps: back-sub
     t = pl.program_id(2)                  # column tile
-    rows = jax.lax.broadcasted_iota(jnp.int32, (n,), 0)
-    cols_bs = jax.lax.broadcasted_iota(jnp.int32, (bs,), 0)
 
     @pl.when((s == 0) & (t == 0))
     def _init():
@@ -490,14 +481,13 @@ def _cholesky_solve_tiled_kernel(thr_ref, a_hbm, b_ref, x_ref, l_hbm,
     def _factor():
         _tiled_factor_cell(i, s, t, first_hbm=a_hbm, work_hbm=l_hbm,
                            slab_scr=slab_scr, pan_scr=pan_scr,
-                           y_scr=y_scr, sem=sem, thresh=thr_ref[0, 0],
-                           n=n, m=m, bs=bs, rows=rows, cols_bs=cols_bs)
+                           y_scr=y_scr, sem=sem, thresh=thr_ref[i], bs=bs)
 
     @pl.when(s == steps)
     def _backsub():
         _tiled_backsub_cell(i, t, steps=steps, work_hbm=l_hbm,
                             slab_scr=slab_scr, y_scr=y_scr, x_ref=x_ref,
-                            sem=sem, bs=bs, m=m, rows=rows)
+                            sem=sem, bs=bs)
 
 
 def cholesky_solve_tiled(a: jax.Array, b: jax.Array, *,
@@ -509,7 +499,7 @@ def cholesky_solve_tiled(a: jax.Array, b: jax.Array, *,
     b: (B,N,M) -> x), but the matrix never sits whole in VMEM: per grid
     cell exactly one (N, bs) column slab is DMA'd in (plus the
     double-buffered panel carry), the trailing matrix stays HBM-resident
-    in a ``pltpu.ANY`` work buffer, and the per-cell working set is
+    in a ``pl.ANY`` work buffer, and the per-cell working set is
     ``tiled_vmem_floats(n, bs, m)`` = O(N*bs).  The deficiency threshold
     is precomputed host-side (one fused O(N) diagonal reduction) because
     the first panel cell needs it before any other slab is seen.
@@ -529,22 +519,21 @@ def cholesky_solve_tiled(a: jax.Array, b: jax.Array, *,
     steps = n // bs
     diag = jnp.diagonal(a, axis1=-2, axis2=-1)
     thr = jnp.maximum(eps * jnp.max(diag, axis=-1), 1e-30)
-    thr = thr.astype(jnp.float32).reshape(bsz, 1)
+    thr = thr.astype(jnp.float32)
     x, _ = pl.pallas_call(
-        functools.partial(_cholesky_solve_tiled_kernel, n=n, m=m, bs=bs,
-                          steps=steps),
+        functools.partial(_cholesky_solve_tiled_kernel, bs=bs, steps=steps),
         grid=(bsz, steps + 1, steps),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda i, s, t: (i, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            # every lane's threshold, whole in SMEM, read at program_id(0)
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec((1, n, m), lambda i, s, t: (i, 0, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_specs=[
             pl.BlockSpec((1, n, m), lambda i, s, t: (i, 0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bsz, n, m), b.dtype),
@@ -556,7 +545,7 @@ def cholesky_solve_tiled(a: jax.Array, b: jax.Array, *,
             pltpu.VMEM((n, m), jnp.float32),
             pltpu.SemaphoreType.DMA,
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(thr, a, b)

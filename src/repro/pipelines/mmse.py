@@ -40,39 +40,28 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import (interpret_default, resolve_backend,
-                                  tpu_compiler_params)
+from repro.kernels.common import (dot, eye, interpret_default, iota,
+                                  resolve_backend)
 from repro.pipelines.cholesky_solve import (DEFAULT_EPS,
                                             TILED_VMEM_BUDGET_BYTES,
                                             _tiled_backsub_cell,
                                             _tiled_factor_cell,
-                                            back_substitution_step,
+                                            chol_solve_inline,
                                             cholesky_solve_unfused,
-                                            factor_forward_step,
-                                            pivot_threshold,
                                             tiled_block_size)
 
 
-def _mmse_kernel(h_ref, y_ref, x_ref, *, m: int, n: int, sigma2: float,
+def _mmse_kernel(h_ref, y_ref, x_ref, *, n: int, sigma2: float,
                  eps: float):
     h = h_ref[0]                                       # (m, n)
     y = y_ref[0]                                       # (m, k)
     # ---- Gram GEMM region: G = H^T H + sigma2 I (MXU) ----
-    g = jnp.dot(h.T, h, preferred_element_type=jnp.float32)
-    rows = jax.lax.broadcasted_iota(jnp.int32, (n,), 0)
-    g = g + sigma2 * (rows[:, None] == rows[None, :]).astype(g.dtype)
+    g = dot(h.T, h) + sigma2 * eye(n)
     # ---- matched filter GEMM: rhs = H^T y ----
-    rhs = jnp.dot(h.T, y, preferred_element_type=jnp.float32)
+    rhs = dot(h.T, y)
     # ---- fused Cholesky solve on the VMEM-resident Gram matrix ----
-    thresh = pivot_threshold(g, rows, eps=eps)
-    g, rhs = jax.lax.fori_loop(
-        0, n,
-        lambda kk, c: factor_forward_step(kk, c[0], c[1], rows, thresh),
-        (g, rhs))
-    rhs = jax.lax.fori_loop(
-        0, n,
-        lambda i, z: back_substitution_step(i, g, z, rows, n=n), rhs)
-    x_ref[0] = rhs.astype(y.dtype)
+    _, x = chol_solve_inline(g, rhs, eps=eps)
+    x_ref[0] = x.astype(y.dtype)
 
 
 def mmse_equalize_pallas(h: jax.Array, y: jax.Array, *,
@@ -87,7 +76,7 @@ def mmse_equalize_pallas(h: jax.Array, y: jax.Array, *,
     if interpret is None:
         interpret = interpret_default()
     return pl.pallas_call(
-        functools.partial(_mmse_kernel, m=m, n=n, sigma2=sigma2, eps=eps),
+        functools.partial(_mmse_kernel, n=n, sigma2=sigma2, eps=eps),
         grid=(bsz,),
         in_specs=[
             pl.BlockSpec((1, m, n), lambda i: (i, 0, 0),
@@ -102,47 +91,36 @@ def mmse_equalize_pallas(h: jax.Array, y: jax.Array, *,
     )(h, y)
 
 
-def _mmse_split_kernel(hr_ref, hi_ref, yr_ref, yi_ref, x_ref, *, m: int,
-                       n: int, sigma2: float, eps: float):
+def _mmse_split_kernel(hr_ref, hi_ref, yr_ref, yi_ref, x_ref, *, n: int,
+                       sigma2: float, eps: float):
     hr = hr_ref[0]                                     # (m, n)
     hi = hi_ref[0]                                     # (m, n)
     yr = yr_ref[0]                                     # (m, k)
     yi = yi_ref[0]                                     # (m, k)
-    f32 = jnp.float32
     # ---- split Gram region (MXU): Gr = Hr^T Hr + Hi^T Hi as ONE dot on
     # the stacked (2m, n) planes; Gi = C - C^T from the single cross GEMM
     # C = Hr^T Hi (antisymmetry replaces the second cross dot).  6 m n^2
     # model flops vs 16 m n^2 for the real-expansion Gram. ----
     hs = jnp.concatenate([hr, hi], axis=0)             # (2m, n)
-    gr = jnp.dot(hs.T, hs, preferred_element_type=f32)
-    c = jnp.dot(hr.T, hi, preferred_element_type=f32)
+    gr = dot(hs.T, hs)
+    c = dot(hr.T, hi)
     gi = c - c.T
     # ---- split matched filter: rhs_r = Hr^T yr + Hi^T yi and
     # rhs_i = Hr^T yi - Hi^T yr, each one stacked dot ----
     ys = jnp.concatenate([yr, yi], axis=0)             # (2m, k)
     yt = jnp.concatenate([yi, -yr], axis=0)
-    rr = jnp.dot(hs.T, ys, preferred_element_type=f32)
-    ri = jnp.dot(hs.T, yt, preferred_element_type=f32)
+    rr = dot(hs.T, ys)
+    ri = dot(hs.T, yt)
     # ---- real embedding of the Hermitian system: the SAME 2n x 2n SPD
     # matrix the expansion path builds, assembled from n x n blocks ----
-    rows_n = jax.lax.broadcasted_iota(jnp.int32, (n,), 0)
-    gr = gr + sigma2 * (rows_n[:, None] == rows_n[None, :]).astype(gr.dtype)
+    gr = gr + sigma2 * eye(n)
     g = jnp.concatenate(
         [jnp.concatenate([gr, -gi], axis=1),
          jnp.concatenate([gi, gr], axis=1)], axis=0)   # (2n, 2n)
     rhs = jnp.concatenate([rr, ri], axis=0)            # (2n, k)
     # ---- fused Cholesky solve, identical chain to the expansion path ----
-    rows = jax.lax.broadcasted_iota(jnp.int32, (2 * n,), 0)
-    thresh = pivot_threshold(g, rows, eps=eps)
-    g, rhs = jax.lax.fori_loop(
-        0, 2 * n,
-        lambda kk, carry: factor_forward_step(kk, carry[0], carry[1], rows,
-                                              thresh),
-        (g, rhs))
-    rhs = jax.lax.fori_loop(
-        0, 2 * n,
-        lambda i, z: back_substitution_step(i, g, z, rows, n=2 * n), rhs)
-    x_ref[0] = rhs.astype(yr.dtype)
+    _, x = chol_solve_inline(g, rhs, eps=eps)
+    x_ref[0] = x.astype(yr.dtype)
 
 
 def mmse_equalize_split_pallas(hr: jax.Array, hi: jax.Array, yr: jax.Array,
@@ -169,8 +147,7 @@ def mmse_equalize_split_pallas(hr: jax.Array, hi: jax.Array, yr: jax.Array,
     obs = pl.BlockSpec((1, m, k), lambda i: (i, 0, 0),
                        memory_space=pltpu.VMEM)
     return pl.pallas_call(
-        functools.partial(_mmse_split_kernel, m=m, n=n, sigma2=sigma2,
-                          eps=eps),
+        functools.partial(_mmse_split_kernel, n=n, sigma2=sigma2, eps=eps),
         grid=(bsz,),
         in_specs=[mat, mat, obs, obs],
         out_specs=pl.BlockSpec((1, 2 * n, k), lambda i: (i, 0, 0),
@@ -244,14 +221,11 @@ def mmse_tiled_vmem_floats(m: int, n: int, bs: int, k: int) -> int:
 
 
 def _mmse_tiled_kernel(h_hbm, y_ref, x_ref, g_hbm, hr_scr, ht_scr, gb_scr,
-                       slab_scr, pan_scr, z_scr, stat_scr, sem, *, m: int,
-                       n: int, k: int, bs: int, steps: int, sigma2: float,
-                       eps: float):
+                       slab_scr, pan_scr, z_scr, stat_scr, sem, *, bs: int,
+                       steps: int, sigma2: float, eps: float):
     i = pl.program_id(0)
     s = pl.program_id(1)          # [0,steps) gram; [steps,2*steps) factor
     t = pl.program_id(2)          # column tile
-    rows = jax.lax.broadcasted_iota(jnp.int32, (n,), 0)
-    cols_bs = jax.lax.broadcasted_iota(jnp.int32, (bs,), 0)
 
     @pl.when((s == 0) & (t == 0))
     def _init():
@@ -280,22 +254,18 @@ def _mmse_tiled_kernel(h_hbm, y_ref, x_ref, g_hbm, hr_scr, ht_scr, gb_scr,
             cp.wait()
 
         ht = jnp.where(r == t, hr_scr[...], ht_scr[...])
-        gb = jnp.dot(hr_scr[...].T, ht,
-                     preferred_element_type=jnp.float32)
+        gb = dot(hr_scr[...].T, ht)
 
         @pl.when(r == t)
         def _diag():
-            eye = (cols_bs[:, None] == cols_bs[None, :])
-            gd = gb + sigma2 * eye.astype(jnp.float32)
+            gd = gb + sigma2 * eye(bs)
             gb_scr[...] = gd
+            on_diag = iota((bs, bs), 0) == iota((bs, bs), 1)
             stat_scr[0] = jnp.maximum(
-                stat_scr[0], jnp.max(jnp.where(eye, gd, -jnp.inf)))
+                stat_scr[0], jnp.max(jnp.where(on_diag, gd, -jnp.inf)))
             # matched-filter rows: z[r-slab] = H_r^T y
-            rhs_r = jnp.dot(hr_scr[...].T, y_ref[0].astype(jnp.float32),
-                            preferred_element_type=jnp.float32)
-            z = jax.lax.dynamic_update_slice(z_scr[...], rhs_r,
-                                             (r * bs, 0))
-            z_scr[...] = z
+            z_scr[pl.ds(pl.multiple_of(r * bs, bs), bs), :] = dot(
+                hr_scr[...].T, y_ref[0].astype(jnp.float32))
 
         @pl.when(r != t)
         def _off():
@@ -319,15 +289,14 @@ def _mmse_tiled_kernel(h_hbm, y_ref, x_ref, g_hbm, hr_scr, ht_scr, gb_scr,
 
         _tiled_factor_cell(i, s2, t, first_hbm=g_hbm, work_hbm=g_hbm,
                            slab_scr=slab_scr, pan_scr=pan_scr,
-                           y_scr=z_scr, sem=sem, thresh=stat_scr[1],
-                           n=n, m=k, bs=bs, rows=rows, cols_bs=cols_bs)
+                           y_scr=z_scr, sem=sem, thresh=stat_scr[1], bs=bs)
 
     # ---- back substitution: reverse-streamed L^T block solve ----
     @pl.when(s == 2 * steps)
     def _backsub():
         _tiled_backsub_cell(i, t, steps=steps, work_hbm=g_hbm,
                             slab_scr=slab_scr, y_scr=z_scr, x_ref=x_ref,
-                            sem=sem, bs=bs, m=k, rows=rows)
+                            sem=sem, bs=bs)
 
 
 def mmse_equalize_tiled(h: jax.Array, y: jax.Array, *,
@@ -356,18 +325,18 @@ def mmse_equalize_tiled(h: jax.Array, y: jax.Array, *,
         interpret = interpret_default()
     steps = n // bs
     x, _ = pl.pallas_call(
-        functools.partial(_mmse_tiled_kernel, m=m, n=n, k=k, bs=bs,
-                          steps=steps, sigma2=sigma2, eps=eps),
+        functools.partial(_mmse_tiled_kernel, bs=bs, steps=steps,
+                          sigma2=sigma2, eps=eps),
         grid=(bsz, 2 * steps + 1, steps),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec((1, m, k), lambda i, s, t: (i, 0, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_specs=[
             pl.BlockSpec((1, n, k), lambda i, s, t: (i, 0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bsz, n, k), y.dtype),
@@ -383,7 +352,7 @@ def mmse_equalize_tiled(h: jax.Array, y: jax.Array, *,
             pltpu.SMEM((2,), jnp.float32),
             pltpu.SemaphoreType.DMA,
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(h, y)

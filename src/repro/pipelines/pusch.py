@@ -45,47 +45,31 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import interpret_default
+from repro.kernels.common import dot, eye, interpret_default
 from repro.kernels.fft import fft_pallas
 from repro.kernels.svd import svd_pallas
-from repro.pipelines.cholesky_solve import (DEFAULT_EPS,
-                                            back_substitution_step,
-                                            factor_forward_step,
-                                            pivot_threshold)
+from repro.pipelines.cholesky_solve import DEFAULT_EPS, chol_solve_inline
 
 DEFAULT_RIDGE = 1e-3
 DEFAULT_LAM = 1e-3
 
 
-def _chol_solve_inline(g, rhs, *, n: int, eps: float):
-    """Fused factor + both substitutions on an SPD (n, n) system already
-    resident in VMEM — the shared tail of every stage kernel here."""
-    rows = jax.lax.broadcasted_iota(jnp.int32, (n,), 0)
-    thresh = pivot_threshold(g, rows, eps=eps)
-    g, rhs = jax.lax.fori_loop(
-        0, n,
-        lambda k, c: factor_forward_step(k, c[0], c[1], rows, thresh),
-        (g, rhs))
-    return jax.lax.fori_loop(
-        0, n,
-        lambda i, y_: back_substitution_step(i, g, y_, rows, n=n), rhs)
+def _gram_plus_eye(x, shift: float):
+    """x x^T + shift I, the regularized Gram of an (n, p) block."""
+    return dot(x, x.T) + shift * eye(x.shape[0])
 
 
-def _estimate_h(xp, yp, *, n: int, ridge: float, eps: float):
+def _estimate_h(xp, yp, *, ridge: float, eps: float):
     """Regularized LS estimate H (m, n) from xp (n, p), yp (m, p)."""
-    rows = jax.lax.broadcasted_iota(jnp.int32, (n,), 0)
-    g = jnp.dot(xp, xp.T, preferred_element_type=jnp.float32)
-    g = g + ridge * (rows[:, None] == rows[None, :]).astype(jnp.float32)
-    rhs = jnp.dot(xp, yp.T, preferred_element_type=jnp.float32)
-    z = _chol_solve_inline(g, rhs, n=n, eps=eps)        # (n, m)
+    _, z = chol_solve_inline(_gram_plus_eye(xp, ridge), dot(xp, yp.T),
+                             eps=eps)                   # (n, m)
     return z.T                                          # (m, n)
 
 
-def _chanest_kernel(xp_ref, yp_ref, h_ref, *, n: int, ridge: float,
-                    eps: float):
+def _chanest_kernel(xp_ref, yp_ref, h_ref, *, ridge: float, eps: float):
     xp = xp_ref[0].astype(jnp.float32)
     yp = yp_ref[0].astype(jnp.float32)
-    h = _estimate_h(xp, yp, n=n, ridge=ridge, eps=eps)
+    h = _estimate_h(xp, yp, ridge=ridge, eps=eps)
     h_ref[0] = h.astype(h_ref.dtype)
 
 
@@ -101,7 +85,7 @@ def channel_estimate_pallas(xp: jax.Array, yp: jax.Array, *,
     if interpret is None:
         interpret = interpret_default()
     return pl.pallas_call(
-        functools.partial(_chanest_kernel, n=n, ridge=ridge, eps=eps),
+        functools.partial(_chanest_kernel, ridge=ridge, eps=eps),
         grid=(bsz,),
         in_specs=[
             pl.BlockSpec((1, n, p), lambda i: (i, 0, 0),
@@ -116,19 +100,17 @@ def channel_estimate_pallas(xp: jax.Array, yp: jax.Array, *,
     )(xp, yp)
 
 
-def _pusch_chain_kernel(xp_ref, yp_ref, y_ref, x_ref, *, n: int,
-                        ridge: float, sigma2: float, eps: float):
+def _pusch_chain_kernel(xp_ref, yp_ref, y_ref, x_ref, *, ridge: float,
+                        sigma2: float, eps: float):
     xp = xp_ref[0].astype(jnp.float32)
     yp = yp_ref[0].astype(jnp.float32)
     y = y_ref[0].astype(jnp.float32)
     # stage 1: channel estimate — H never leaves VMEM
-    h = _estimate_h(xp, yp, n=n, ridge=ridge, eps=eps)
+    h = _estimate_h(xp, yp, ridge=ridge, eps=eps)
     # stage 2: MMSE equalize consuming the just-produced H
-    rows = jax.lax.broadcasted_iota(jnp.int32, (n,), 0)
-    g = jnp.dot(h.T, h, preferred_element_type=jnp.float32)
-    g = g + sigma2 * (rows[:, None] == rows[None, :]).astype(jnp.float32)
-    rhs = jnp.dot(h.T, y, preferred_element_type=jnp.float32)
-    x = _chol_solve_inline(g, rhs, n=n, eps=eps)
+    ht = h.T
+    _, x = chol_solve_inline(_gram_plus_eye(ht, sigma2), dot(ht, y),
+                             eps=eps)
     x_ref[0] = x.astype(x_ref.dtype)
 
 
@@ -145,8 +127,8 @@ def pusch_chain_pallas(xp: jax.Array, yp: jax.Array, y: jax.Array, *,
     if interpret is None:
         interpret = interpret_default()
     return pl.pallas_call(
-        functools.partial(_pusch_chain_kernel, n=n, ridge=ridge,
-                          sigma2=sigma2, eps=eps),
+        functools.partial(_pusch_chain_kernel, ridge=ridge, sigma2=sigma2,
+                          eps=eps),
         grid=(bsz,),
         in_specs=[
             pl.BlockSpec((1, n, p), lambda i: (i, 0, 0),
@@ -190,10 +172,9 @@ def _svd_apply_kernel(f_ref, b_ref, x_ref, *, m: int, n: int,
     b = b_ref[0].astype(jnp.float32)
     u = f[:m]                                           # (m, n)
     v = f[m:m + n]                                      # (n, n)
-    s = f[m + n]                                        # (n,)
-    w = jnp.dot(u.T, b, preferred_element_type=jnp.float32)   # (n, k)
-    w = (s / (s * s + lam))[:, None] * w
-    x = jnp.dot(v, w, preferred_element_type=jnp.float32)
+    s = f[m + n:m + n + 1].T                            # (n, 1)
+    w = (s / (s * s + lam)) * dot(u.T, b)               # (n, k)
+    x = dot(v, w)
     x_ref[0] = x.astype(x_ref.dtype)
 
 

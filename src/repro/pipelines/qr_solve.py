@@ -23,32 +23,43 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import (interpret_default, resolve_backend,
-                                  tpu_compiler_params)
+from repro.kernels.common import (dot, interpret_default, iota, put_col,
+                                  put_row, resolve_backend, take_col,
+                                  take_row)
 from repro.kernels.qr import qr_pallas
 from repro.kernels.trisolve import trisolve_pallas
 from repro.pipelines.cholesky_solve import (TILED_VMEM_BUDGET_BYTES,
-                                            _pan_read, _pan_write,
-                                            tiled_block_size)
+                                            _row_block, tiled_block_size)
 
 DEFAULT_TINY = 1e-20
 
 
-def reflect_step(k, r, y, rows, *, tiny: float = DEFAULT_TINY):
-    """One fused outer iteration: build reflector k, apply to R and rhs."""
-    # ---- householder region (non-critical: norm, sqrt, div) ----
-    x = jnp.where(rows >= k, r[:, k], 0.0)            # masked column (F4)
-    xk = r[k, k]
-    norm = jnp.sqrt(jnp.sum(x * x))
+def householder(x, g, *, tiny: float = DEFAULT_TINY):
+    """Reflector (v, tau) zeroing column ``x`` (r, 1) below row ``g``
+    (the non-critical householder region: norm, sqrt, div).  A
+    degenerate (zero-norm) column gets tau = 0, the identity."""
+    rows = iota(x.shape, 0)
+    x = jnp.where(rows >= g, x, 0.0)                  # masked column (F4)
+    xk = take_row(x, g)
+    norm = jnp.sqrt(jnp.sum(x * x, axis=0, keepdims=True))
     alpha = jnp.where(xk >= 0, -norm, norm)
-    v = x - alpha * (rows == k).astype(r.dtype)
-    vnorm2 = jnp.maximum(jnp.sum(v * v), tiny)
+    v = x - alpha * (rows == g).astype(x.dtype)
+    vnorm2 = jnp.maximum(jnp.sum(v * v, axis=0, keepdims=True), tiny)
     tau = jnp.where(norm < tiny, 0.0, 2.0 / vnorm2)   # degenerate: skip
-    # ---- critical region 1: R update (v^T R then rank-1) ----
-    r = r - v[:, None] * (tau * (v @ r))[None, :]
-    # ---- critical region 2 (fused solve): rhs <- (I - tau v v^T) rhs ----
-    y = y - v[:, None] * (tau * (v @ y))[None, :]
-    return r, y
+    return v, tau
+
+
+def reflect(v, tau, mat):
+    """(I - tau v v^T) mat: v^T mat as one sublane reduction, then the
+    rank-1 update (a critical region)."""
+    return mat - v * (tau * jnp.sum(v * mat, axis=0, keepdims=True))
+
+
+def reflect_step(k, r, y, *, tiny: float = DEFAULT_TINY):
+    """One fused outer iteration: build reflector k, apply it to R and,
+    in the same iteration, to the right-hand sides (the fused solve)."""
+    v, tau = householder(take_col(r, k), k, tiny=tiny)
+    return reflect(v, tau, r), reflect(v, tau, y)
 
 
 def back_substitute_r(r, y, *, n: int, tiny: float, thresh=None):
@@ -64,36 +75,32 @@ def back_substitute_r(r, y, *, n: int, tiny: float, thresh=None):
     diagonal block at a time, so it passes the GLOBAL R-diagonal
     threshold accumulated during the panel sweep.
     """
-    rows_n = jax.lax.broadcasted_iota(jnp.int32, (n,), 0)
+    r = r[:n]
     z = y[:n]
+    rows_n = iota((n, 1), 0)
     if thresh is None:
-        diag = jnp.abs(jnp.where(rows_n[:, None] == rows_n[None, :],
-                                 r[:n], 0.0).sum(axis=1))
-        thresh = jnp.maximum(1e-6 * jnp.max(diag), tiny)
+        diag = jnp.where(rows_n == iota((1, n), 1), jnp.abs(r), 0.0)
+        thresh = jnp.maximum(
+            1e-6 * jnp.max(diag, axis=(0, 1), keepdims=True), tiny)
 
     def bwd(i, z):
         k = n - 1 - i
-        rkk = r[k, k]
+        col = take_col(r, k)
+        rkk = take_row(col, k)
         ok = jnp.abs(rkk) > thresh
-        xk = jnp.where(ok, z[k] / jnp.where(ok, rkk, 1.0), 0.0)
-        z = z.at[k].set(xk)
-        col = jnp.where(rows_n < k, r[:n, k], 0.0)
-        return z - col[:, None] * xk[None, :]
+        xk = jnp.where(ok, take_row(z, k) / jnp.where(ok, rkk, 1.0), 0.0)
+        z = put_row(z, k, xk)
+        return z - jnp.where(rows_n < k, col, 0.0) * xk
 
     return jax.lax.fori_loop(0, n, bwd, z)
 
 
 def _qr_solve_kernel(a_ref, b_ref, x_ref, *, m: int, n: int,
                      tiny: float):
-    r = a_ref[0]                                      # (m, n)
-    y = b_ref[0]                                      # (m, k)
-    rows = jax.lax.broadcasted_iota(jnp.int32, (m,), 0)
     nref = min(n, m - 1) if m > 1 else 0
-
     r, y = jax.lax.fori_loop(
-        0, nref, lambda k, c: reflect_step(k, c[0], c[1], rows, tiny=tiny),
-        (r, y))
-
+        0, nref, lambda k, c: reflect_step(k, c[0], c[1], tiny=tiny),
+        (a_ref[0], b_ref[0]))                         # (m, n), (m, k)
     x_ref[0] = back_substitute_r(r, y, n=n, tiny=tiny)
 
 
@@ -123,80 +130,72 @@ def qr_solve_pallas(a: jax.Array, b: jax.Array, *,
     )(a, b)
 
 
-def _qr_panel_reflect_step(j, carry, *, o, m: int, rows, tiny: float):
+def _qr_panel_reflect_step(j, carry, *, o, tiny: float):
     """Reflector ``g = o + j`` built from and applied to the panel only;
     (v, tau) accumulated for the compact-WY block apply."""
     pan, v_acc, tau_acc = carry
-    g = o + j
-    x = jax.lax.dynamic_slice(pan, (0, j), (m, 1))[:, 0]
-    x = jnp.where(rows >= g, x, 0.0)                  # masked column (F4)
-    xk = jnp.take(x, g)
-    norm = jnp.sqrt(jnp.sum(x * x))
-    alpha = jnp.where(xk >= 0, -norm, norm)
-    v = x - alpha * (rows == g).astype(pan.dtype)
-    vnorm2 = jnp.maximum(jnp.sum(v * v), tiny)
-    tau = jnp.where(norm < tiny, 0.0, 2.0 / vnorm2)   # degenerate: skip
-    pan = pan - v[:, None] * (tau * (v @ pan))[None, :]
-    v_acc = jax.lax.dynamic_update_slice(v_acc, v[:, None], (0, j))
-    tau_acc = jax.lax.dynamic_update_slice(tau_acc, tau[None], (j,))
-    return pan, v_acc, tau_acc
+    v, tau = householder(take_col(pan, j), o + j, tiny=tiny)
+    return (reflect(v, tau, pan), put_col(v_acc, j, v),
+            put_col(tau_acc, j, tau))
 
 
-def _wy_t_step(j, t, *, vt_v, taus, cols_bs):
+def _wy_t_step(j, t, *, vt_v, taus):
     """Column ``j`` of the compact-WY ``T`` (LAPACK larft, forward
     columnwise): T[:j, j] = -tau_j * T[:j, :j] @ (V^T v_j); T[j,j] =
     tau_j.  Columns >= j of the carried ``t`` are still zero, so the
-    full-width dot only consumes finished columns."""
-    z = jax.lax.dynamic_slice(vt_v, (0, j), (vt_v.shape[0], 1))[:, 0]
-    z = jnp.where(cols_bs < j, z, 0.0)
-    tau_j = jnp.take(taus, j)
-    tcol = -tau_j * jnp.dot(t, z, preferred_element_type=jnp.float32)
-    tcol = jnp.where(cols_bs < j, tcol, 0.0)
-    tcol = tcol + tau_j * (cols_bs == j).astype(t.dtype)
-    return jax.lax.dynamic_update_slice(t, tcol[:, None], (0, j))
+    full-width product only consumes finished columns."""
+    bs = t.shape[0]
+    rows = iota((bs, 1), 0)
+    z = jnp.where(iota((1, bs), 1) < j, take_col(vt_v, j).T, 0.0)
+    tau_j = take_col(taus, j)
+    tcol = -tau_j * jnp.sum(t * z, axis=1, keepdims=True)
+    tcol = jnp.where(rows < j, tcol, 0.0)
+    tcol = tcol + tau_j * (rows == j).astype(t.dtype)
+    return put_col(t, j, tcol)
 
 
-def _qr_solve_blocked_kernel(a_ref, b_ref, x_ref, *, m: int, n: int,
+def _panel_wy(pan, *, o, tiny: float):
+    """Factor one (m, bs) panel into its reflectors and their
+    compact-WY form: returns (R panel, V, T)."""
+    m, bs = pan.shape
+    pan, v, taus = jax.lax.fori_loop(
+        0, bs, functools.partial(_qr_panel_reflect_step, o=o, tiny=tiny),
+        (pan, jnp.zeros((m, bs), jnp.float32),
+         jnp.zeros((1, bs), jnp.float32)))
+    # T build: one V^T V gram + bs short column steps
+    t = jax.lax.fori_loop(
+        0, bs, functools.partial(_wy_t_step, vt_v=dot(v.T, v), taus=taus),
+        jnp.zeros((bs, bs), jnp.float32))
+    return pan, v, t
+
+
+def _wy_apply(v, t, mat):
+    """Block reflector Q_p^T mat = mat - V T^T V^T mat: the whole panel's
+    reflectors as three GEMMs (critical MXU regions) instead of bs
+    rank-1 updates."""
+    return mat - dot(v, dot(t.T, dot(v.T, mat)))
+
+
+def _qr_solve_blocked_kernel(a_ref, b_ref, x_ref, r_scr, *, n: int,
                              bs: int, tiny: float):
-    r = a_ref[0]                                      # (m, n)
-    y = b_ref[0]                                      # (m, k)
-    rows = jax.lax.broadcasted_iota(jnp.int32, (m,), 0)
-    cols_n = jax.lax.broadcasted_iota(jnp.int32, (n,), 0)
-    cols_bs = jax.lax.broadcasted_iota(jnp.int32, (bs,), 0)
+    steps = n // bs
+    a = a_ref[0]                                      # (m, n)
+    for p in range(steps):                            # column slabs
+        r_scr[p] = a[:, p * bs:(p + 1) * bs]
 
-    def panel_step(p, carry):
-        r, y = carry
-        o = p * bs
-        # ---- panel factor: bs reflectors applied panel-locally ----
-        pan = jax.lax.dynamic_slice(r, (0, o), (m, bs))
-        pan, v, taus = jax.lax.fori_loop(
-            0, bs,
-            functools.partial(_qr_panel_reflect_step, o=o, m=m, rows=rows,
-                              tiny=tiny),
-            (pan, jnp.zeros((m, bs), r.dtype), jnp.zeros((bs,), r.dtype)))
-        r = jax.lax.dynamic_update_slice(r, pan, (0, o))
-        # ---- T build: one V^T V gram + bs short column steps ----
-        vt_v = jnp.dot(v.T, v, preferred_element_type=jnp.float32)
-        t = jax.lax.fori_loop(
-            0, bs,
-            functools.partial(_wy_t_step, vt_v=vt_v, taus=taus,
-                              cols_bs=cols_bs),
-            jnp.zeros((bs, bs), r.dtype))
-        # ---- block apply Q_p^T = I - V T^T V^T (critical MXU regions):
-        # the whole panel's reflectors hit the trailing columns and the
-        # rhs as three GEMMs instead of bs rank-1 updates ----
-        wr = jnp.dot(v.T, r, preferred_element_type=jnp.float32)
-        upd = jnp.dot(v, jnp.dot(t.T, wr,
-                                 preferred_element_type=jnp.float32),
-                      preferred_element_type=jnp.float32)
-        r = r - jnp.where(cols_n[None, :] >= o + bs, upd, 0.0)
-        wy = jnp.dot(v.T, y, preferred_element_type=jnp.float32)
-        y = y - jnp.dot(v, jnp.dot(t.T, wy,
-                                   preferred_element_type=jnp.float32),
-                        preferred_element_type=jnp.float32)
-        return r, y
+    def panel_step(p, y):
+        pan, v, t = _panel_wy(r_scr[p], o=p * bs, tiny=tiny)
+        r_scr[p] = pan
 
-    r, y = jax.lax.fori_loop(0, n // bs, panel_step, (r, y))
+        def _trail(q, carry):
+            r_scr[q] = _wy_apply(v, t, r_scr[q])
+            return carry
+
+        jax.lax.fori_loop(p + 1, steps, _trail, 0)
+        return _wy_apply(v, t, y)
+
+    y = jax.lax.fori_loop(0, steps, panel_step, b_ref[0])
+    r = jnp.concatenate([r_scr[p] for p in range(steps)], axis=1)
     x_ref[0] = back_substitute_r(r, y, n=n, tiny=tiny)
 
 
@@ -221,7 +220,7 @@ def qr_solve_blocked(a: jax.Array, b: jax.Array, *, bs: int | None = None,
     if interpret is None:
         interpret = interpret_default()
     return pl.pallas_call(
-        functools.partial(_qr_solve_blocked_kernel, m=m, n=n, bs=bs,
+        functools.partial(_qr_solve_blocked_kernel, n=n, bs=bs,
                           tiny=tiny),
         grid=(bsz,),
         in_specs=[
@@ -233,6 +232,7 @@ def qr_solve_blocked(a: jax.Array, b: jax.Array, *, bs: int | None = None,
         out_specs=pl.BlockSpec((1, n, k), lambda i: (i, 0, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((bsz, n, k), b.dtype),
+        scratch_shapes=[pltpu.VMEM((n // bs, m, bs), jnp.float32)],
         interpret=interpret,
     )(a, b)
 
@@ -244,7 +244,7 @@ def qr_solve_blocked(a: jax.Array, b: jax.Array, *, bs: int | None = None,
 # Same data-tiling scheme as ``cholesky_solve_tiled`` (see the long
 # comment there): grid = (lanes, steps + 1, tiles) with
 # steps = tiles = n // bs, the (m, n) matrix HBM-resident in a
-# ``pltpu.ANY`` work buffer, one (m, bs) column slab DMA'd per cell.
+# ``pl.ANY`` work buffer, one (m, bs) column slab DMA'd per cell.
 # The panel cell factors bs Householder reflectors panel-locally,
 # accumulates compact-WY (V, T) in VMEM scratch, and applies the block
 # reflector to the right-hand sides; trailing cells stream their slab
@@ -262,14 +262,11 @@ def qr_tiled_vmem_floats(m: int, n: int, bs: int, k: int) -> int:
 
 
 def _qr_solve_tiled_kernel(a_hbm, b_ref, x_ref, r_hbm, slab_scr, pan_scr,
-                           v_scr, t_scr, y_scr, dmax_scr, sem, *, m: int,
-                           n: int, k: int, bs: int, steps: int,
-                           tiny: float):
+                           v_scr, t_scr, y_scr, dmax_scr, sem, *, n: int,
+                           bs: int, steps: int, tiny: float):
     i = pl.program_id(0)
     s = pl.program_id(1)                  # panel step; == steps: back-sub
     t = pl.program_id(2)                  # column tile
-    rows = jax.lax.broadcasted_iota(jnp.int32, (m,), 0)
-    cols_bs = jax.lax.broadcasted_iota(jnp.int32, (bs,), 0)
 
     @pl.when((s == 0) & (t == 0))
     def _init():
@@ -284,32 +281,14 @@ def _qr_solve_tiled_kernel(a_hbm, b_ref, x_ref, r_hbm, slab_scr, pan_scr,
     @pl.when((s < steps) & (t == s))
     def _panel():
         o = s * bs
-        pan = _pan_read(pan_scr, s % 2)
-        pan, v, taus = jax.lax.fori_loop(
-            0, bs,
-            functools.partial(_qr_panel_reflect_step, o=o, m=m, rows=rows,
-                              tiny=tiny),
-            (pan, jnp.zeros((m, bs), jnp.float32),
-             jnp.zeros((bs,), jnp.float32)))
-        vt_v = jnp.dot(v.T, v, preferred_element_type=jnp.float32)
-        tt = jax.lax.fori_loop(
-            0, bs,
-            functools.partial(_wy_t_step, vt_v=vt_v, taus=taus,
-                              cols_bs=cols_bs),
-            jnp.zeros((bs, bs), jnp.float32))
-        # block-apply Q_p^T to the right-hand sides
-        y = y_scr[...]
-        wy = jnp.dot(v.T, y, preferred_element_type=jnp.float32)
-        y_scr[...] = y - jnp.dot(
-            v, jnp.dot(tt.T, wy, preferred_element_type=jnp.float32),
-            preferred_element_type=jnp.float32)
+        pan, v, tt = _panel_wy(pan_scr[s % 2], o=o, tiny=tiny)
+        y_scr[...] = _wy_apply(v, tt, y_scr[...])     # Q_p^T on the rhs
         v_scr[...] = v
         t_scr[...] = tt
         # global |diag R| max for the back-substitution threshold
-        blk = jax.lax.dynamic_slice(pan, (o, 0), (bs, bs))
-        d = jnp.max(jnp.abs(jnp.where(
-            cols_bs[:, None] == cols_bs[None, :], blk, 0.0)))
-        dmax_scr[0] = jnp.maximum(dmax_scr[0], d)
+        diag = iota((pan.shape[0], 1), 0) == o + iota((1, bs), 1)
+        dmax_scr[0] = jnp.maximum(
+            dmax_scr[0], jnp.max(jnp.where(diag, jnp.abs(pan), 0.0)))
         slab_scr[...] = pan
         cp = pltpu.make_async_copy(slab_scr,
                                    r_hbm.at[i, :, pl.ds(o, bs)], sem)
@@ -332,13 +311,7 @@ def _qr_solve_tiled_kernel(a_hbm, b_ref, x_ref, r_hbm, slab_scr, pan_scr,
             cp.start()
             cp.wait()
 
-        v = v_scr[...]
-        tt = t_scr[...]
-        slab = slab_scr[...]
-        w = jnp.dot(v.T, slab, preferred_element_type=jnp.float32)
-        slab = slab - jnp.dot(
-            v, jnp.dot(tt.T, w, preferred_element_type=jnp.float32),
-            preferred_element_type=jnp.float32)
+        slab = _wy_apply(v_scr[...], t_scr[...], slab_scr[...])
         slab_scr[...] = slab
         cp = pltpu.make_async_copy(slab_scr,
                                    r_hbm.at[i, :, pl.ds(t * bs, bs)], sem)
@@ -347,7 +320,7 @@ def _qr_solve_tiled_kernel(a_hbm, b_ref, x_ref, r_hbm, slab_scr, pan_scr,
 
         @pl.when(t == s + 1)              # double-buffered panel carry
         def _stash():
-            _pan_write(pan_scr, (s + 1) % 2, slab)
+            pan_scr[(s + 1) % 2] = slab
 
     @pl.when(s == steps)
     def _backsub():
@@ -357,20 +330,18 @@ def _qr_solve_tiled_kernel(a_hbm, b_ref, x_ref, r_hbm, slab_scr, pan_scr,
                                    slab_scr, sem)
         cp.start()
         cp.wait()
-        slab = slab_scr[...]
-        z = y_scr[...]
         thresh = jnp.maximum(1e-6 * dmax_scr[0], tiny)
-        rb = jax.lax.dynamic_slice(slab, (o, 0), (bs, bs))
-        zt = jax.lax.dynamic_slice(z, (o, 0), (bs, k))
-        xt = back_substitute_r(rb, zt, n=bs, tiny=tiny, thresh=thresh)
-        z = jax.lax.dynamic_update_slice(z, xt, (o, 0))
-        above = jnp.where(rows[:, None] < o, slab, 0.0)
-        z = z - jnp.dot(above, xt, preferred_element_type=jnp.float32)
-        y_scr[...] = z
+        xt = back_substitute_r(_row_block(slab_scr, rt, bs),
+                               _row_block(y_scr, rt, bs), n=bs, tiny=tiny,
+                               thresh=thresh)
+        y_scr[pl.ds(pl.multiple_of(o, bs), bs), :] = xt
+        above = jnp.where(iota((slab_scr.shape[0], 1), 0) < o,
+                          slab_scr[...], 0.0)
+        y_scr[...] = y_scr[...] - dot(above, xt)
 
         @pl.when(t == steps - 1)
         def _finish():
-            x_ref[0] = z[:n].astype(x_ref.dtype)
+            x_ref[0] = y_scr[...][:n].astype(x_ref.dtype)
 
 
 def qr_solve_tiled(a: jax.Array, b: jax.Array, *, bs: int | None = None,
@@ -397,18 +368,18 @@ def qr_solve_tiled(a: jax.Array, b: jax.Array, *, bs: int | None = None,
         interpret = interpret_default()
     steps = n // bs
     x, _ = pl.pallas_call(
-        functools.partial(_qr_solve_tiled_kernel, m=m, n=n, k=k, bs=bs,
-                          steps=steps, tiny=tiny),
+        functools.partial(_qr_solve_tiled_kernel, n=n, bs=bs, steps=steps,
+                          tiny=tiny),
         grid=(bsz, steps + 1, steps),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec((1, m, k), lambda i, s, t: (i, 0, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_specs=[
             pl.BlockSpec((1, n, k), lambda i, s, t: (i, 0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bsz, n, k), b.dtype),
@@ -423,7 +394,7 @@ def qr_solve_tiled(a: jax.Array, b: jax.Array, *, bs: int | None = None,
             pltpu.SMEM((1,), jnp.float32),
             pltpu.SemaphoreType.DMA,
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(a, b)

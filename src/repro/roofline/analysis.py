@@ -1,11 +1,9 @@
 """Roofline derivation from compiled dry-run artifacts.
 
-Three terms (seconds), TPU v5e constants:
-  compute    = HLO_FLOPs / (chips * 197e12 bf16 FLOP/s)
-  memory     = HLO_bytes / (chips * 819e9 B/s HBM)
-  collective = collective_bytes / (chips * 50e9 B/s ICI link)
-               (DCN collectives — ops whose replica groups span pods —
-                are charged at 25 GB/s/host separately)
+Three terms (seconds), with the target chip's peaks from ``PEAKS``:
+  compute    = HLO_FLOPs / (chips * peak bf16 FLOP/s)
+  memory     = HLO_bytes / (chips * peak HBM B/s)
+  collective = collective_bytes / (chips * ICI B/s per link)
 
 cost_analysis() provides flops/bytes; collective bytes are parsed from
 the *optimized* (post-SPMD) HLO text, summing result-shape bytes of each
@@ -21,10 +19,32 @@ import re
 
 import numpy as np
 
-PEAK_FLOPS = 197e12       # bf16 / chip
-HBM_BW = 819e9            # B/s / chip
-ICI_BW = 50e9             # B/s / link
-DCN_BW = 25e9             # B/s / host (cross-pod)
+
+@dataclasses.dataclass(frozen=True)
+class Peaks:
+    flops: float          # bf16 FLOP/s per chip
+    hbm_bw: float         # HBM B/s per chip
+    ici_bw: float         # ICI B/s per link
+
+
+# Per-chip peaks keyed by jax ``Device.device_kind``.  TPU v5e: Google
+# Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16, 819 GB/s HBM,
+# 1,600 Gbit/s ICI per chip over 4 links (50 GB/s each).
+PEAKS = {
+    "TPU v5 lite": Peaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+V5E = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> Peaks:
+    """The peak table's row for ``device_kind``; a device that is not in
+    the table is an error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}") from None
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -85,11 +105,13 @@ class Roofline:
     bottleneck: str = ""
     useful_ratio: float = 0.0
     bytes_per_device: float = 0.0
+    device_kind: str = V5E        # the chip the roofline is drawn for
 
     def finish(self):
-        self.t_compute = self.hlo_flops / (self.chips * PEAK_FLOPS)
-        self.t_memory = self.hlo_bytes / (self.chips * HBM_BW)
-        self.t_collective = self.coll_bytes / (self.chips * ICI_BW)
+        pk = peaks(self.device_kind)
+        self.t_compute = self.hlo_flops / (self.chips * pk.flops)
+        self.t_memory = self.hlo_bytes / (self.chips * pk.hbm_bw)
+        self.t_collective = self.coll_bytes / (self.chips * pk.ici_bw)
         terms = {"compute": self.t_compute, "memory": self.t_memory,
                  "collective": self.t_collective}
         self.bottleneck = max(terms, key=terms.get)
@@ -107,7 +129,8 @@ class Roofline:
         """Model-FLOPs utilization at the roofline-projected step time."""
         if self.step_time == 0:
             return 0.0
-        return self.model_flops / (self.chips * PEAK_FLOPS * self.step_time)
+        return self.model_flops / (
+            self.chips * peaks(self.device_kind).flops * self.step_time)
 
     def to_json(self) -> dict:
         d = dataclasses.asdict(self)

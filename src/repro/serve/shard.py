@@ -7,8 +7,8 @@ of lanes in lockstep and the outputs gather back.  ``LaneShards`` is
 the serve-side handle on that mesh:
 
   * **wrapping** — :meth:`wrap` turns a pipeline entry point into its
-    mesh-spanning form via the version-portable
-    :func:`repro.distributed.sharding.shard_map` shim (``P(axis)`` on
+    mesh-spanning form via
+    :func:`repro.distributed.sharding.shard_map` (``P(axis)`` on
     the batch dim of every input and output; trailing dims replicated).
     Because lanes are independent, the sharded program is bit-identical
     to the single-device launch on the same batch — the property the

@@ -242,3 +242,17 @@ def test_ssm_scan_chunk_invariance():
     y16, _ = ssm_scan_pallas(x, a, bb, cc, chunk=16, interpret=True)
     y64, _ = ssm_scan_pallas(x, a, bb, cc, chunk=64, interpret=True)
     assert_close(y16, y64, rtol=1e-4, name="chunk-invariance")
+
+
+def test_interpret_mode_never_hides_a_tpu(monkeypatch):
+    """Interpret mode is the default off a TPU only; asking for it on a
+    TPU raises instead of running the kernels in the interpreter."""
+    from repro.kernels import common
+    monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
+    monkeypatch.setattr(common, "on_tpu", lambda: False)
+    assert common.interpret_default()
+    monkeypatch.setattr(common, "on_tpu", lambda: True)
+    assert not common.interpret_default()
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+    with pytest.raises(RuntimeError):
+        common.interpret_default()

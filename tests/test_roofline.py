@@ -6,7 +6,8 @@ import numpy as np
 import pytest
 
 from repro.roofline.analysis import (Roofline, collective_bytes,
-                                     model_flops_decode, model_flops_train)
+                                     model_flops_decode, model_flops_train,
+                                     peaks)
 from repro.roofline.hlo_costs import analyze_hlo
 from repro.configs import get_config
 
@@ -89,6 +90,17 @@ def test_roofline_terms_and_bottleneck():
     assert r.useful_ratio == pytest.approx(0.5)
     assert r.step_time == pytest.approx(2.0)
     assert r.mfu == pytest.approx(98.5e12 / (197e12 * 2.0))
+
+
+def test_roofline_peaks_keyed_by_device_kind():
+    """Peaks come from the device-kind table; an unknown kind raises
+    instead of silently borrowing another chip's peaks."""
+    assert peaks("TPU v5 lite").flops == pytest.approx(197e12)
+    r = Roofline(arch="x", shape="y", mesh="single", chips=1,
+                 hlo_flops=1.0, hlo_bytes=1.0, coll_bytes=0.0,
+                 coll_breakdown={}, model_flops=1.0, device_kind="cpu")
+    with pytest.raises(KeyError):
+        r.finish()
 
 
 def test_model_flops_formulas():

@@ -6,7 +6,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed import sharding as shd
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import make_mesh, make_production_mesh
 
 
 def mesh1d():
@@ -69,7 +69,7 @@ def test_param_spec_policy():
 
 
 def test_constrain_under_mesh_runs():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     with shd.axis_rules(mesh):
         f = jax.jit(lambda x: shd.constrain(x * 2, "batch", None))
         out = f(jnp.ones((2, 3)))
@@ -87,12 +87,11 @@ def test_make_production_mesh_requires_devices():
         make_production_mesh(multi_pod=True)
 
 
-# ---------------- version-portable shard_map shim ----------------
+# ---------------- shard_map shim ----------------
 
 def test_shard_map_shim_prefers_new_api(monkeypatch):
-    """When ``jax.shard_map`` exists (newer releases) the shim must call
-    it — forwarding the ``check_vma`` knob under its NEW name, never the
-    legacy ``check_rep``."""
+    """The shim calls ``jax.shard_map``, forwarding the ``check_vma``
+    knob, never the legacy ``check_rep``."""
     seen = {}
 
     def fake(f, *, mesh, in_specs, out_specs, **kw):
@@ -108,22 +107,9 @@ def test_shard_map_shim_prefers_new_api(monkeypatch):
     assert "check_rep" not in seen
 
 
-def test_shard_map_shim_experimental_fallback(monkeypatch):
-    """Without ``jax.shard_map`` the shim must fall back to
-    ``jax.experimental.shard_map`` (``check_rep`` spelling) and still
-    produce a working mesh program — bit-identical to the unsharded
-    computation."""
-    monkeypatch.delattr(jax, "shard_map", raising=False)
-    mesh = jax.make_mesh((2,), ("data",))
-    x = np.arange(8, dtype=np.float32).reshape(2, 4)
-    fn = shd.shard_map(lambda a: a * 2.0, mesh=mesh, in_specs=P("data"),
-                       out_specs=P("data"))
-    np.testing.assert_array_equal(np.asarray(jax.jit(fn)(x)), x * 2.0)
-
-
 def test_shard_map_shim_executes_on_data_mesh():
-    """Whichever branch is live in this jax version, the shim's output
-    matches the plain computation exactly on a real 2-device mesh."""
+    """The shim's output matches the plain computation exactly on a real
+    2-device mesh."""
     mesh = jax.make_mesh((2,), ("data",))
     rng = np.random.default_rng(3)
     x = rng.standard_normal((4, 6)).astype(np.float32)

@@ -84,7 +84,8 @@ def test_spmd_lower_compile_small_mesh():
         from repro.train.trainer import make_train_step
 
         cfg = get_smoke("qwen3-14b")
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         with shd.axis_rules(mesh):
             p_abs = shp.abstract_params(cfg)
             import jax.tree_util as jtu

@@ -1,0 +1,91 @@
+"""Compile every served Pallas kernel for a TPU v5e chip, without a chip.
+
+Interpret mode (what the rest of the suite runs) accepts programs the
+TPU's compiler (Mosaic) refuses: a value sliced at a traced offset, a
+block that breaks the (8, 128) tiling rule.  These tests lower the
+kernels ``chip_smoke.py`` serves with ``interpret=False`` for a
+described ``v5e:2x2`` topology, at the shapes the smoke run serves, and
+check that each compiled program holds the kernel (``tpu_custom_call``).
+
+The topology is described inside a module fixture, never at import: only
+one process at a time may load the TPU library, and the fixture keeps
+that to the worker the file runs on.
+"""
+import functools
+import os
+
+import jax
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro import kernels as K
+
+SMALL = (8, 12, 16, 24, 32)
+
+# (pipeline, expected variant, lanes, per-lane arg shapes) — the buckets
+# chip_smoke.py serves: the slot phase at lanes=8, the large phase at
+# lanes=2, and the pusch_receive stages and chain at lanes=8
+CASES = (
+    [("cholesky_solve", "base", 8, ((n, n), (n, 2))) for n in SMALL]
+    + [("qr_solve", "base", 8, ((n + 4, n), (n + 4, 2))) for n in SMALL]
+    + [("mmse_equalize", "base", 8, ((20, 16), (20, 2))),
+       ("mmse_equalize", "split_complex", 8,
+        ((20, 16), (20, 16), (20, 2), (20, 2))),
+       ("cholesky_solve", "blocked", 2, ((256, 256), (256, 2))),
+       ("qr_solve", "blocked", 2, ((272, 256), (272, 2))),
+       ("mmse_equalize", "base", 2, ((272, 256), (272, 2)))]
+    + [("cholesky_solve", "tiled", 2, ((n, n), (n, 2))) for n in (512, 1024)]
+    + [(p, "tiled", 2, ((n + 16, n), (n + 16, 2)))
+       for p in ("qr_solve", "mmse_equalize") for n in (512, 1024)]
+    + [c for n in (8, 12) for c in (
+        ("pusch_fft", "base", 8, ((n + 4, 64), (n + 4, 64))),
+        ("pusch_chanest", "base", 8, ((n, 2 * n), (n + 4, 2 * n))),
+        ("mmse_equalize", "base", 8, ((n + 4, n), (n + 4, 2))),
+        ("pusch_chain", "base", 8,
+         ((n, 2 * n), (n + 4, 2 * n), (n + 4, 2))))]
+)
+
+
+def _case_id(case):
+    pipeline, variant, lanes, shapes = case
+    return f"{pipeline}-{variant}-{'x'.join(map(str, shapes[0]))}-l{lanes}"
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One device of a described (not attached) v5e:2x2 host, with JAX's
+    persistent compilation cache off: what is compiled for a described
+    chip cannot be read back without one."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.mark.parametrize("case", CASES, ids=_case_id)
+def test_served_kernel_compiles_for_v5e(one_chip, case, record_property):
+    pipeline, variant, lanes, shapes = case
+    spec = K.get(pipeline)
+    v = spec.dispatch_key(shapes, (np.float32,) * len(shapes))
+    assert v.name == variant, (pipeline, shapes, v.name)
+    args = [jax.ShapeDtypeStruct((lanes, *s), np.float32, sharding=one_chip)
+            for s in shapes]
+    compiled = jax.jit(functools.partial(v.fn, interpret=False)) \
+        .lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    record_property("memory_analysis", str(mem))
+    # the program fits one chip's 16 GB of HBM with room to spare
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
+        + mem.output_size_in_bytes < 2 ** 30

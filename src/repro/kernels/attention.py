@@ -126,4 +126,5 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
                                  "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)

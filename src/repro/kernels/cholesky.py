@@ -64,4 +64,5 @@ def cholesky_pallas(a: jax.Array, *, interpret: bool | None = None
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((b, n, n), a.dtype),
         interpret=interpret,
+        name="cholesky",
     )(a)

@@ -122,5 +122,6 @@ def fft_pallas(x_re: jax.Array, x_im: jax.Array, *,
             jax.ShapeDtypeStruct((b, 1, n), x_im.dtype),
         ],
         interpret=interpret,
+        name="fft",
     )(planes(x_re), planes(x_im), jnp.asarray(wr), jnp.asarray(wi))
     return fr[:, 0], fi[:, 0]

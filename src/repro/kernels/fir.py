@@ -62,4 +62,5 @@ def fir_pallas(x: jax.Array, h: jax.Array, *, bo: int = 256,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((1, out), x.dtype),
         interpret=interpret,
+        name="fir",
     )(x[None, :], h)[0]

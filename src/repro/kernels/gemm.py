@@ -60,4 +60,5 @@ def gemm_pallas(x: jax.Array, y: jax.Array, *, bm: int = 128, bn: int = 128,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="gemm",
     )(x, y)

@@ -71,4 +71,5 @@ def qr_pallas(a: jax.Array, *, interpret: bool | None = None):
             jax.ShapeDtypeStruct((b, m, n), a.dtype),
         ],
         interpret=interpret,
+        name="qr",
     )(a)

@@ -121,5 +121,6 @@ def ssm_scan_pallas(x: jax.Array, a: jax.Array, b: jax.Array, c: jax.Array,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="ssm_scan",
     )(x, a, b, c)
     return y, hf

@@ -95,5 +95,6 @@ def svd_pallas(a: jax.Array, *, sweeps: int = 12,
             jax.ShapeDtypeStruct((b, n, n), a.dtype),
         ],
         interpret=interpret,
+        name="svd",
     )(a)
     return u, s[:, 0], v

@@ -60,4 +60,5 @@ def trisolve_pallas(l: jax.Array, b: jax.Array, *, lower: bool = True,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((bsz, n, m), b.dtype),
         interpret=interpret,
+        name="trisolve",
     )(l, b)

@@ -160,6 +160,7 @@ def cholesky_solve_pallas(a: jax.Array, b: jax.Array, *,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name="cholesky_solve",
     )(a, b)
     return (out[0], out[1]) if return_l else out[0]
 
@@ -314,6 +315,7 @@ def cholesky_solve_blocked(a: jax.Array, b: jax.Array, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="cholesky_solve_blocked",
     )(a, b)
 
 
@@ -548,6 +550,7 @@ def cholesky_solve_tiled(a: jax.Array, b: jax.Array, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
+        name="cholesky_solve_tiled",
     )(thr, a, b)
     return x
 

@@ -88,6 +88,7 @@ def mmse_equalize_pallas(h: jax.Array, y: jax.Array, *,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((bsz, n, k), y.dtype),
         interpret=interpret,
+        name="mmse_equalize",
     )(h, y)
 
 
@@ -154,6 +155,7 @@ def mmse_equalize_split_pallas(hr: jax.Array, hi: jax.Array, yr: jax.Array,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((bsz, 2 * n, k), yr.dtype),
         interpret=interpret,
+        name="mmse_split",
     )(hr, hi, yr, yi)
 
 
@@ -355,6 +357,7 @@ def mmse_equalize_tiled(h: jax.Array, y: jax.Array, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
+        name="mmse_equalize_tiled",
     )(h, y)
     return x
 

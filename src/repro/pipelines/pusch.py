@@ -97,6 +97,7 @@ def channel_estimate_pallas(xp: jax.Array, yp: jax.Array, *,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((bsz, m, n), yp.dtype),
         interpret=interpret,
+        name="pusch_chanest",
     )(xp, yp)
 
 
@@ -142,6 +143,7 @@ def pusch_chain_pallas(xp: jax.Array, yp: jax.Array, y: jax.Array, *,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((bsz, n, k), y.dtype),
         interpret=interpret,
+        name="pusch_chain",
     )(xp, yp, y)
 
 
@@ -203,4 +205,5 @@ def svd_apply_pallas(f: jax.Array, b: jax.Array, *,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((bsz, n, k), b.dtype),
         interpret=interpret,
+        name="svd_apply",
     )(f, b)

@@ -127,6 +127,7 @@ def qr_solve_pallas(a: jax.Array, b: jax.Array, *,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((bsz, n, k), b.dtype),
         interpret=interpret,
+        name="qr_solve",
     )(a, b)
 
 
@@ -234,6 +235,7 @@ def qr_solve_blocked(a: jax.Array, b: jax.Array, *, bs: int | None = None,
         out_shape=jax.ShapeDtypeStruct((bsz, n, k), b.dtype),
         scratch_shapes=[pltpu.VMEM((n // bs, m, bs), jnp.float32)],
         interpret=interpret,
+        name="qr_solve_blocked",
     )(a, b)
 
 
@@ -397,6 +399,7 @@ def qr_solve_tiled(a: jax.Array, b: jax.Array, *, bs: int | None = None,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
+        name="qr_solve_tiled",
     )(a, b)
     return x
 

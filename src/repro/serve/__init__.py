@@ -33,6 +33,9 @@ lane-pool accounting + batch lifecycle):
   faults   FaultInjector                (seeded fault injection driving
                                          the launch-supervision /
                                          quarantine / demotion paths)
+  trace    span                         (named host spans of the launch
+                                         path on the profiler's clock,
+                                         on while a profiler records)
   engine   back-compat shim re-exporting the original names
 
 The kernel registry (``repro.kernels``) is the routing table: any

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.serve.metrics import MetricsSnapshot, Recorder
+from repro.serve.trace import span
 
 
 class ManualClock:
@@ -121,7 +122,12 @@ class EngineCore:
         attempts cost no kernel time), a ``nan`` fault poisons the drawn
         output lanes, a ``stall`` fault inflates the measured wall-clock
         (never the scheduling clock).  With no injector or no context
-        the call is exactly the legacy path."""
+        the call is exactly the legacy path.
+
+        Inside the measured wall, the ``serve.core.copy_in``,
+        ``serve.core.execute`` and ``serve.core.copy_out`` spans
+        (:mod:`repro.serve.trace`) mark its three steps while a profiler
+        records."""
         fault = None
         if self.injector is not None and fault_ctx is not None:
             ctx = dict(fault_ctx)
@@ -131,10 +137,14 @@ class EngineCore:
                 from repro.serve.faults import InjectedLaunchError
                 raise InjectedLaunchError(fault.reason)
         t0 = self.wall()
-        inputs = [jnp.asarray(p) for p in padded]
-        if device is not None:
-            inputs = [jax.device_put(x, device) for x in inputs]
-        res = np.asarray(fn(*inputs))
+        with span("serve.core.copy_in"):
+            inputs = [jnp.asarray(p) for p in padded]
+            if device is not None:
+                inputs = [jax.device_put(x, device) for x in inputs]
+        with span("serve.core.execute"):
+            out = fn(*inputs)
+        with span("serve.core.copy_out"):
+            res = np.asarray(out)
         dt = self.wall() - t0
         if fault is not None:
             if fault.kind == "nan":

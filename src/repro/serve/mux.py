@@ -221,6 +221,7 @@ from repro.serve.metrics import shard_stats
 from repro.serve.shard import LaneShards
 from repro.serve.solver import (SolveJob, VariantDispatcher,
                                 resolve_pipeline_spec)
+from repro.serve.trace import span
 from repro.serve.tuning import BucketTuner
 
 
@@ -523,27 +524,28 @@ class SolverMux(EngineCore):
         if priority not in SolveJob.PRIORITIES:
             raise ValueError(f"priority must be one of "
                              f"{SolveJob.PRIORITIES}, got {priority!r}")
-        pool = self._pool(pipeline)
-        self._seq += 1
-        job = SolveJob(args=tuple(np.asarray(a) for a in args),
-                       pipeline=pipeline, deadline=deadline,
-                       submitted_at=self.clock(), seq=self._seq,
-                       priority=priority)
-        if any(a.dtype.kind in "fc" and not np.all(np.isfinite(a))
-               for a in job.args):
-            job.state = "failed"
-            job.reason = "nonfinite_input"
-            job.finished_at = job.submitted_at
-            self.recorder.record_fail(pipeline, job.submitted_at,
-                                      job.priority, "nonfinite_input")
-            self._event("fail", t=job.submitted_at, pipeline=pipeline,
-                        seq=job.seq, reason="nonfinite_input")
+        with span("serve.mux.admit"):
+            pool = self._pool(pipeline)
+            self._seq += 1
+            job = SolveJob(args=tuple(np.asarray(a) for a in args),
+                           pipeline=pipeline, deadline=deadline,
+                           submitted_at=self.clock(), seq=self._seq,
+                           priority=priority)
+            if any(a.dtype.kind in "fc" and not np.all(np.isfinite(a))
+                   for a in job.args):
+                job.state = "failed"
+                job.reason = "nonfinite_input"
+                job.finished_at = job.submitted_at
+                self.recorder.record_fail(pipeline, job.submitted_at,
+                                          job.priority, "nonfinite_input")
+                self._event("fail", t=job.submitted_at, pipeline=pipeline,
+                            seq=job.seq, reason="nonfinite_input")
+                return job
+            pool.enqueue(job)
+            if self.tuner is not None:
+                self.tuner.note_arrival(pipeline, job.shape_key(),
+                                        job.submitted_at)
             return job
-        pool.enqueue(job)
-        if self.tuner is not None:
-            self.tuner.note_arrival(pipeline, job.shape_key(),
-                                    job.submitted_at)
-        return job
 
     # ---------------- DAG jobs ----------------
 
@@ -894,31 +896,33 @@ class SolverMux(EngineCore):
         bisect / terminal per-job ``failed``)."""
         spec = pool.spec
         t = self.clock() if now is None else now
-        if mesh > 1:
-            variant, _ = pool.dispatcher.resolve_sharded(key)
-        else:
-            variant, _ = pool.dispatcher.resolve(key)
-        width = self.lanes * max(1, mesh)
-        riders = tuple(riders)
-        if riders:
-            big_shapes = tuple(shape for shape, _ in key)
-            embedded = [spec.coalesce.embed(j.args, big_shapes)
-                        for j in riders]
-            for lane in embedded:
-                for arr, (shape, dt) in zip(lane, key):
-                    arr = np.asarray(arr)
-                    if arr.shape != tuple(shape) or str(arr.dtype) != dt:
-                        raise ValueError(
-                            f"{spec.name!r} coalesce.embed produced a "
-                            f"{arr.shape}/{arr.dtype} lane; the host "
-                            f"bucket expects {tuple(shape)}/{dt}")
-            stacked = [np.stack([np.asarray(j.args[i]) for j in chunk]
-                                + [np.asarray(e[i]) for e in embedded])
-                       for i in range(len(key))]
-        else:
-            stacked = [np.stack([np.asarray(j.args[i]) for j in chunk])
-                       for i in range(len(chunk[0].args))]
-        padded, pad = pad_group(spec, stacked, width, variant=variant)
+        with span("serve.mux.stack"):
+            if mesh > 1:
+                variant, _ = pool.dispatcher.resolve_sharded(key)
+            else:
+                variant, _ = pool.dispatcher.resolve(key)
+            width = self.lanes * max(1, mesh)
+            riders = tuple(riders)
+            if riders:
+                big_shapes = tuple(shape for shape, _ in key)
+                embedded = [spec.coalesce.embed(j.args, big_shapes)
+                            for j in riders]
+                for lane in embedded:
+                    for arr, (shape, dt) in zip(lane, key):
+                        arr = np.asarray(arr)
+                        if arr.shape != tuple(shape) \
+                                or str(arr.dtype) != dt:
+                            raise ValueError(
+                                f"{spec.name!r} coalesce.embed produced a "
+                                f"{arr.shape}/{arr.dtype} lane; the host "
+                                f"bucket expects {tuple(shape)}/{dt}")
+                stacked = [np.stack([np.asarray(j.args[i]) for j in chunk]
+                                    + [np.asarray(e[i]) for e in embedded])
+                           for i in range(len(key))]
+            else:
+                stacked = [np.stack([np.asarray(j.args[i]) for j in chunk])
+                           for i in range(len(chunk[0].args))]
+            padded, pad = pad_group(spec, stacked, width, variant=variant)
         return self._supervise(pool, key, list(chunk), riders, padded,
                                pad, t, mesh, shard)
 
@@ -1043,38 +1047,41 @@ class SolverMux(EngineCore):
                 failed = True
                 reason = f"launch_exception:{type(e).__name__}"
             if not failed:
-                bad = [i for i in range(real)
-                       if not np.all(np.isfinite(res[i]))]
-                if not bad:
-                    # ---- success ----
-                    self.record_launch(spec.name, key, real, pad,
-                                       variant.name,
-                                       coalesced=len(riders),
-                                       measured=measured, mesh=mesh,
-                                       shard=rec_shard)
-                    if mesh > 1:
-                        self.observe_launch(spec, variant, key,
-                                            real + pad, measured,
-                                            mesh=mesh)
-                    else:
-                        self.observe_launch(spec, variant, key,
-                                            real + pad, measured)
-                    done = self._scatter(pool, chunk, riders, res, t)
-                    pool.dispatcher.note_success(key, variant)
-                    if mesh == 1 and self.shards is not None:
-                        if probing is not None:
-                            since = self.shards.quarantined_at[probing]
-                            down = self.shards.reinstate(probing, t,
-                                                         since)
-                            self._event("reinstate", t=t, shard=probing,
-                                        downtime=_round(down))
+                with span("serve.mux.finish"):
+                    bad = [i for i in range(real)
+                           if not np.all(np.isfinite(res[i]))]
+                    if not bad:
+                        # ---- success ----
+                        self.record_launch(spec.name, key, real, pad,
+                                           variant.name,
+                                           coalesced=len(riders),
+                                           measured=measured, mesh=mesh,
+                                           shard=rec_shard)
+                        if mesh > 1:
+                            self.observe_launch(spec, variant, key,
+                                                real + pad, measured,
+                                                mesh=mesh)
                         else:
-                            self.shards.note_success(shard)
-                    self._watchdog(pool, key, variant, width, mesh,
-                                   measured, t)
-                    self._flush_event(pool, key, chunk, riders, variant,
-                                      t, mesh, rec_shard, shard)
-                    return done
+                            self.observe_launch(spec, variant, key,
+                                                real + pad, measured)
+                        done = self._scatter(pool, chunk, riders, res, t)
+                        pool.dispatcher.note_success(key, variant)
+                        if mesh == 1 and self.shards is not None:
+                            if probing is not None:
+                                since = self.shards.quarantined_at[probing]
+                                down = self.shards.reinstate(probing, t,
+                                                             since)
+                                self._event("reinstate", t=t,
+                                            shard=probing,
+                                            downtime=_round(down))
+                            else:
+                                self.shards.note_success(shard)
+                        self._watchdog(pool, key, variant, width, mesh,
+                                       measured, t)
+                        self._flush_event(pool, key, chunk, riders,
+                                          variant, t, mesh, rec_shard,
+                                          shard)
+                        return done
             # ---- failure accounting ----
             if not failed:
                 reason = "nonfinite_output"
@@ -1121,13 +1128,15 @@ class SolverMux(EngineCore):
         if not failed and bad:
             # executed fine but some real lanes are persistently
             # non-finite: fail exactly those jobs, serve the rest
-            self.record_launch(spec.name, key, real, pad, variant.name,
-                               coalesced=len(riders), measured=measured,
-                               mesh=mesh, shard=rec_shard)
-            done = self._scatter(pool, chunk, riders, res, t,
-                                 bad=set(bad))
-            self._flush_event(pool, key, chunk, riders, variant, t,
-                              mesh, rec_shard, shard)
+            with span("serve.mux.finish"):
+                self.record_launch(spec.name, key, real, pad, variant.name,
+                                   coalesced=len(riders),
+                                   measured=measured, mesh=mesh,
+                                   shard=rec_shard)
+                done = self._scatter(pool, chunk, riders, res, t,
+                                     bad=set(bad))
+                self._flush_event(pool, key, chunk, riders, variant, t,
+                                  mesh, rec_shard, shard)
             return done
         if riders:
             # a poisoned donor must never sink its host: detach the

@@ -5,7 +5,8 @@ TPU's compiler (Mosaic) refuses: a value sliced at a traced offset, a
 block that breaks the (8, 128) tiling rule.  These tests lower the
 kernels ``chip_smoke.py`` serves with ``interpret=False`` for a
 described ``v5e:2x2`` topology, at the shapes the smoke run serves, and
-check that each compiled program holds the kernel (``tpu_custom_call``).
+check that each compiled program holds the kernel (``tpu_custom_call``)
+under the name its ``pallas_call`` gives it.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and the fixture keeps
@@ -13,6 +14,7 @@ that to the worker the file runs on.
 """
 import functools
 import os
+import re
 
 import jax
 import numpy as np
@@ -45,6 +47,25 @@ CASES = (
         ("pusch_chain", "base", 8,
          ((n, 2 * n), (n + 4, 2 * n), (n + 4, 2))))]
 )
+
+
+# the name each served kernel's pallas_call gives its custom call, after
+# its registry entry or variant; the profiler's trace names the device op
+# by it
+KERNEL_NAMES = {
+    ("cholesky_solve", "base"): "cholesky_solve",
+    ("cholesky_solve", "blocked"): "cholesky_solve_blocked",
+    ("cholesky_solve", "tiled"): "cholesky_solve_tiled",
+    ("qr_solve", "base"): "qr_solve",
+    ("qr_solve", "blocked"): "qr_solve_blocked",
+    ("qr_solve", "tiled"): "qr_solve_tiled",
+    ("mmse_equalize", "base"): "mmse_equalize",
+    ("mmse_equalize", "split_complex"): "mmse_split",
+    ("mmse_equalize", "tiled"): "mmse_equalize_tiled",
+    ("pusch_fft", "base"): "fft",
+    ("pusch_chanest", "base"): "pusch_chanest",
+    ("pusch_chain", "base"): "pusch_chain",
+}
 
 
 def _case_id(case):
@@ -83,7 +104,11 @@ def test_served_kernel_compiles_for_v5e(one_chip, case, record_property):
             for s in shapes]
     compiled = jax.jit(functools.partial(v.fn, interpret=False)) \
         .lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    name = KERNEL_NAMES[pipeline, variant]
+    assert re.search(rf'%{name}(\.\d+)? = [^\n]*custom-call\([^\n]*'
+                     rf'custom_call_target="tpu_custom_call"', text), name
     mem = compiled.memory_analysis()
     record_property("memory_analysis", str(mem))
     # the program fits one chip's 16 GB of HBM with room to spare

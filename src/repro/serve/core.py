@@ -104,12 +104,21 @@ class EngineCore:
         self.recorder.reset()
 
     def _timed_call(self, fn, padded: list, device=None,
-                    fault_ctx: dict | None = None
-                    ) -> tuple[np.ndarray, float]:
-        """Execute one padded lane-group launch and measure its wall
-        clock on ``self.wall``.  The one seam every launch goes through:
+                    fault_ctx: dict | None = None) -> tuple[object, float]:
+        """Start one padded lane-group launch and measure its wall clock
+        on ``self.wall``.  The one seam every launch goes through:
         deterministic tests replace it with a synthetic wall model to
         drive the calibration loop without real-timer noise.
+
+        Returns ``(answer, dt)``: the answer is still in flight, its copy
+        back to the host started (``copy_to_host_async``) right after
+        dispatch, and ``dt`` is the wall of the steps taken so far.
+        :meth:`_gather` waits for the answer and adds that wait, so a
+        caller may start the next launch in between and the copy back
+        lands while the host prepares it (``SolverMux`` does, in a
+        bucket flush).  ``answer`` may also be a host array already (a
+        ``nan`` fault below, or a wrapper of this method); gathering it
+        costs nothing.
 
         ``device`` commits the inputs to one mesh shard's device before
         the call (mesh-sharded muxes placing a non-spanning launch);
@@ -119,15 +128,16 @@ class EngineCore:
         :class:`repro.serve.faults.FaultInjector` (``self.injector``):
         a drawn ``raise`` fault aborts BEFORE the kernel executes
         (:class:`~repro.serve.faults.InjectedLaunchError` — failed
-        attempts cost no kernel time), a ``nan`` fault poisons the drawn
-        output lanes, a ``stall`` fault inflates the measured wall-clock
-        (never the scheduling clock).  With no injector or no context
-        the call is exactly the legacy path.
+        attempts cost no kernel time), a ``nan`` fault gathers the
+        answer at once and poisons the drawn output lanes, a ``stall``
+        fault inflates the measured wall-clock (never the scheduling
+        clock).  With no injector or no context the call is exactly
+        the legacy path.
 
-        Inside the measured wall, the ``serve.core.copy_in``,
-        ``serve.core.execute`` and ``serve.core.copy_out`` spans
-        (:mod:`repro.serve.trace`) mark its three steps while a profiler
-        records."""
+        Inside the measured wall, the ``serve.core.copy_in`` and
+        ``serve.core.execute`` spans (:mod:`repro.serve.trace`) mark its
+        two steps while a profiler records; :meth:`_gather` adds the
+        third, ``serve.core.copy_out``."""
         fault = None
         if self.injector is not None and fault_ctx is not None:
             ctx = dict(fault_ctx)
@@ -143,18 +153,28 @@ class EngineCore:
                 inputs = [jax.device_put(x, device) for x in inputs]
         with span("serve.core.execute"):
             out = fn(*inputs)
-        with span("serve.core.copy_out"):
-            res = np.asarray(out)
+            out.copy_to_host_async()
         dt = self.wall() - t0
         if fault is not None:
             if fault.kind == "nan":
-                res = np.array(res)            # writable copy
+                out, dt = self._gather(out, dt)
+                out = np.array(out)            # writable copy
                 for lane in fault.lanes:
-                    if 0 <= lane < res.shape[0]:
-                        res[lane] = np.nan
+                    if 0 <= lane < out.shape[0]:
+                        out[lane] = np.nan
             elif fault.kind == "stall":
                 dt += fault.stall
-        return res, dt
+        return out, dt
+
+    def _gather(self, answer, dt: float) -> tuple[np.ndarray, float]:
+        """The answer of a launch :meth:`_timed_call` started, on the
+        host, and the launch's measured wall: ``dt`` plus the wait here
+        (the ``serve.core.copy_out`` span).  The wall is the launch's
+        own cost, never the steps of a launch started in between."""
+        t0 = self.wall()
+        with span("serve.core.copy_out"):
+            res = np.asarray(answer)
+        return res, dt + self.wall() - t0
 
     def observe_launch(self, spec, variant, key: tuple, lanes: int,
                        measured: float, mesh: int = 1) -> None:
@@ -192,7 +212,8 @@ class EngineCore:
         stacked = [np.stack([np.asarray(j.args[i]) for j in jobs])
                    for i in range(len(jobs[0].args))]
         padded, pad = pad_group(spec, stacked, width, variant=variant)
-        res, measured = self._timed_call(fn, padded, device=device)
+        res, measured = self._gather(*self._timed_call(fn, padded,
+                                                       device=device))
         self.record_launch(spec.name, key, len(jobs), pad,
                            variant.name if variant is not None else "base",
                            measured=measured, mesh=mesh, shard=shard)

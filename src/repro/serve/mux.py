@@ -298,6 +298,30 @@ class _Candidate:
 
 
 @dataclasses.dataclass(eq=False)
+class _Started:
+    """One launch :meth:`SolverMux._begin` has stacked, placed and
+    started: its current attempt's answer is on its way back to the host
+    (``answer``, ``(array, dt)`` as ``EngineCore._timed_call`` returns
+    it), or ``answer`` is None and ``reason`` says why the attempt
+    raised.  :meth:`SolverMux._supervise` gathers and finishes it."""
+
+    pool: "_LanePool"
+    key: tuple
+    chunk: list
+    riders: tuple
+    padded: list
+    pad: int
+    t: float
+    mesh: int
+    shard: int | None
+    device: object = None
+    probing: int | None = None      # the quarantined shard it probes
+    variant: object = None
+    answer: tuple | None = None
+    reason: str = "launch_failed"
+
+
+@dataclasses.dataclass(eq=False)
 class DagJob:
     """One submitted DAG (``SolverMux.submit_dag``): a set of stage
     :class:`SolveJob` s the mux advances through the declared
@@ -879,8 +903,9 @@ class SolverMux(EngineCore):
                 mesh: int = 1, shard: int | None = None) -> list:
         """One supervised grid launch: ``chunk`` jobs of the (pool, key)
         bucket plus optional cross-shape ``riders`` embedded into
-        otherwise-padded lanes.  Records the launch + per-job latencies
-        and logs a ``flush`` event.
+        otherwise-padded lanes, started and finished before it returns.
+        Records the launch + per-job latencies and logs a ``flush``
+        event.
 
         On a mesh, ``mesh > 1`` runs the shard_map-wrapped spanning form
         (lane axis split over the mesh, padded to ``lanes * mesh`` so
@@ -894,6 +919,14 @@ class SolverMux(EngineCore):
         scheduler bugs, not launch faults; execution goes through
         :meth:`_supervise`, which contains failures instead (retry /
         bisect / terminal per-job ``failed``)."""
+        return self._supervise(self._begin(pool, key, chunk, riders, now,
+                                           mesh, shard))
+
+    def _begin(self, pool: _LanePool, key: tuple, chunk: list,
+               riders: tuple = (), now: float | None = None,
+               mesh: int = 1, shard: int | None = None) -> _Started:
+        """Stack and pad the launch, place it, and start its first
+        attempt (:meth:`_start`); :meth:`_supervise` finishes it."""
         spec = pool.spec
         t = self.clock() if now is None else now
         with span("serve.mux.stack"):
@@ -923,8 +956,48 @@ class SolverMux(EngineCore):
                 stacked = [np.stack([np.asarray(j.args[i]) for j in chunk])
                            for i in range(len(chunk[0].args))]
             padded, pad = pad_group(spec, stacked, width, variant=variant)
-        return self._supervise(pool, key, list(chunk), riders, padded,
-                               pad, t, mesh, shard)
+        launch = _Started(pool, key, list(chunk), riders, padded, pad, t,
+                          mesh, shard)
+        if mesh == 1 and self.shards is not None:
+            # a quarantined shard owed a probe gets this launch; else
+            # place on the least-loaded healthy shard
+            while self._probe_ready and launch.probing is None:
+                p = self._probe_ready.pop(0)
+                if self.shards.quarantined(p):
+                    launch.shard = launch.probing = p
+            if launch.probing is None and (
+                    launch.shard is None
+                    or self.shards.quarantined(launch.shard)):
+                launch.shard = self.shards.pick(
+                    among=self.shards.healthy())
+            launch.device = self.shards.devices[launch.shard]
+        self._start(launch)
+        return launch
+
+    def _start(self, launch: _Started) -> None:
+        """Start one attempt of ``launch``: resolve its entry point and
+        call :meth:`_timed_call`, keeping the in-flight answer, or the
+        reason the call raised."""
+        pool, key, mesh = launch.pool, launch.key, launch.mesh
+        # re-resolve each attempt: a mid-supervision demotion swaps
+        # the entry point (demotable variants share the spec's
+        # calling convention, so the prepared group is reusable)
+        if mesh > 1:
+            launch.variant, fn = pool.dispatcher.resolve_sharded(key)
+        else:
+            launch.variant, fn = pool.dispatcher.resolve(key)
+        ctx = {"pipeline": pool.spec.name, "variant": launch.variant.name,
+               "width": self.lanes * max(1, mesh), "mesh": mesh,
+               "shard": None if mesh > 1 else launch.shard, "t": launch.t}
+        launch.answer = None
+        try:
+            launch.answer = self._timed_call(fn, launch.padded,
+                                             device=launch.device,
+                                             fault_ctx=ctx)
+        except InjectedLaunchError as e:
+            launch.reason = str(e) or "launch_failed"
+        except Exception as e:              # noqa: BLE001 — contained
+            launch.reason = f"launch_exception:{type(e).__name__}"
 
     def _scatter(self, pool: _LanePool, chunk: list, riders: tuple,
                  res, t: float, bad: set | None = None) -> list:
@@ -994,31 +1067,21 @@ class SolverMux(EngineCore):
                         variant=variant.name, measured=_round(measured),
                         predicted=_round(predicted))
 
-    def _supervise(self, pool: _LanePool, key: tuple, chunk: list,
-                   riders: tuple, padded: list, pad: int, t: float,
-                   mesh: int, shard: int | None) -> list:
-        """Supervised execution of one prepared launch: the attempt loop
-        plus the containment ladder (module docstring).  Returns the
-        terminal jobs — every ``chunk`` job comes back ``done`` or
-        ``failed``; detached riders come back still ``queued`` (the
-        policy dispatcher only dequeues terminal jobs)."""
+    def _supervise(self, launch: _Started) -> list:
+        """Supervised execution of one started launch: gather each
+        attempt's answer, then the attempt loop plus the containment
+        ladder (module docstring).  Returns the terminal jobs — every
+        ``chunk`` job comes back ``done`` or ``failed``; detached riders
+        come back still ``queued`` (the policy dispatcher only dequeues
+        terminal jobs)."""
+        pool, key, chunk, riders = (launch.pool, launch.key, launch.chunk,
+                                    launch.riders)
+        pad, t, mesh = launch.pad, launch.t, launch.mesh
         spec = pool.spec
         real = len(chunk) + len(riders)
         width = self.lanes * max(1, mesh)
-        device = None
-        probing = None
-        if mesh == 1 and self.shards is not None:
-            # a quarantined shard owed a probe gets this launch; else
-            # place on the least-loaded healthy shard
-            while self._probe_ready and probing is None:
-                p = self._probe_ready.pop(0)
-                if self.shards.quarantined(p):
-                    shard = probing = p
-            if probing is None and (shard is None
-                                    or self.shards.quarantined(shard)):
-                shard = self.shards.pick(among=self.shards.healthy())
-            device = self.shards.devices[shard]
-        rec_shard = -1 if mesh > 1 else (shard if shard is not None
+        rec_shard = -1 if mesh > 1 else (launch.shard
+                                         if launch.shard is not None
                                          else 0)
         tried: set[int] = set()
         reason = "launch_failed"
@@ -1026,26 +1089,18 @@ class SolverMux(EngineCore):
         bad: list[int] = []
         res = measured = None
         for attempt in range(self.max_retries + 1):
-            # re-resolve each attempt: a mid-supervision demotion swaps
-            # the entry point (demotable variants share the spec's
-            # calling convention, so the prepared group is reusable)
-            if mesh > 1:
-                variant, fn = pool.dispatcher.resolve_sharded(key)
+            if attempt:
+                self._start(launch)
+            variant, shard = launch.variant, launch.shard
+            failed, bad = launch.answer is None, []
+            if failed:
+                reason = launch.reason
             else:
-                variant, fn = pool.dispatcher.resolve(key)
-            ctx = {"pipeline": spec.name, "variant": variant.name,
-                   "width": width, "mesh": mesh,
-                   "shard": None if mesh > 1 else shard, "t": t}
-            failed, bad = False, []
-            try:
-                res, measured = self._timed_call(fn, padded,
-                                                 device=device,
-                                                 fault_ctx=ctx)
-            except InjectedLaunchError as e:
-                failed, reason = True, str(e) or "launch_failed"
-            except Exception as e:          # noqa: BLE001 — contained
-                failed = True
-                reason = f"launch_exception:{type(e).__name__}"
+                try:
+                    res, measured = self._gather(*launch.answer)
+                except Exception as e:      # noqa: BLE001 — contained
+                    failed = True
+                    reason = f"launch_exception:{type(e).__name__}"
             if not failed:
                 with span("serve.mux.finish"):
                     bad = [i for i in range(real)
@@ -1067,6 +1122,7 @@ class SolverMux(EngineCore):
                         done = self._scatter(pool, chunk, riders, res, t)
                         pool.dispatcher.note_success(key, variant)
                         if mesh == 1 and self.shards is not None:
+                            probing = launch.probing
                             if probing is not None:
                                 since = self.shards.quarantined_at[probing]
                                 down = self.shards.reinstate(probing, t,
@@ -1115,15 +1171,15 @@ class SolverMux(EngineCore):
                             backoff=_round(backoff))
                 if mesh == 1 and self.shards is not None and failed:
                     # re-place away from the shard that just failed
-                    probing = None
+                    launch.probing = None
                     tried.add(shard)
                     pickable = ([s for s in self.shards.healthy()
                                  if s not in tried]
                                 or self.shards.healthy()
                                 or list(range(self.shards.size)))
-                    shard = self.shards.pick(among=pickable)
-                    device = self.shards.devices[shard]
-                    rec_shard = shard
+                    launch.shard = rec_shard = self.shards.pick(
+                        among=pickable)
+                    launch.device = self.shards.devices[launch.shard]
         # ---- retries exhausted: contain, never propagate ----
         if not failed and bad:
             # executed fine but some real lanes are persistently
@@ -1184,7 +1240,12 @@ class SolverMux(EngineCore):
         """Dispatch a bucket in lane-group chunks.  ``full_only`` leaves
         the trailing partial chunk queued (continuous-batching path).
         On a mesh, a backlog of at least ``lanes * mesh_size`` drains in
-        mesh-spanning launches first; the remainder goes per-shard."""
+        mesh-spanning launches first; the remainder goes per-shard.
+
+        The chunks' launches overlap two deep (:meth:`_launch_chunks`):
+        launch k's answer comes back to the host while launch k+1 is
+        stacked, copied in and dispatched.  Every launch is finished
+        before this returns, so callers never see a job in flight."""
         jobs = pool.buckets[key]
         done: list[SolveJob] = []
         if self.shards is not None and self.shards.all_healthy():
@@ -1195,17 +1256,56 @@ class SolverMux(EngineCore):
                 chunk, jobs = jobs[:total], jobs[total:]
                 done.extend(self._launch(pool, key, chunk, now=now,
                                          mesh=self.shards.size))
+        chunks = []
         while len(jobs) >= self.lanes:
             chunk, jobs = jobs[:self.lanes], jobs[self.lanes:]
-            done.extend(self._launch(pool, key, chunk, now=now))
+            chunks.append(chunk)
         if jobs and not full_only:
-            done.extend(self._launch(pool, key, jobs, now=now))
+            chunks.append(jobs)
             jobs = []
+        done.extend(self._launch_chunks(pool, key, chunks, now))
         if jobs:
             pool.buckets[key] = jobs
         else:
             del pool.buckets[key]
             pool.age.pop(key, None)
+        return done
+
+    def _launch_chunks(self, pool: _LanePool, key: tuple, chunks: list,
+                       now: float | None) -> list[SolveJob]:
+        """Launch ``chunks`` of one bucket in order, each supervised.
+
+        Where nothing reads the order of the host steps, two launches
+        are in flight at once: launch k+1 is begun (stacked, copied in,
+        dispatched, its copy back started) before launch k is gathered
+        and finished, so k's copy back lands while the host works on
+        k+1.  The kernels, their inputs and the answers are the same;
+        each ``LaunchRecord.measured`` is still its own launch's steps,
+        and the events come in the same order.  A failure found at k's
+        gather runs k's retry / bisect ladder there, with k+1 in flight.
+
+        Launches stay one at a time where the serial order is part of
+        what the mux promises: with a fault injector (its seeded draws
+        are replayed in launch order) and on a mesh (placement reads
+        the load the previous launch noted as it finished).  The
+        overload policy's rounds never come here: they launch one
+        admitted candidate at a time (:meth:`_poll_policy`)."""
+        if self.injector is not None or self.shards is not None:
+            return [job for chunk in chunks
+                    for job in self._launch(pool, key, chunk, now=now)]
+        done: list[SolveJob] = []
+        held = None
+        try:
+            for chunk in chunks:
+                started = self._begin(pool, key, chunk, now=now)
+                prev, held = held, started
+                if prev is not None:
+                    done.extend(self._supervise(prev))
+        finally:
+            # a preparation error in the next chunk still finishes the
+            # launch already in flight, as the serial order would have
+            if held is not None:
+                done.extend(self._supervise(held))
         return done
 
     def _bucket_max_wait(self, pool: "_LanePool | None", key: tuple,

@@ -17,23 +17,31 @@ The six spans are leaves: none contains another, so their totals add.
 
   serve.mux.admit     ``SolverMux.submit``: the arguments to arrays, the
                       finite admission scan, enqueue, the tuner's note
-  serve.mux.stack     ``SolverMux._launch``: variant resolve, rider
+  serve.mux.stack     ``SolverMux._begin``: variant resolve, rider
                       embedding, one ``np.stack`` per argument, filler
                       padding (``pad_group``)
   serve.core.copy_in  ``EngineCore._timed_call``: each padded plane to a
                       device array (``jnp.asarray``, ``device_put`` when
                       the launch is placed on a shard)
   serve.core.execute  the call of the jitted entry point until it
-                      returns: dispatch, not the kernel's completion
-  serve.core.copy_out ``np.asarray`` of the result: waits for the kernel
-                      and copies the answer back to the host
-  serve.mux.finish    ``SolverMux._supervise`` once a call returned: the
-                      per-lane finite check, ``record_launch``,
+                      returns (dispatch, not the kernel's completion),
+                      and the start of the answer's copy back to the host
+  serve.core.copy_out ``EngineCore._gather``: ``np.asarray`` of the
+                      answer, waiting for whatever of the kernel and the
+                      copy back has not finished yet
+  serve.mux.finish    ``SolverMux._supervise`` once an answer is on the
+                      host: the per-lane finite check, ``record_launch``,
                       ``observe_launch``, scatter with ``record_job`` per
                       job, the watchdog and the ``flush`` event
 
-The three ``serve.core`` spans lie inside the wall that
+A launch's three ``serve.core`` spans lie inside the wall that
 ``LaunchRecord.measured`` takes, so together they come to that wall.
+They do not always run back to back.  In a bucket flush of several lane
+groups (``SolverMux._launch_chunks``) launch k+1's stack, copy_in and
+execute come before launch k's copy_out and finish, so k's copy back
+lands while the host prepares k+1; ``measured`` leaves k+1's steps out.
+A flush of one launch, a mux with a fault injector or a mesh, and the
+overload policy's rounds keep the order above, launch by launch.
 
 To see them for a running server::
 

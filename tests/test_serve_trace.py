@@ -76,22 +76,28 @@ def test_off_builds_no_annotation(monkeypatch):
 
 
 def test_on_each_launch_emits_its_leaves_in_order(tmp_path):
+    """The poll's two full pairs overlap: the second launch is stacked,
+    copied in and dispatched before the first is gathered and finished.
+    The drain's lone launch runs its five steps back to back."""
     with jax.profiler.trace(str(tmp_path)):
         mux = serve()
     events = program_spans(tmp_path)
     names = [n for _, _, n in events]
     launches = len(mux.metrics().launches)
     assert launches == 3
-    assert names == ["serve.mux.admit"] * 5 + list(LAUNCH) * launches
+    begin, end = list(LAUNCH[:3]), list(LAUNCH[3:])
+    assert names == (["serve.mux.admit"] * 5 + begin + begin + end + end
+                     + list(LAUNCH))
     # leaves: none overlaps the next
-    for (_, end, _), (start, _, _) in zip(events, events[1:]):
-        assert end <= start
+    for (_, end_ns, _), (start, _, _) in zip(events, events[1:]):
+        assert end_ns <= start
 
 
 def test_the_core_spans_lie_inside_the_measured_wall(tmp_path):
     """Each launch's copy_in, execute and copy_out follow one another
-    inside its launch, and together come to no more than the launch's
-    wall on the real clock."""
+    (the next launch's copy_in and execute come between the last two),
+    and together come to no more than the launch's wall on the real
+    clock."""
     with jax.profiler.trace(str(tmp_path)):
         mux = SolverMux(lanes=2, clock=ManualClock())
         for seed in range(4):
@@ -102,9 +108,13 @@ def test_the_core_spans_lie_inside_the_measured_wall(tmp_path):
     core = [e for e in events if e[2].startswith("serve.core.")]
     walls = [lr.measured for lr in mux.metrics().launches]
     assert len(core) == 3 * len(walls) == 6
+    assert [n for _, _, n in core] == list(LAUNCH[1:3]) * 2 \
+        + [LAUNCH[3]] * 2
     for i, wall in enumerate(walls):
-        steps = core[3 * i:3 * i + 3]
+        steps = [core[2 * i], core[2 * i + 1], core[4 + i]]
         assert [n for _, _, n in steps] == list(LAUNCH[1:4])
+        for (_, end_ns, _), (start, _, _) in zip(steps, steps[1:]):
+            assert end_ns <= start
         assert sum(e - s for s, e, _ in steps) * 1e-9 <= wall
 
 
@@ -112,8 +122,10 @@ def test_measured_is_the_same_with_spans_on(tmp_path):
     off = [lr.measured for lr in serve().metrics().launches]
     with jax.profiler.trace(str(tmp_path)):
         on = [lr.measured for lr in serve().metrics().launches]
-    # two readings of the ticking measurement clock per launch
-    assert on == off == [1.0, 1.0, 1.0]
+    # four readings of the ticking measurement clock per launch: around
+    # its copy in and dispatch, and around its gather; the next launch's
+    # readings in between are not its own
+    assert on == off == [2.0, 2.0, 2.0]
 
 
 def test_an_injected_stall_inflates_only_measured(tmp_path):
@@ -125,7 +137,7 @@ def test_an_injected_stall_inflates_only_measured(tmp_path):
         on = serve(injector=stalled())
     for mux in (off, on):
         assert [lr.measured for lr in mux.metrics().launches] \
-            == [31.0, 31.0, 31.0]
+            == [32.0, 32.0, 32.0]
     assert on.events == off.events
     # the spans time the real steps; the stall is added to measured only
     core = [e - s for s, e, n in program_spans(tmp_path)

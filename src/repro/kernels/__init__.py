@@ -86,7 +86,9 @@ class Variant:
     padding lane), and ``make_case``; ``None`` inherits the spec's.
     ``sizes`` is the variant's default bench/test sweep and ``flops`` an
     optional closed-form model-FLOP count over per-lane shapes (feeds
-    BENCH_pipelines.json).
+    BENCH_pipelines.json).  ``run_shapes`` maps a job's per-lane shapes
+    to the shapes the entry point pads each lane to and runs at (the
+    tiled variants' whole 128-wide slabs); ``None``: the job's own.
     """
 
     name: str
@@ -97,6 +99,7 @@ class Variant:
     make_case: Callable | None = None
     sizes: tuple[int, ...] = ()
     flops: Callable | None = None
+    run_shapes: Callable | None = None
 
     def model_flops(self, shapes) -> float:
         """Closed-form model FLOPs for ONE lane at per-lane arg shapes —
@@ -109,6 +112,19 @@ class Variant:
         if shapes and shapes[0]:
             return float(np.prod(shapes[0]))
         return 1.0
+
+    def pad_flops(self, shapes, real: int, width: int) -> float:
+        """Model FLOPs a ``width``-lane launch of ``real`` jobs at
+        per-lane ``shapes`` spends on padding: each filler lane whole,
+        plus the rows and columns ``run_shapes`` pads every real lane
+        by (each at the :meth:`model_flops` model)."""
+        # nothing padded: a full launch of small jobs skips the models
+        if self.run_shapes is None and real == width:
+            return 0.0
+        run = shapes if self.run_shapes is None \
+            else self.run_shapes(tuple(tuple(s) for s in shapes))
+        return (width * self.model_flops(run)
+                - real * self.model_flops(shapes))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -665,15 +681,28 @@ def _register_all() -> None:
 
     def _tiled_when(shapes, dtypes):
         """HBM-scale tiled applicability: two (matrix, rhs) args at
-        n >= 512 tiling evenly into the (n, bs) DMA slabs (bs falls
-        back 128 -> 64 -> 32, so n % 32 == 0 suffices — the same
-        divisibility the blocked kernels need, ensuring NO n >= 512
-        shape the registry can serve falls back to a whole-matrix VMEM
-        kernel).  Listed BEFORE ``blocked`` in each variants table so
-        large shapes leave VMEM-residency behind; the midrange stays on
-        the blocked kernels."""
+        n >= 512 with n % 32 == 0 — the same divisibility the blocked
+        kernels need, ensuring NO n >= 512 shape the registry can serve
+        falls back to a whole-matrix VMEM kernel.  The tiled entry
+        points run every such n in 128-wide (n, 128) DMA slabs, padding
+        an n that 128 does not divide up to whole slabs
+        (``_tiled_run_shapes``).  Listed BEFORE ``blocked`` in each
+        variants table so large shapes leave VMEM-residency behind; the
+        midrange stays on the blocked kernels."""
         return (len(shapes) == 2 and len(shapes[0]) == 2
                 and shapes[0][-1] >= 512 and shapes[0][-1] % 32 == 0)
+
+    def _tiled_run_shapes(shapes):
+        """Shapes a tiled Cholesky / QR lane runs at: p identity rows
+        and columns, p zero rhs rows, p the pad to whole slabs."""
+        (m, n), (_, k) = shapes
+        p = pp.tiled_padded_n(n) - n
+        return ((m + p, n + p), (m + p, k))
+
+    def _mmse_tiled_run_shapes(shapes):
+        """Shapes a tiled MMSE lane runs at: p zero channel columns."""
+        (m, n), rhs = shapes
+        return ((m, pp.tiled_padded_n(n)), rhs)
 
     # One lane and a narrow rhs keep the n >= 512 registry cases cheap
     # enough for CI's interpret-mode dispatch sweep while still proving
@@ -714,7 +743,8 @@ def _register_all() -> None:
         variants=(
             Variant(name="tiled", fn=pp.cholesky_solve_tiled,
                     when=_tiled_when, make_case=_chol_tiled_case,
-                    sizes=(512, 1024), flops=_chol_solve_flops),
+                    sizes=(512, 1024), flops=_chol_solve_flops,
+                    run_shapes=_tiled_run_shapes),
             Variant(name="blocked", fn=pp.cholesky_solve_blocked,
                     when=_blocked_when, sizes=(128, 256),
                     flops=_chol_solve_flops))))
@@ -746,7 +776,8 @@ def _register_all() -> None:
         variants=(
             Variant(name="tiled", fn=pp.qr_solve_tiled,
                     when=_tiled_when, make_case=_tall_tiled_case,
-                    sizes=(512, 1024), flops=_qr_solve_flops),
+                    sizes=(512, 1024), flops=_qr_solve_flops,
+                    run_shapes=_tiled_run_shapes),
             Variant(name="blocked", fn=pp.qr_solve_blocked,
                     when=_blocked_when, sizes=(128, 256),
                     flops=_qr_solve_flops))))
@@ -816,7 +847,8 @@ def _register_all() -> None:
                     flops=_mmse_split_flops),
             Variant(name="tiled", fn=pp.mmse_equalize_tiled,
                     when=_tiled_when, make_case=_tall_tiled_case,
-                    sizes=(512, 1024), flops=_mmse_flops))))
+                    sizes=(512, 1024), flops=_mmse_flops,
+                    run_shapes=_mmse_tiled_run_shapes))))
 
     # ---------------- DAG stage pipelines (PUSCH + SVD-solve) ----------
     # Per-lane DAG geometry: A = n + 4 antennas, NF-point OFDM FFT, the

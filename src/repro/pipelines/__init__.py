@@ -35,14 +35,16 @@ dispatcher (``KernelSpec.dispatch``) selects by shape/arity: blocked
 / ``qr_solve_blocked`` for the 128 <= n < 512 midrange, true
 sub-matrix-tiled ``cholesky_solve_tiled`` / ``qr_solve_tiled`` /
 ``mmse_equalize_tiled`` (HBM-resident matrix, O(n*bs) VMEM slabs, DMA'd
-per grid cell) for n >= 512, and the split re/im ``mmse_equalize_split``
-fast path for jobs arriving as 4 complex planes.
+per grid cell, n padded up to whole 128-wide slabs) for n >= 512, and the
+split re/im ``mmse_equalize_split`` fast path for jobs arriving as 4
+complex planes.
 """
 from repro.pipelines.cholesky_solve import (cholesky_solve,  # noqa: F401
                                             cholesky_solve_blocked,
                                             cholesky_solve_pallas,
                                             cholesky_solve_tiled,
                                             cholesky_solve_unfused,
+                                            tiled_padded_n,
                                             tiled_vmem_floats)
 from repro.pipelines.mmse import (expand_complex_channel,  # noqa: F401
                                   mmse_equalize, mmse_equalize_blocked,
@@ -72,4 +74,5 @@ __all__ = [
     "channel_estimate_pallas", "pusch_chain_pallas", "pusch_fft_pallas",
     "svd_apply_pallas", "svd_factor_pallas",
     "tiled_vmem_floats", "qr_tiled_vmem_floats", "mmse_tiled_vmem_floats",
+    "tiled_padded_n",
 ]

@@ -353,15 +353,43 @@ def cholesky_solve_blocked(a: jax.Array, b: jax.Array, *,
 TILED_VMEM_BUDGET_BYTES = 14 * 2 ** 20
 
 
-def tiled_block_size(n: int) -> int:
-    """Default slab width: the largest of {128, 64, 32} dividing n, so
-    every n % 32 == 0 shape the dispatcher can route here (the variant
-    predicate's requirement) actually tiles — n = 1888 must not fall
-    back to a whole-matrix VMEM kernel for want of a 64-divisor."""
-    for bs in (128, 64, 32):
-        if n % bs == 0:
-            return bs
-    raise ValueError(f"n={n} does not tile into 32-wide slabs")
+# Slab width of the tiled kernels when the caller gives none.  Mosaic
+# DMAs a column slab out of a ``pl.ANY`` ref only when the slice's lane
+# extent is a whole number of 128-lane tiles, so served shapes always run
+# at 128 and an n that 128 does not divide is padded up to whole slabs
+# (``tiled_padded_n``).  A caller passing ``bs`` (the CPU tests' 32 and
+# 64) gets no padding and must tile n evenly.
+TILED_BS = 128
+
+# Named scope of the padding ops around a tiled kernel, so that a trace
+# can tell them apart from the kernel.
+TILED_PAD_SCOPE = "tiled_pad"
+
+
+def tiled_padded_n(n: int) -> int:
+    """The width a tiled kernel runs an n-column problem at when no
+    ``bs`` is given: n rounded up to whole ``TILED_BS`` slabs."""
+    return -(-n // TILED_BS) * TILED_BS
+
+
+def pad_identity(a: jax.Array, p: int) -> jax.Array:
+    """``[[a, 0], [0, I]]``: each (m, n) lane of ``a`` (B, m, n) grown by
+    ``p`` rows and ``p`` columns, an identity block in the new corner.
+    The padded unknowns decouple from the real ones (the Cholesky factor
+    of ``diag(A, I)`` is ``diag(L, I)``; the least-squares problem splits
+    in two, the identity block's alone) and solve to 0 against a zero
+    right-hand side, so the first n unknowns are the unpadded solution."""
+    _, m, n = a.shape
+    a = jnp.pad(a, ((0, 0), (0, p), (0, p)))
+    rows = jax.lax.broadcasted_iota(jnp.int32, (m + p, n + p), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (m + p, n + p), 1)
+    corner = (rows - m == cols - n) & (cols >= n)
+    return jnp.where(corner, jnp.ones((), a.dtype), a)
+
+
+def pad_rows(b: jax.Array, p: int) -> jax.Array:
+    """``b`` (B, m, k) with ``p`` zero rows appended to each lane."""
+    return jnp.pad(b, ((0, 0), (0, p), (0, 0)))
 
 
 def tiled_vmem_floats(n: int, bs: int, m: int) -> int:
@@ -507,21 +535,33 @@ def cholesky_solve_tiled(a: jax.Array, b: jax.Array, *,
     the first panel cell needs it before any other slab is seen.
     Registered as the ``tiled`` variant of the ``cholesky_solve`` spec;
     the dispatcher picks it for N >= 512.
+
+    With ``bs`` unset the slabs are ``TILED_BS`` wide and an N they do
+    not divide runs as ``[[A, 0], [0, I]]`` against ``[b; 0]`` at
+    ``tiled_padded_n(N)`` (padded here, on the device, under the
+    ``tiled_pad`` scope); the answer's first N rows come back.
     """
     bsz, n, n2 = a.shape
     b2, n3, m = b.shape
     assert n == n2 == n3 and bsz == b2, (a.shape, b.shape)
+    n_job = n
+    # the threshold of the job's own diagonal: the padded unit pivots
+    # must not move it
+    diag = jnp.diagonal(a, axis1=-2, axis2=-1)
+    thr = jnp.maximum(eps * jnp.max(diag, axis=-1), 1e-30)
+    thr = thr.astype(jnp.float32)
     if bs is None:
-        bs = tiled_block_size(n)
+        bs, n = TILED_BS, tiled_padded_n(n)
+        if n > n_job:
+            with jax.named_scope(TILED_PAD_SCOPE):
+                a = pad_identity(a, n - n_job)
+                b = pad_rows(b, n - n_job)
     assert n % bs == 0 and n >= 2 * bs, (n, bs)
     assert tiled_vmem_floats(n, bs, m) * 4 <= TILED_VMEM_BUDGET_BYTES, \
         (n, bs, m)
     if interpret is None:
         interpret = interpret_default()
     steps = n // bs
-    diag = jnp.diagonal(a, axis1=-2, axis2=-1)
-    thr = jnp.maximum(eps * jnp.max(diag, axis=-1), 1e-30)
-    thr = thr.astype(jnp.float32)
     x, _ = pl.pallas_call(
         functools.partial(_cholesky_solve_tiled_kernel, bs=bs, steps=steps),
         grid=(bsz, steps + 1, steps),
@@ -552,6 +592,9 @@ def cholesky_solve_tiled(a: jax.Array, b: jax.Array, *,
         interpret=interpret,
         name="cholesky_solve_tiled",
     )(thr, a, b)
+    if n > n_job:
+        with jax.named_scope(TILED_PAD_SCOPE):
+            x = x[:, :n_job]
     return x
 
 

@@ -42,13 +42,14 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import (dot, eye, interpret_default, iota,
                                   resolve_backend)
-from repro.pipelines.cholesky_solve import (DEFAULT_EPS,
+from repro.pipelines.cholesky_solve import (DEFAULT_EPS, TILED_BS,
+                                            TILED_PAD_SCOPE,
                                             TILED_VMEM_BUDGET_BYTES,
                                             _tiled_backsub_cell,
                                             _tiled_factor_cell,
                                             chol_solve_inline,
                                             cholesky_solve_unfused,
-                                            tiled_block_size)
+                                            tiled_padded_n)
 
 
 def _mmse_kernel(h_ref, y_ref, x_ref, *, n: int, sigma2: float,
@@ -314,12 +315,24 @@ def mmse_equalize_tiled(h: jax.Array, y: jax.Array, *,
     ``mmse_tiled_vmem_floats`` = O((M+N)*bs), so N = 1024/2048 channel
     counts (the n >> 512 PUSCH shapes) become servable.  Registered as
     the ``tiled`` variant of the ``mmse_equalize`` spec for N >= 512.
+
+    With ``bs`` unset the slabs are ``TILED_BS`` wide and an N they do
+    not divide runs with zero channel columns appended up to
+    ``tiled_padded_n(N)`` (on the device, under the ``tiled_pad``
+    scope): the Gram matrix is then ``diag(G, sigma^2 I)`` and the
+    matched filter ``[H^T y; 0]``, so the padded outputs are 0, the
+    deficiency threshold is unmoved (every diagonal of G is at least
+    sigma^2), and the first N rows of the answer come back.
     """
     bsz, m, n = h.shape
     b2, m2, k = y.shape
     assert m == m2 and bsz == b2 and m >= n, (h.shape, y.shape)
+    n_job = n
     if bs is None:
-        bs = tiled_block_size(n)
+        bs, n = TILED_BS, tiled_padded_n(n)
+        if n > n_job:
+            with jax.named_scope(TILED_PAD_SCOPE):
+                h = jnp.pad(h, ((0, 0), (0, 0), (0, n - n_job)))
     assert n % bs == 0 and n >= 2 * bs, (n, bs)
     assert mmse_tiled_vmem_floats(m, n, bs, k) * 4 <= \
         TILED_VMEM_BUDGET_BYTES, (m, n, bs, k)
@@ -359,6 +372,9 @@ def mmse_equalize_tiled(h: jax.Array, y: jax.Array, *,
         interpret=interpret,
         name="mmse_equalize_tiled",
     )(h, y)
+    if n > n_job:
+        with jax.named_scope(TILED_PAD_SCOPE):
+            x = x[:, :n_job]
     return x
 
 

@@ -28,8 +28,10 @@ from repro.kernels.common import (dot, interpret_default, iota, put_col,
                                   take_row)
 from repro.kernels.qr import qr_pallas
 from repro.kernels.trisolve import trisolve_pallas
-from repro.pipelines.cholesky_solve import (TILED_VMEM_BUDGET_BYTES,
-                                            _row_block, tiled_block_size)
+from repro.pipelines.cholesky_solve import (TILED_BS, TILED_PAD_SCOPE,
+                                            TILED_VMEM_BUDGET_BYTES,
+                                            _row_block, pad_identity,
+                                            pad_rows, tiled_padded_n)
 
 DEFAULT_TINY = 1e-20
 
@@ -265,7 +267,7 @@ def qr_tiled_vmem_floats(m: int, n: int, bs: int, k: int) -> int:
 
 def _qr_solve_tiled_kernel(a_hbm, b_ref, x_ref, r_hbm, slab_scr, pan_scr,
                            v_scr, t_scr, y_scr, dmax_scr, sem, *, n: int,
-                           bs: int, steps: int, tiny: float):
+                           n_job: int, bs: int, steps: int, tiny: float):
     i = pl.program_id(0)
     s = pl.program_id(1)                  # panel step; == steps: back-sub
     t = pl.program_id(2)                  # column tile
@@ -287,8 +289,10 @@ def _qr_solve_tiled_kernel(a_hbm, b_ref, x_ref, r_hbm, slab_scr, pan_scr,
         y_scr[...] = _wy_apply(v, tt, y_scr[...])     # Q_p^T on the rhs
         v_scr[...] = v
         t_scr[...] = tt
-        # global |diag R| max for the back-substitution threshold
-        diag = iota((pan.shape[0], 1), 0) == o + iota((1, bs), 1)
+        # global |diag R| max for the back-substitution threshold, over
+        # the job's own columns (padded ones have |R_jj| = 1)
+        cols = o + iota((1, bs), 1)
+        diag = (iota((pan.shape[0], 1), 0) == cols) & (cols < n_job)
         dmax_scr[0] = jnp.maximum(
             dmax_scr[0], jnp.max(jnp.where(diag, jnp.abs(pan), 0.0)))
         slab_scr[...] = pan
@@ -357,12 +361,24 @@ def qr_solve_tiled(a: jax.Array, b: jax.Array, *, bs: int | None = None,
     current panel live in VMEM — ``qr_tiled_vmem_floats`` = O(M*bs).
     Registered as the ``tiled`` variant of the ``qr_solve`` spec; the
     dispatcher picks it for N >= 512.
+
+    With ``bs`` unset the slabs are ``TILED_BS`` wide and an N they do
+    not divide runs as ``[[A, 0], [0, I]]`` against ``[b; 0]``: p more
+    columns and p more rows, to ``tiled_padded_n(N)`` columns (padded on
+    the device under the ``tiled_pad`` scope).  The padded unknowns
+    solve to 0 and the first N rows of the answer come back.
     """
     bsz, m, n = a.shape
     b2, m2, k = b.shape
     assert m == m2 and bsz == b2 and m >= n, (a.shape, b.shape)
+    n_job = n
     if bs is None:
-        bs = tiled_block_size(n)
+        bs, n = TILED_BS, tiled_padded_n(n)
+        if n > n_job:
+            with jax.named_scope(TILED_PAD_SCOPE):
+                a = pad_identity(a, n - n_job)
+                b = pad_rows(b, n - n_job)
+            m += n - n_job
     assert n % bs == 0 and n >= 2 * bs, (n, bs)
     assert qr_tiled_vmem_floats(m, n, bs, k) * 4 <= \
         TILED_VMEM_BUDGET_BYTES, (m, n, bs, k)
@@ -370,8 +386,8 @@ def qr_solve_tiled(a: jax.Array, b: jax.Array, *, bs: int | None = None,
         interpret = interpret_default()
     steps = n // bs
     x, _ = pl.pallas_call(
-        functools.partial(_qr_solve_tiled_kernel, n=n, bs=bs, steps=steps,
-                          tiny=tiny),
+        functools.partial(_qr_solve_tiled_kernel, n=n, n_job=n_job, bs=bs,
+                          steps=steps, tiny=tiny),
         grid=(bsz, steps + 1, steps),
         in_specs=[
             pl.BlockSpec(memory_space=pl.ANY),
@@ -401,6 +417,9 @@ def qr_solve_tiled(a: jax.Array, b: jax.Array, *, bs: int | None = None,
         interpret=interpret,
         name="qr_solve_tiled",
     )(a, b)
+    if n > n_job:
+        with jax.named_scope(TILED_PAD_SCOPE):
+            x = x[:, :n_job]
     return x
 
 

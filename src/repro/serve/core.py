@@ -83,11 +83,12 @@ class EngineCore:
     def record_launch(self, pipeline: str, shape: tuple, real: int,
                       padded: int, variant: str = "base",
                       coalesced: int = 0, measured: float = None,
-                      mesh: int = 1, shard: int = 0) -> None:
+                      mesh: int = 1, shard: int = 0,
+                      pad_flops: float = 0.0) -> None:
         self.recorder.record_launch(
             pipeline, shape, real, padded, self.clock(), variant,
             coalesced, math.nan if measured is None else measured,
-            mesh, shard)
+            mesh, shard, pad_flops)
 
     def record_job(self, pipeline: str, item) -> None:
         """Stamp ``finished_at`` and log the job's latency sample (keyed
@@ -214,9 +215,12 @@ class EngineCore:
         padded, pad = pad_group(spec, stacked, width, variant=variant)
         res, measured = self._gather(*self._timed_call(fn, padded,
                                                        device=device))
-        self.record_launch(spec.name, key, len(jobs), pad,
-                           variant.name if variant is not None else "base",
-                           measured=measured, mesh=mesh, shard=shard)
+        v = variant if variant is not None else spec.base
+        self.record_launch(spec.name, key, len(jobs), pad, v.name,
+                           measured=measured, mesh=mesh, shard=shard,
+                           pad_flops=v.pad_flops(
+                               [s for s, _ in key], len(jobs),
+                               len(jobs) + pad))
         if mesh > 1:
             self.observe_launch(spec, variant, key, len(jobs) + pad,
                                 measured, mesh=mesh)

@@ -104,6 +104,11 @@ class LaunchRecord:
     shard: int = 0
     """Shard the launch was placed on (``-1`` for mesh-spanning
     launches, which occupy every shard)."""
+    pad_flops: float = 0.0
+    """Model FLOPs the launch spent on padding (``Variant.pad_flops``):
+    its ``padded`` filler lanes whole, plus the rows and columns the
+    variant pads each real lane by (a tiled solve at an n that 128 does
+    not divide runs at whole 128-wide slabs)."""
 
 
 @dataclasses.dataclass(frozen=True)
@@ -314,6 +319,8 @@ class MetricsSnapshot:
     total_coalesced: int = 0
     total_failed: int = 0
     total_retries: int = 0
+    total_pad_flops: float = 0.0
+    """Sum of ``LaunchRecord.pad_flops``: model FLOPs spent on padding."""
     faults: FaultStats = dataclasses.field(default_factory=FaultStats)
     """Fault-handling health block (see :class:`FaultStats`).  The
     Recorder fills retries/failed_jobs; ``SolverMux.metrics()`` attaches
@@ -379,11 +386,12 @@ class Recorder:
                       padded: int, t: float, variant: str = "base",
                       coalesced: int = 0,
                       measured: float = math.nan,
-                      mesh: int = 1, shard: int = 0) -> None:
+                      mesh: int = 1, shard: int = 0,
+                      pad_flops: float = 0.0) -> None:
         self._launches.append(
             LaunchRecord(pipeline, shape, int(real), int(padded), t,
                          variant, int(coalesced), float(measured),
-                         int(mesh), int(shard)))
+                         int(mesh), int(shard), float(pad_flops)))
 
     def record_job(self, pipeline: str, submitted_at: float,
                    finished_at: float,
@@ -525,5 +533,6 @@ class Recorder:
             total_coalesced=sum(l.coalesced for l in self._launches),
             total_failed=len(self._fails),
             total_retries=sum(self._retries.values()),
+            total_pad_flops=sum(l.pad_flops for l in self._launches),
             faults=FaultStats(retries=sum(self._retries.values()),
                               failed_jobs=len(self._fails)))

@@ -244,6 +244,13 @@ def _round(x: float) -> float:
     return float(f"{x:.6g}")
 
 
+def _pad_flops(variant, key: tuple, real: int, pad: int) -> float:
+    """``LaunchRecord.pad_flops`` of a launch of ``real`` jobs and
+    ``pad`` filler lanes of bucket ``key`` (coalesced riders count as
+    real lanes at the bucket's shape)."""
+    return variant.pad_flops([shape for shape, _ in key], real, real + pad)
+
+
 def _shape_label(key: tuple) -> list:
     """JSON-able form of a shape-bucket key for the event log."""
     return [list(shape) for shape, _ in key]
@@ -1111,7 +1118,9 @@ class SolverMux(EngineCore):
                                            variant.name,
                                            coalesced=len(riders),
                                            measured=measured, mesh=mesh,
-                                           shard=rec_shard)
+                                           shard=rec_shard,
+                                           pad_flops=_pad_flops(
+                                               variant, key, real, pad))
                         if mesh > 1:
                             self.observe_launch(spec, variant, key,
                                                 real + pad, measured,
@@ -1188,7 +1197,9 @@ class SolverMux(EngineCore):
                 self.record_launch(spec.name, key, real, pad, variant.name,
                                    coalesced=len(riders),
                                    measured=measured, mesh=mesh,
-                                   shard=rec_shard)
+                                   shard=rec_shard,
+                                   pad_flops=_pad_flops(variant, key, real,
+                                                        pad))
                 done = self._scatter(pool, chunk, riders, res, t,
                                      bad=set(bad))
                 self._flush_event(pool, key, chunk, riders, variant, t,

@@ -197,8 +197,9 @@ def test_dispatcher_picks_tiled_for_hbm_buckets(name, n):
     key = (mat, (mat[0], 2))
     v = spec.dispatch_key(key, (np.float32, np.float32))
     assert v.name == "tiled", (name, n, v.name)
-    from repro.pipelines.cholesky_solve import tiled_block_size
-    assert n % tiled_block_size(n) == 0    # the wrapper can tile it
+    from repro.pipelines import tiled_padded_n
+    # the wrapper runs it in whole 128-wide slabs, padding under 128
+    assert tiled_padded_n(n) % 128 == 0 and 0 <= tiled_padded_n(n) - n < 128
     # and the midrange/base buckets are untouched by the new variant
     small = ((24, 24), (24, 2)) if name == "cholesky_solve" \
         else ((28, 24), (28, 2))

@@ -48,6 +48,16 @@ CASES = (
          ((n, 2 * n), (n + 4, 2 * n), (n + 4, 2))))]
 )
 
+# tiled buckets at an n that 128 does not divide, which the entry points
+# pad up to whole 128-wide slabs (n = 704 runs at 768): the STAP bucket
+# chipbench serves (KASSPER's 352 complex DOF, real-embedded, 32
+# steering vectors, 8 lanes), and the tall QR and MMSE systems
+PADDED_CASES = (
+    [("cholesky_solve", "tiled", 8, ((704, 704), (704, 32)))]
+    + [(p, "tiled", 2, ((720, 704), (720, 2)))
+       for p in ("qr_solve", "mmse_equalize")]
+)
+
 
 # the name each served kernel's pallas_call gives its custom call, after
 # its registry entry or variant; the profiler's trace names the device op
@@ -94,7 +104,7 @@ def one_chip():
     cc.reset_cache()
 
 
-@pytest.mark.parametrize("case", CASES, ids=_case_id)
+@pytest.mark.parametrize("case", CASES + PADDED_CASES, ids=_case_id)
 def test_served_kernel_compiles_for_v5e(one_chip, case, record_property):
     pipeline, variant, lanes, shapes = case
     spec = K.get(pipeline)
@@ -109,6 +119,12 @@ def test_served_kernel_compiles_for_v5e(one_chip, case, record_property):
     name = KERNEL_NAMES[pipeline, variant]
     assert re.search(rf'%{name}(\.\d+)? = [^\n]*custom-call\([^\n]*'
                      rf'custom_call_target="tpu_custom_call"', text), name
+    if case in PADDED_CASES:
+        # the kernel runs at whole 128-wide slabs, not at the job's n
+        run = v.run_shapes(shapes)[0]
+        assert run[1] % 128 == 0 and run[1] > shapes[0][1], run
+        assert re.search(rf'%{name}(\.\d+)? = [^\n]*'
+                         rf'f32\[{lanes},{run[0]},{run[1]}\]', text), run
     mem = compiled.memory_analysis()
     record_property("memory_analysis", str(mem))
     # the program fits one chip's 16 GB of HBM with room to spare

@@ -84,11 +84,11 @@ class EngineCore:
                       padded: int, variant: str = "base",
                       coalesced: int = 0, measured: float = None,
                       mesh: int = 1, shard: int = 0,
-                      pad_flops: float = 0.0) -> None:
+                      pad_flops: float = 0.0, groups: int = 1) -> None:
         self.recorder.record_launch(
             pipeline, shape, real, padded, self.clock(), variant,
             coalesced, math.nan if measured is None else measured,
-            mesh, shard, pad_flops)
+            mesh, shard, pad_flops, groups)
 
     def record_job(self, pipeline: str, item) -> None:
         """Stamp ``finished_at`` and log the job's latency sample (keyed

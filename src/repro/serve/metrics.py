@@ -109,6 +109,10 @@ class LaunchRecord:
     its ``padded`` filler lanes whole, plus the rows and columns the
     variant pads each real lane by (a tiled solve at an n that 128 does
     not divide runs at whole 128-wide slabs)."""
+    groups: int = 1
+    """Lane groups the launch carried: above 1 where a bucket flush sent
+    several full groups as one launch (``repro.serve.mux.launch_plan``);
+    a mesh-spanning launch counts as one group."""
 
 
 @dataclasses.dataclass(frozen=True)
@@ -321,6 +325,11 @@ class MetricsSnapshot:
     total_retries: int = 0
     total_pad_flops: float = 0.0
     """Sum of ``LaunchRecord.pad_flops``: model FLOPs spent on padding."""
+    total_merged_launches: int = 0
+    """Launches that carried more than one lane group: how often a
+    bucket flush's launch plan sent several groups at once."""
+    mean_launch_groups: float = math.nan
+    """Mean ``LaunchRecord.groups`` over the launches (NaN before any)."""
     faults: FaultStats = dataclasses.field(default_factory=FaultStats)
     """Fault-handling health block (see :class:`FaultStats`).  The
     Recorder fills retries/failed_jobs; ``SolverMux.metrics()`` attaches
@@ -387,11 +396,12 @@ class Recorder:
                       coalesced: int = 0,
                       measured: float = math.nan,
                       mesh: int = 1, shard: int = 0,
-                      pad_flops: float = 0.0) -> None:
+                      pad_flops: float = 0.0, groups: int = 1) -> None:
         self._launches.append(
             LaunchRecord(pipeline, shape, int(real), int(padded), t,
                          variant, int(coalesced), float(measured),
-                         int(mesh), int(shard), float(pad_flops)))
+                         int(mesh), int(shard), float(pad_flops),
+                         int(groups)))
 
     def record_job(self, pipeline: str, submitted_at: float,
                    finished_at: float,
@@ -534,5 +544,9 @@ class Recorder:
             total_failed=len(self._fails),
             total_retries=sum(self._retries.values()),
             total_pad_flops=sum(l.pad_flops for l in self._launches),
+            total_merged_launches=sum(l.groups > 1 for l in self._launches),
+            mean_launch_groups=(sum(l.groups for l in self._launches)
+                                / len(self._launches))
+            if self._launches else math.nan,
             faults=FaultStats(retries=sum(self._retries.values()),
                               failed_jobs=len(self._fails)))

@@ -20,7 +20,9 @@ that interleaved stream and serves it with the paper's lane model:
     (queued jobs in THAT pool >= ``pressure``) demands draining;
     ``run()`` drains everything.  Bucket flush order is deadline-aware:
     the bucket with the oldest (earliest) deadline flushes first, ties
-    broken by submission order.
+    broken by submission order.  A flush with four or more full lane
+    groups ready sends them as a few wide launches (:func:`launch_plan`)
+    rather than one launch a group.
   * **padding** — a short lane group is topped up from the pipeline's
     ``KernelSpec.filler`` (a declared benign problem, e.g. identity
     system / zero rhs) so padded lanes stay finite and are discarded.
@@ -256,6 +258,33 @@ def _shape_label(key: tuple) -> list:
     return [list(shape) for shape, _ in key]
 
 
+# the widest launch a bucket flush sends, in lane groups: with the
+# powers of two below it, a bucket compiles at most six batch sizes
+MAX_LAUNCH_GROUPS = 32
+
+
+def launch_plan(groups: int) -> list[int]:
+    """Lane groups per launch for a bucket flush with ``groups`` full
+    groups ready, in launch order.
+
+    With p the largest power of two at most ``groups / 2`` (and at most
+    :data:`MAX_LAUNCH_GROUPS`), launches of p groups go out while p
+    remain, then the rest in its binary decomposition, largest first:
+    35 groups go as [16, 16, 2, 1].  Two or more groups always make two
+    or more launches, so one is in flight while the host prepares the
+    next, and a flush of fewer than four groups sends one a launch."""
+    p = 1
+    while 2 * p <= min(groups / 2, MAX_LAUNCH_GROUPS):
+        p *= 2
+    plan = [p] * (groups // p)
+    rest = groups % p
+    while rest:
+        part = 1 << (rest.bit_length() - 1)
+        plan.append(part)
+        rest -= part
+    return plan
+
+
 @dataclasses.dataclass
 class OverloadPolicy:
     """Overload-management knobs for :class:`SolverMux` (see the module
@@ -326,6 +355,7 @@ class _Started:
     variant: object = None
     answer: tuple | None = None
     reason: str = "launch_failed"
+    groups: int = 1                 # lane groups merged into the launch
 
 
 @dataclasses.dataclass(eq=False)
@@ -402,8 +432,11 @@ class SolverMux(EngineCore):
     overload policy described in the module docstring.
 
     Parameters:
-      lanes     lane-group width per grid launch (per-pipeline pools all
-                share it; a launch never carries more than ``lanes`` jobs)
+      lanes     lane-group width (per-pipeline pools all share it): the
+                unit a launch is padded to.  A launch carries one group,
+                except where a bucket flush with four or more full
+                groups ready sends them as a few launches of several
+                groups each (:func:`launch_plan`)
       max_wait  seconds a partial bucket may age before ``poll`` flushes
                 it anyway (``None``: only deadlines/pressure flush
                 partials)
@@ -964,7 +997,8 @@ class SolverMux(EngineCore):
                            for i in range(len(chunk[0].args))]
             padded, pad = pad_group(spec, stacked, width, variant=variant)
         launch = _Started(pool, key, list(chunk), riders, padded, pad, t,
-                          mesh, shard)
+                          mesh, shard,
+                          groups=(len(chunk) + len(riders) + pad) // width)
         if mesh == 1 and self.shards is not None:
             # a quarantined shard owed a probe gets this launch; else
             # place on the least-loaded healthy shard
@@ -994,7 +1028,8 @@ class SolverMux(EngineCore):
         else:
             launch.variant, fn = pool.dispatcher.resolve(key)
         ctx = {"pipeline": pool.spec.name, "variant": launch.variant.name,
-               "width": self.lanes * max(1, mesh), "mesh": mesh,
+               "width": launch.groups * self.lanes * max(1, mesh),
+               "mesh": mesh,
                "shard": None if mesh > 1 else launch.shard, "t": launch.t}
         launch.answer = None
         try:
@@ -1036,10 +1071,12 @@ class SolverMux(EngineCore):
 
     def _flush_event(self, pool: _LanePool, key: tuple, chunk: list,
                      riders: tuple, variant, t: float, mesh: int,
-                     rec_shard: int, shard: int | None) -> None:
+                     rec_shard: int, shard: int | None,
+                     groups: int = 1) -> None:
         """Shard load accounting + the ``flush`` event.  mesh/shard
-        fields only appear on sharded muxes, so the single-device event
-        stream (golden traces) is unchanged."""
+        fields only appear on sharded muxes, and ``groups`` only on a
+        launch of several lane groups, so the single-device event
+        stream of one group a launch (golden traces) is unchanged."""
         if self.shards is not None:
             cost = pool.dispatcher.price(key, self.lanes * max(1, mesh),
                                          mesh=mesh)
@@ -1053,10 +1090,11 @@ class SolverMux(EngineCore):
                         coalesced=[j.seq for j in riders],
                         mesh=mesh, shard=rec_shard)
         else:
+            wide = {"groups": groups} if groups > 1 else {}
             self._event("flush", t=t, pipeline=pool.spec.name,
                         variant=variant.name, shape=_shape_label(key),
                         jobs=[j.seq for j in chunk],
-                        coalesced=[j.seq for j in riders])
+                        coalesced=[j.seq for j in riders], **wide)
 
     def _watchdog(self, pool: _LanePool, key: tuple, variant, width: int,
                   mesh: int, measured: float, t: float) -> None:
@@ -1083,10 +1121,11 @@ class SolverMux(EngineCore):
         terminal jobs)."""
         pool, key, chunk, riders = (launch.pool, launch.key, launch.chunk,
                                     launch.riders)
-        pad, t, mesh = launch.pad, launch.t, launch.mesh
+        pad, t, mesh, groups = launch.pad, launch.t, launch.mesh, \
+            launch.groups
         spec = pool.spec
         real = len(chunk) + len(riders)
-        width = self.lanes * max(1, mesh)
+        width = real + pad
         rec_shard = -1 if mesh > 1 else (launch.shard
                                          if launch.shard is not None
                                          else 0)
@@ -1110,8 +1149,8 @@ class SolverMux(EngineCore):
                     reason = f"launch_exception:{type(e).__name__}"
             if not failed:
                 with span("serve.mux.finish"):
-                    bad = [i for i in range(real)
-                           if not np.all(np.isfinite(res[i]))]
+                    finite = np.isfinite(res[:real]).reshape(real, -1)
+                    bad = np.flatnonzero(~finite.all(axis=1)).tolist()
                     if not bad:
                         # ---- success ----
                         self.record_launch(spec.name, key, real, pad,
@@ -1120,7 +1159,8 @@ class SolverMux(EngineCore):
                                            measured=measured, mesh=mesh,
                                            shard=rec_shard,
                                            pad_flops=_pad_flops(
-                                               variant, key, real, pad))
+                                               variant, key, real, pad),
+                                           groups=groups)
                         if mesh > 1:
                             self.observe_launch(spec, variant, key,
                                                 real + pad, measured,
@@ -1145,11 +1185,26 @@ class SolverMux(EngineCore):
                                        measured, t)
                         self._flush_event(pool, key, chunk, riders,
                                           variant, t, mesh, rec_shard,
-                                          shard)
+                                          shard, groups)
                         return done
-            # ---- failure accounting ----
             if not failed:
                 reason = "nonfinite_output"
+            if groups > 1:
+                # a launch of several lane groups decomposes into its
+                # groups at once, each running the ladder below at the
+                # one-group width: a poisoned lane fails only its own
+                # job, and the fault path compiles no new batch size
+                self._event("retry", t=t, pipeline=spec.name,
+                            shape=_shape_label(key),
+                            jobs=[j.seq for j in chunk],
+                            action="decompose", reason=reason)
+                done = []
+                for i in range(0, len(chunk), self.lanes):
+                    done.extend(self._launch(pool, key,
+                                             chunk[i:i + self.lanes],
+                                             now=t))
+                return done
+            # ---- failure accounting ----
             fallback = pool.dispatcher.note_failure(key, variant,
                                                     self.demote_after)
             if fallback is not None:
@@ -1253,10 +1308,12 @@ class SolverMux(EngineCore):
         On a mesh, a backlog of at least ``lanes * mesh_size`` drains in
         mesh-spanning launches first; the remainder goes per-shard.
 
-        The chunks' launches overlap two deep (:meth:`_launch_chunks`):
-        launch k's answer comes back to the host while launch k+1 is
-        stacked, copied in and dispatched.  Every launch is finished
-        before this returns, so callers never see a job in flight."""
+        The full chunks go out as a few launches of several groups each
+        (:func:`launch_plan`), and the launches overlap two deep
+        (:meth:`_launch_chunks`): launch k's answer comes back to the
+        host while launch k+1 is stacked, copied in and dispatched.
+        Every launch is finished before this returns, so callers never
+        see a job in flight."""
         jobs = pool.buckets[key]
         done: list[SolveJob] = []
         if self.shards is not None and self.shards.all_healthy():
@@ -1285,25 +1342,40 @@ class SolverMux(EngineCore):
     def _launch_chunks(self, pool: _LanePool, key: tuple, chunks: list,
                        now: float | None) -> list[SolveJob]:
         """Launch ``chunks`` of one bucket in order, each supervised.
+        Every chunk is one lane group; all but the last are full.
 
-        Where nothing reads the order of the host steps, two launches
-        are in flight at once: launch k+1 is begun (stacked, copied in,
-        dispatched, its copy back started) before launch k is gathered
-        and finished, so k's copy back lands while the host works on
-        k+1.  The kernels, their inputs and the answers are the same;
-        each ``LaunchRecord.measured`` is still its own launch's steps,
-        and the events come in the same order.  A failure found at k's
-        gather runs k's retry / bisect ladder there, with k+1 in flight.
+        Where nothing reads the order of the host steps, the full groups
+        go out as the few launches of :func:`launch_plan`, in job order,
+        and the partial group last.  Each lane's arithmetic, the filler
+        and the answers are those of one group a launch; a launch of
+        several groups pays the launch's fixed host costs (copy in,
+        dispatch, gather) once.  A failure in such a launch decomposes
+        it into its groups (:meth:`_supervise`).
 
-        Launches stay one at a time where the serial order is part of
-        what the mux promises: with a fault injector (its seeded draws
-        are replayed in launch order) and on a mesh (placement reads
-        the load the previous launch noted as it finished).  The
-        overload policy's rounds never come here: they launch one
-        admitted candidate at a time (:meth:`_poll_policy`)."""
+        Two launches are in flight at once: launch k+1 is begun
+        (stacked, copied in, dispatched, its copy back started) before
+        launch k is gathered and finished, so k's copy back lands while
+        the host works on k+1.  Each ``LaunchRecord.measured`` is still
+        its own launch's steps, and the events come in launch order.  A
+        failure found at k's gather runs k's ladder there, with k+1 in
+        flight.
+
+        Launches stay one group at a time, one after another, where the
+        serial order is part of what the mux promises: with a fault
+        injector (its seeded draws are replayed in launch order) and on
+        a mesh (placement reads the load the previous launch noted as it
+        finished).  The overload policy's rounds never come here: they
+        launch one admitted candidate at a time (:meth:`_poll_policy`)."""
         if self.injector is not None or self.shards is not None:
             return [job for chunk in chunks
                     for job in self._launch(pool, key, chunk, now=now)]
+        full = sum(len(chunk) == self.lanes for chunk in chunks)
+        merged, i = [], 0
+        for k in launch_plan(full):
+            merged.append([job for chunk in chunks[i:i + k]
+                           for job in chunk])
+            i += k
+        chunks = merged + chunks[i:]
         done: list[SolveJob] = []
         held = None
         try:

@@ -14,6 +14,7 @@ import functools
 
 import jax
 import numpy as np
+from jax.experimental.layout import Format, Layout
 
 from repro.serve.core import FifoEngineCore
 
@@ -179,11 +180,18 @@ class VariantDispatcher:
     def resolve(self, key: tuple):
         """``key`` is a SolveJob.shape_key(): per-arg ((shape, dtype)).
         Returns the dispatched registry Variant and its jit'd, options-
-        bound entry point."""
+        bound entry point.
+
+        The entry point returns its answer in the layout the compiler
+        picks (``Layout.AUTO``), the one the kernel writes: the default
+        layout of a wide batch can put the batch dimension minor (the
+        TPU's at 128 lanes or more), which would cost a relayout copy
+        of every answer on the device."""
         variant = self._dispatch(key)
         fn = self._fns.get(variant.name)
         if fn is None:
-            fn = jax.jit(functools.partial(variant.fn, **self.options))
+            fn = jax.jit(functools.partial(variant.fn, **self.options),
+                         out_shardings=Format(Layout.AUTO))
             self._fns[variant.name] = fn
         return variant, fn
 

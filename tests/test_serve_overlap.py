@@ -20,7 +20,10 @@ from repro.serve.core import EngineCore
 
 from test_serve_trace import LAUNCH, Ticks, program_spans
 
-JOBS = 9                    # four full pairs and one lone job on 2 lanes
+# three full pairs and one lone job on 2 lanes: a flush of three full
+# groups still sends one group a launch (launch_plan; the merged launches
+# of four or more are pinned in test_serve_launch_plan.py)
+JOBS = 7
 MARK = 7777.0               # a poisoned job's first right-hand side entry
 
 
@@ -82,7 +85,7 @@ def test_overlapped_flush_equals_one_group_at_a_time(poisoner, poison):
             np.testing.assert_array_equal(g.out, w.out)
     assert over.events == one.events
     assert _records(over) == _records(one)
-    assert len(_records(over)) == 5
+    assert len(_records(over)) == 4
 
 
 def test_execute_of_the_next_launch_precedes_the_gather(tmp_path):
@@ -93,10 +96,10 @@ def test_execute_of_the_next_launch_precedes_the_gather(tmp_path):
     begin, end = list(LAUNCH[:3]), list(LAUNCH[3:])
     # launch k+1 begins before launch k ends; the last ends cold
     want = ["serve.mux.admit"] * JOBS + begin
-    for _ in range(4):
+    for _ in range(3):
         want += begin + end
     assert names == want + end
-    assert len(mux.metrics().launches) == 5
+    assert len(mux.metrics().launches) == 4
     for (_, end_ns, _), (start, _, _) in zip(events, events[1:]):
         assert end_ns <= start
 
@@ -112,14 +115,14 @@ def test_every_launch_goes_through_the_timed_call(monkeypatch):
     monkeypatch.setattr(EngineCore, "_timed_call", counting)
     mux, jobs = _serve(_args())
     assert [j.state for j in jobs] == ["done"] * JOBS
-    assert calls == [2] * len(mux.metrics().launches) == [2] * 5
+    assert calls == [2] * len(mux.metrics().launches) == [2] * 4
 
 
 def test_measured_excludes_the_next_launchs_steps():
     """On a clock that ticks a second a reading, launch k's gather comes
     after launch k+1's two readings; its wall is still its own four."""
     mux, _ = _serve(_args(), wall=Ticks())
-    assert [lr.measured for lr in mux.metrics().launches] == [2.0] * 5
+    assert [lr.measured for lr in mux.metrics().launches] == [2.0] * 4
 
 
 def test_a_poisoned_lane_runs_its_ladder_with_the_next_in_flight(

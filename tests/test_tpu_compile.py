@@ -58,6 +58,13 @@ PADDED_CASES = (
        for p in ("qr_solve", "mmse_equalize")]
 )
 
+# the NR cell's split_complex MMSE bucket at the batch sizes a bucket
+# flush's launch plan sends (2, 16 and 32 lane groups of 8; 64 antennas,
+# 16 layers, 144 right-hand sides)
+NR_SHAPES = ((64, 16), (64, 16), (64, 144), (64, 144))
+PLAN_CASES = [("mmse_equalize", "split_complex", lanes, NR_SHAPES)
+              for lanes in (16, 128, 256)]
+
 
 # the name each served kernel's pallas_call gives its custom call, after
 # its registry entry or variant; the profiler's trace names the device op
@@ -104,7 +111,8 @@ def one_chip():
     cc.reset_cache()
 
 
-@pytest.mark.parametrize("case", CASES + PADDED_CASES, ids=_case_id)
+@pytest.mark.parametrize("case", CASES + PADDED_CASES + PLAN_CASES,
+                         ids=_case_id)
 def test_served_kernel_compiles_for_v5e(one_chip, case, record_property):
     pipeline, variant, lanes, shapes = case
     spec = K.get(pipeline)
@@ -130,3 +138,27 @@ def test_served_kernel_compiles_for_v5e(one_chip, case, record_property):
     # the program fits one chip's 16 GB of HBM with room to spare
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
         + mem.output_size_in_bytes < 2 ** 30
+
+
+@pytest.mark.parametrize("lanes", (8, 16, 128, 256))
+def test_served_mmse_split_entry_point_holds_one_kernel_op(one_chip, lanes):
+    """The NR bucket's entry point as ``SolverMux`` serves it, at the
+    batch sizes of the launch plan: the kernel is the program's only
+    instruction that names it (the benchmark's device trace finds the
+    kernel by its name), with no relayout copy of the answer, whose
+    default layout puts the batch dimension minor from 128 lanes on."""
+    from repro.serve.solver import VariantDispatcher
+    spec = K.get("mmse_equalize")
+    key = tuple((s, "float32") for s in NR_SHAPES)
+    variant, fn = VariantDispatcher(
+        spec, {"interpret": False, "sigma2": 0.1}).resolve(key)
+    assert variant.name == "split_complex"
+    args = [jax.ShapeDtypeStruct((lanes, *s), np.float32, sharding=one_chip)
+            for s in NR_SHAPES]
+    text = fn.lower(*args).compile().as_text()
+    naming = [line for line in text.splitlines()
+              if "mmse_split" in line and " = " in line]
+    assert len(naming) == 1, naming
+    assert re.search(r'%mmse_split\.\d+ = f32\[' + str(lanes)
+                     + r',32,144\][^\n]*custom_call_target="tpu_custom_call"',
+                     naming[0])

@@ -5,7 +5,7 @@ FUSED mmse_equalize pipeline (GEMM + Cholesky + two substitutions in one
 kernel launch per lane), via the real expansion [[Re,-Im],[Im,Re]], and
 again via the split re/im fast path (``mmse_equalize_split`` — same
 answer at ~0.4x the GEMM flops).  The same traffic is then pushed
-through serve.PipelineEngine the way a baseband service would: jobs in,
+through serve.SolverMux the way a baseband service would: jobs in,
 lane-pooled grid launches, jobs out — split-plane jobs transparently
 dispatch to the ``split_complex`` registry variant.
 
@@ -19,7 +19,7 @@ import numpy as np
 
 from repro.pipelines import (expand_complex_channel, mmse_equalize,
                              mmse_equalize_split)
-from repro.serve import PipelineEngine, SolveJob
+from repro.serve import SolverMux
 
 ANTENNAS = 16        # receive antennas (paper sizes 12..32)
 STREAMS = 12         # spatial streams
@@ -73,21 +73,21 @@ def main():
           f"max |expansion - split| = "
           f"{np.abs(np.asarray(xsplit) - xhat).max():.2e}")
 
-    # --- the same traffic through the serving engine ---
-    eng = PipelineEngine("mmse_equalize", lanes=8, sigma2=sigma2)
-    jobs = [eng.submit(SolveJob(args=(np.asarray(h[i]), np.asarray(y[i]))))
+    # --- the same traffic through the serving mux ---
+    mux = SolverMux(lanes=8, options={"mmse_equalize": {"sigma2": sigma2}})
+    jobs = [mux.submit("mmse_equalize", np.asarray(h[i]), np.asarray(y[i]))
             for i in range(SUBCARRIERS)]
     # split-plane jobs ride the SAME pipeline; the registry dispatcher
     # routes their 4-arg shape bucket to the split_complex variant
-    split_jobs = [eng.submit(SolveJob(args=(hr[i], hi[i], yr[i], yi[i])))
+    split_jobs = [mux.submit("mmse_equalize", hr[i], hi[i], yr[i], yi[i])
                   for i in range(SUBCARRIERS)]
     t0 = time.perf_counter()
-    eng.run()
+    mux.run()
     dt = time.perf_counter() - t0
     served = np.stack([j.out for j in jobs])
     served_split = np.stack([j.out for j in split_jobs])
-    counts = eng.metrics()["mmse_equalize"].dispatch_counts
-    print(f"  PipelineEngine: {len(jobs) + len(split_jobs)} jobs in "
+    counts = mux.metrics()["mmse_equalize"].dispatch_counts
+    print(f"  SolverMux: {len(jobs) + len(split_jobs)} jobs in "
           f"{dt * 1e3:.2f} ms, dispatch={counts}, "
           f"max |direct - served| = {np.abs(served - xhat).max():.2e}, "
           f"max |split - served| = "

@@ -7,8 +7,8 @@ Every kernel in this package has three faces:
 
 On TPU the Pallas path compiles natively; on this CPU container it runs in
 interpret=True mode (Python evaluation of the kernel body) for correctness
-validation, while `backend='xla'` gives the fast pure-jnp path used by the
-CPU benchmarks and as the production fallback.
+validation, while `backend='xla'` gives the fast pure-jnp path used as the
+production fallback.
 """
 from __future__ import annotations
 

@@ -2,11 +2,11 @@
 
 backend=None  -> pallas on TPU, xla elsewhere (production default)
 backend='pallas' -> the Pallas kernel (interpret=True off-TPU: validation)
-backend='xla' -> pure-jnp path (CPU benchmarks / fallback)
+backend='xla' -> pure-jnp path (CPU fallback)
 
 The xla paths are *not* the naive oracles from ref.py: they are the fused
-FGOP formulations (same region fusion, same masking) expressed in jnp so
-the mechanism benchmarks can compare fused-vs-naive on any backend.
+FGOP formulations (same region fusion, same masking) expressed in jnp, so
+fused-vs-naive can be compared on any backend.
 """
 from __future__ import annotations
 

@@ -23,8 +23,7 @@ Two helpers here are shared infrastructure rather than CLI plumbing:
 
 * :func:`run_overload` — the deterministic synthetic overload scenario
   (offered load >= 2x lane capacity, mixed priorities, virtual clock)
-  behind ``benchmarks.bench_pipelines.run_slo``'s overload sweep and the
-  SLO-attainment acceptance test.
+  behind the SLO-attainment acceptance test.
 * :func:`replay_trace` / :func:`load_trace` — replay a committed JSON
   trace (each entry a seed-keyed job, never raw arrays) through a mux on
   a virtual clock, returning the mux so callers can assert on its
@@ -268,9 +267,8 @@ def run_sharded_overload(mesh_size: int, *, ticks: int = 6,
                          lanes: int = 4, load_lanes: int | None = None,
                          seed: int = 0) -> dict:
     """Virtual-clock replay of the committed overload trace against a
-    mesh of ``mesh_size`` lane shards — the scaling scenario behind
-    ``benchmarks.bench_pipelines.run_slo``'s ``serve_slo/sharded/*``
-    rows.
+    mesh of ``mesh_size`` lane shards — the scaling scenario the
+    sharded-serving tests compare across mesh sizes.
 
     The offered load is generated for ``load_lanes`` lanes (default
     ``8 * lanes`` — saturating even the largest swept mesh) and replayed
@@ -387,12 +385,11 @@ def run_chaos(fault_trace: str | dict | None, *, mesh_size: int = 4,
     end — virtual clock, seed-keyed jobs, seed-keyed faults — so the
     event stream is golden-file-pinnable.
 
-    Returns the summary ``benchmarks.bench_pipelines.run_slo`` emits as
-    ``serve_slo/faults/*`` rows: hard-SLO attainment, per-state job
-    counts, ``hard_lost`` (hard jobs left in no terminal state, or
-    failed without a structured reason — must be zero), the supervision
-    observables (retries / failed jobs / quarantines / reinstatements /
-    demotions), and the drained event stream."""
+    Returns the summary the ``--chaos`` run prints: hard-SLO attainment,
+    per-state job counts, ``hard_lost`` (hard jobs left in no terminal
+    state, or failed without a structured reason — must be zero), the
+    supervision observables (retries / failed jobs / quarantines /
+    reinstatements / demotions), and the drained event stream."""
     import os
 
     from repro.serve import FaultInjector

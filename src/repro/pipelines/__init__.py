@@ -23,11 +23,11 @@ lane (grid cell) per subcarrier/problem:
 
 Each pipeline ships three faces (mirroring repro.kernels): the fused
 Pallas kernel (``*_pallas``), an unfused multi-``pallas_call`` baseline
-(``*_unfused`` / ``*_composed``) whose HBM round-trips quantify the
-fusion win in benchmarks/bench_pipelines.py, and a jit'd dispatching
-wrapper.  All are registered in the kernel registry
-(``repro.kernels.get/names/specs``) next to the primitive kernels, so
-tests, benchmarks, and the serve engine enumerate them uniformly.
+(``*_unfused`` / ``*_composed``) whose HBM round-trips the fusion
+saves, and a jit'd dispatching wrapper.  All are registered in the
+kernel registry (``repro.kernels.get/names/specs``) next to the
+primitive kernels, so tests, the benchmark and the serving mux
+enumerate them uniformly.
 
 Each pipeline additionally registers performance *variants* the registry
 dispatcher (``KernelSpec.dispatch``) selects by shape/arity: blocked

@@ -1,15 +1,16 @@
 """Serving stack for the FGOP reproduction.
 
-Layout (one concern per module, all sharing the ``EngineCore`` queue +
-lane-pool accounting + batch lifecycle):
+Layout (one concern per module; the two engines, SolverMux and
+DecodeEngine, share the ``EngineCore`` clock, lane-pool accounting and
+launch seam):
 
-  core     EngineCore (+ FifoEngineCore), ManualClock, registry-driven
-           pad_group
+  core     EngineCore, ManualClock, registry-driven pad_group
   decode   DecodeEngine / Request       (LM continuous batching:
                                          per-slot positions, paged KV
                                          slot reuse, per-slot sampling;
                                          attaches to SolverMux)
-  solver   PipelineEngine / SolveJob    (single solver pipeline)
+  solver   SolveJob / VariantDispatcher (solver jobs; shape bucket ->
+                                         variant + compiled entry point)
   mux      SolverMux / OverloadPolicy   (mixed pipelines, shape-bucketed
                                          continuous batching, deadline-
                                          aware flush; admission control,
@@ -36,15 +37,13 @@ lane-pool accounting + batch lifecycle):
   trace    span                         (named host spans of the launch
                                          path on the profiler's clock,
                                          on while a profiler records)
-  engine   back-compat shim re-exporting the original names
 
 The kernel registry (``repro.kernels``) is the routing table: any
 ``kind="pipeline"`` spec is servable, and its declared ``filler``
 supplies benign padding lanes.
 """
 from repro.serve.config import ServeConfig, global_config  # noqa: F401
-from repro.serve.core import (EngineCore, FifoEngineCore,  # noqa: F401
-                              ManualClock, pad_group)
+from repro.serve.core import EngineCore, ManualClock, pad_group  # noqa: F401
 from repro.serve.cost import (CostModel, DriftStat,  # noqa: F401
                               RobustEstimator)
 from repro.serve.faults import (Fault, FaultInjector,  # noqa: F401
@@ -56,8 +55,7 @@ from repro.serve.metrics import (DagStats, DecodeStats,  # noqa: F401
                                  ShardStats, shard_stats)
 from repro.serve.mux import DagJob, OverloadPolicy, SolverMux  # noqa: F401
 from repro.serve.shard import LaneShards  # noqa: F401
-from repro.serve.solver import (PipelineEngine, SolveJob,  # noqa: F401
-                                VariantDispatcher)
+from repro.serve.solver import SolveJob, VariantDispatcher  # noqa: F401
 from repro.serve.tuning import BucketTuner  # noqa: F401
 
 
@@ -70,9 +68,9 @@ def __getattr__(name):
     raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
 
 __all__ = [
-    "EngineCore", "FifoEngineCore", "ManualClock", "pad_group",
+    "EngineCore", "ManualClock", "pad_group",
     "DecodeEngine", "Request",
-    "PipelineEngine", "SolveJob", "SolverMux", "VariantDispatcher",
+    "SolveJob", "SolverMux", "VariantDispatcher",
     "DagJob", "DagStats", "DecodeStats",
     "OverloadPolicy", "CostModel", "DriftStat", "RobustEstimator",
     "ServeConfig", "global_config", "BucketTuner",

@@ -1,15 +1,15 @@
 """Common engine core shared by decode and solver serving.
 
-Both engine families (LM decode and solver pipelines) are the same
-machine at this altitude: submitted work, a fixed pool of ``lanes`` the
-device executes in lockstep, and a batch lifecycle of *take → pad to
-the pool → dispatch → scatter results → record metrics*.
-:class:`EngineCore` owns the shared clock, lane-pool accounting (a
-:class:`repro.serve.metrics.Recorder`), and group-dispatch lifecycle;
-:class:`FifoEngineCore` adds the single-FIFO queue used by
-``DecodeEngine`` and ``PipelineEngine`` (``SolverMux`` keeps
-per-pipeline shape buckets instead), so each engine only implements
-what actually differs: how a batch is executed.
+Both engines (``DecodeEngine`` for LM decode, ``SolverMux`` for the
+solver pipelines) are the same machine at this altitude: submitted
+work, a fixed pool of ``lanes`` the device executes in lockstep, and a
+batch lifecycle of *take → pad to the pool → dispatch → scatter results
+→ record metrics*.  :class:`EngineCore` owns the shared clock, lane-pool
+accounting (a :class:`repro.serve.metrics.Recorder`) and the measured
+launch seam (:meth:`EngineCore._timed_call` / :meth:`EngineCore._gather`);
+each engine keeps its own queue (``DecodeEngine`` one FIFO,
+``SolverMux`` per-pipeline shape buckets) and implements how a batch is
+executed.
 
 Padding is registry-driven: a lane group short of the pool size is
 filled from the pipeline's declared ``KernelSpec.filler`` — a benign
@@ -62,9 +62,8 @@ class EngineCore:
     :meth:`observe_launch`, the hook engines override to close the
     cost-model calibration loop (the base hook is a no-op).
 
-    Deliberately queue-free: single-FIFO engines (decode, one-pipeline
-    solver) add the queue via :class:`FifoEngineCore`; the mux keeps its
-    own per-pipeline shape buckets instead.
+    Deliberately queue-free: ``DecodeEngine`` keeps a single FIFO, the
+    mux its per-pipeline shape buckets.
     """
 
     def __init__(self, lanes: int, clock=None, wall=None):
@@ -186,83 +185,6 @@ class EngineCore:
         passes ``mesh``, so legacy 5-arg overrides keep working).  The
         base engine does nothing; cost-model-carrying engines override
         it to feed :meth:`repro.serve.cost.CostModel.observe`."""
-
-    # ---------------- batch lifecycle ----------------
-
-    def dispatch_group(self, spec, fn, key: tuple, jobs: list,
-                       variant=None, mesh: int = 1, shard: int = 0,
-                       device=None) -> list:
-        """The one lane-group batch lifecycle, shared by every solver
-        engine: stack per-arg, pad to the pool from the (variant's or
-        spec's) filler, launch ``fn`` once (measured — the wall-clock is
-        stamped on the LaunchRecord and fed to :meth:`observe_launch`),
-        scatter per-lane results back onto the jobs, and account the
-        launch + per-job latencies.
-
-        ``fn`` is the jit'd entry point the caller resolved through
-        ``KernelSpec.dispatch_key`` for this shape bucket; ``variant``
-        is the matching registry Variant (None = the spec's base).
-
-        ``mesh > 1`` runs a mesh-spanning launch: ``fn`` must be the
-        shard_map-wrapped entry point and the group is padded to the
-        full ``lanes * mesh`` width, so every shard executes a complete
-        ``lanes``-wide slab (no shard ever sees a partial remainder).
-        ``shard``/``device`` place a non-spanning launch on one mesh
-        shard; both default to the legacy single-device behavior."""
-        width = self.lanes * max(1, mesh)
-        stacked = [np.stack([np.asarray(j.args[i]) for j in jobs])
-                   for i in range(len(jobs[0].args))]
-        padded, pad = pad_group(spec, stacked, width, variant=variant)
-        res, measured = self._gather(*self._timed_call(fn, padded,
-                                                       device=device))
-        v = variant if variant is not None else spec.base
-        self.record_launch(spec.name, key, len(jobs), pad, v.name,
-                           measured=measured, mesh=mesh, shard=shard,
-                           pad_flops=v.pad_flops(
-                               [s for s, _ in key], len(jobs),
-                               len(jobs) + pad))
-        if mesh > 1:
-            self.observe_launch(spec, variant, key, len(jobs) + pad,
-                                measured, mesh=mesh)
-        else:
-            # legacy call shape: mesh=1 overrides predating the mesh
-            # path (5-arg signatures) keep working unmodified
-            self.observe_launch(spec, variant, key, len(jobs) + pad,
-                                measured)
-        for i, job in enumerate(jobs):
-            job.out = res[i]
-            if hasattr(job, "state"):
-                job.state = "done"
-            self.record_job(spec.name, job)
-        return jobs
-
-
-class FifoEngineCore(EngineCore):
-    """EngineCore plus the single-FIFO queue lifecycle: submitted items
-    are stamped with ``submitted_at`` and popped oldest-first a lane
-    pool at a time."""
-
-    def __init__(self, lanes: int, clock=None):
-        super().__init__(lanes, clock=clock)
-        self._queue: list = []
-
-    def submit(self, item):
-        if getattr(item, "submitted_at", None) is None:
-            item.submitted_at = self.clock()
-        self._queue.append(item)
-        return item
-
-    def pending(self) -> int:
-        return len(self._queue)
-
-    def take(self, k: int | None = None) -> list:
-        """Pop the oldest ``k`` (default: one lane pool) queued items."""
-        k = self.lanes if k is None else k
-        taken, self._queue = self._queue[:k], self._queue[k:]
-        return taken
-
-    def drain(self) -> list:
-        return self.take(len(self._queue))
 
 
 def pad_group(spec, stacked: list[np.ndarray], lanes: int, variant=None

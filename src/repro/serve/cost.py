@@ -17,7 +17,7 @@ coalescing amortize.  Both start as guesses or as an offline calibration
 replaces, neither is trusted forever:
 
 **The online loop.**  Every serve-side flush measures its wall-clock
-(:meth:`repro.serve.core.EngineCore.dispatch_group` stamps it onto the
+(:class:`repro.serve.mux.SolverMux` stamps it onto the
 :class:`~repro.serve.metrics.LaunchRecord`) and feeds it back through
 :meth:`CostModel.observe`.  Each observation
 

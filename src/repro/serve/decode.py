@@ -1,15 +1,16 @@
 """LM decode serving: :class:`Request` + :class:`DecodeEngine`.
 
 DecodeEngine is a continuous-batching engine on top of
-:class:`repro.serve.core.FifoEngineCore`: a fixed pool of ``batch``
-lanes (slots), each carrying its own position counter.  Every
-:meth:`DecodeEngine.step` is ONE jit'd SPMD program over the whole pool
-(the paper's implicit vector masking applied to the request dimension):
-slots mid-prefill consume their next prompt token, generating slots
-consume their last output token, idle slots are fed a benign token at
-position 0 and their logits discarded.  A finishing request frees only
-its slot; the next queued request prefills into that slot while the
-other slots keep generating — no pool-wide barrier, no cache rebuild.
+:class:`repro.serve.core.EngineCore`, with a FIFO queue of its own and
+a fixed pool of ``batch`` lanes (slots), each carrying its own position
+counter.  Every :meth:`DecodeEngine.step` is ONE jit'd SPMD program over
+the whole pool (the paper's implicit vector masking applied to the
+request dimension): slots mid-prefill consume their next prompt token,
+generating slots consume their last output token, idle slots are fed a
+benign token at position 0 and their logits discarded.  A finishing
+request frees only its slot; the next queued request prefills into that
+slot while the other slots keep generating — no pool-wide barrier, no
+cache rebuild.
 
 Slot-level paged KV reuse: :func:`repro.models.decode.attention_decode`
 masks each slot's attention to its live length ``pos + 1``, so a freed
@@ -28,10 +29,10 @@ as :meth:`DecodeEngine.run_lockstep`, switched the WHOLE pool to one
 shared ``categorical`` stream whenever any pool member sampled; the
 regression test pins both behaviors.)
 
-The shared core supplies the queue, the clock and the lane/latency
-accounting, so decode traffic reports the same SLO metrics surface as
-the solver engines; per-phase (insert / prefill / generate) samples
-land in :class:`repro.serve.metrics.DecodeStats`.  When attached to a
+The shared core supplies the clock and the lane/latency accounting,
+so decode traffic reports the same SLO metrics surface as the solver
+mux; per-phase (insert / prefill / generate) samples land in
+:class:`repro.serve.metrics.DecodeStats`.  When attached to a
 :class:`repro.serve.mux.SolverMux` the engine additionally shares the
 mux's recorder, clocks and event stream (``event_cb``) and feeds
 measured step wall-clock to the cost model (``observe_cb``).
@@ -46,7 +47,7 @@ import numpy as np
 
 from repro.models import decode as D
 from repro.models.config import ArchConfig
-from repro.serve.core import FifoEngineCore
+from repro.serve.core import EngineCore
 
 
 @dataclasses.dataclass
@@ -69,11 +70,12 @@ class Request:
     seq: int | None = None
 
 
-class DecodeEngine(FifoEngineCore):
+class DecodeEngine(EngineCore):
     def __init__(self, cfg: ArchConfig, params, batch: int = 8,
                  max_len: int = 512, eos_id: int = 1, seed: int = 0,
                  clock=None):
         super().__init__(batch, clock=clock)
+        self._queue: list[Request] = []
         self.cfg = cfg
         self.params = params
         self.max_len = max_len
@@ -117,7 +119,23 @@ class DecodeEngine(FifoEngineCore):
         if item.seq is None:
             self._serial += 1
             item.seq = self._serial
-        return super().submit(item)
+        if item.submitted_at is None:
+            item.submitted_at = self.clock()
+        self._queue.append(item)
+        return item
+
+    def pending(self) -> int:
+        return len(self._queue)
+
+    def take(self, k: int | None = None) -> list[Request]:
+        """Pop the oldest ``k`` (default: one lane pool) queued
+        requests."""
+        k = self.lanes if k is None else k
+        taken, self._queue = self._queue[:k], self._queue[k:]
+        return taken
+
+    def drain(self) -> list[Request]:
+        return self.take(len(self._queue))
 
     def occupied(self) -> int:
         """Slots currently holding an unfinished request."""

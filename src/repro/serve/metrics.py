@@ -31,8 +31,7 @@ with per-pipeline p50/p99/mean/max latency (overall AND per priority
 class), throughput over the active window, lane utilization (real lanes
 / dispatched lanes), padded-lane waste (the complement), and the
 dropped / preempted / coalesced counters the overload policy exposes —
-the SLO surface the ROADMAP asks ``benchmarks/bench_pipelines.py`` to
-report for mixed traffic.
+the SLO surface a mixed-traffic run reports.
 """
 from __future__ import annotations
 

@@ -79,8 +79,8 @@ class LaneShards:
         axis; each shard sees its own contiguous lane slab.  The caller
         is responsible for padding the batch to a multiple of
         ``size * lanes_per_device`` so no shard sees a partial
-        remainder (``EngineCore.dispatch_group`` pads to the full
-        ``lanes * mesh`` width)."""
+        remainder (``SolverMux`` pads a mesh-spanning launch to the
+        full ``lanes * mesh`` width)."""
         spec = P(self.axis)
         return shard_map(fn, mesh=self.mesh,
                          in_specs=(spec,) * nargs, out_specs=spec)

@@ -1,22 +1,19 @@
-"""Single-pipeline solver serving: :class:`SolveJob` + :class:`PipelineEngine`.
+"""Solver jobs and their dispatch: :class:`SolveJob` +
+:class:`VariantDispatcher`.
 
-``PipelineEngine`` is the one-pipeline-per-instance engine from the
-original serving stack, rebased on :class:`repro.serve.core.EngineCore`:
-the queue, lane accounting and registry-driven padding are shared with
-the decode engine and the multi-pipeline :class:`repro.serve.mux.SolverMux`
-(which is what you want for mixed traffic).
+A :class:`SolveJob` is one solver problem as the serving mux
+(:class:`repro.serve.mux.SolverMux`) queues it; a
+:class:`VariantDispatcher` resolves each of its shape buckets to a
+registry variant and that variant's compiled entry point.
 """
 from __future__ import annotations
 
-import collections
 import dataclasses
 import functools
 
 import jax
 import numpy as np
 from jax.experimental.layout import Format, Layout
-
-from repro.serve.core import FifoEngineCore
 
 
 @dataclasses.dataclass(eq=False)
@@ -76,7 +73,7 @@ class SolveJob:
 
 
 def resolve_pipeline_spec(pipeline: str):
-    """Registry lookup + kind check shared by the solver engines."""
+    """Registry lookup + kind check for a servable solver pipeline."""
     from repro import kernels as K
     spec = K.get(pipeline)
     if spec.kind != "pipeline":
@@ -87,10 +84,10 @@ def resolve_pipeline_spec(pipeline: str):
 
 class VariantDispatcher:
     """Shape-bucket -> (Variant, jit'd fn) resolution with a per-variant
-    compile cache, shared by PipelineEngine and the SolverMux pools.
+    compile cache, one per SolverMux lane pool.
 
-    Every serve-side launch goes through :meth:`resolve` — the engines
-    never touch ``spec.pallas`` directly — so a bucket of large or
+    Every serve-side launch goes through :meth:`resolve` — the mux
+    never touches ``spec.pallas`` directly — so a bucket of large or
     split-complex jobs transparently lands on the registry's fast
     variant, with one compiled program per variant x shape bucket.
     ``options`` (e.g. ``sigma2``) are bound into every variant entry
@@ -225,51 +222,3 @@ class VariantDispatcher:
         shapes = tuple(shape for shape, _ in key)
         return self.cost_model.launch_cost(self.spec.name, variant,
                                            shapes, lanes, mesh=mesh)
-
-
-class PipelineEngine(FifoEngineCore):
-    """Batched solver service over a single registered pipeline.
-
-    Jobs are grouped by problem shape, stacked, padded to a multiple of
-    the ``lanes`` pool size with the spec's declared benign filler
-    (padded lanes' results are discarded), and executed as one grid
-    launch per group, routed through ``KernelSpec.dispatch`` so each
-    shape group lands on the right performance variant.  ``pipeline`` is
-    any ``kind="pipeline"`` name in the kernel registry; extra keyword
-    ``options`` (e.g. ``sigma2`` for mmse_equalize) are bound into the
-    served kernel.
-    """
-
-    def __init__(self, pipeline: str = "cholesky_solve", lanes: int = 8,
-                 clock=None, **options):
-        super().__init__(lanes, clock=clock)
-        self.spec = resolve_pipeline_spec(pipeline)
-        self._dispatcher = VariantDispatcher(self.spec, options)
-
-    def submit(self, job: SolveJob) -> SolveJob:
-        job.pipeline = self.spec.name
-        return super().submit(job)
-
-    def observe_launch(self, spec, variant, key, lanes, measured,
-                       mesh: int = 1):
-        """Feed measured launch wall-clock to the dispatcher's cost
-        model when one is attached (set ``engine._dispatcher.cost_model``
-        or pass one to the dispatcher) — same calibration loop as the
-        mux, no-op otherwise."""
-        cm = self._dispatcher.cost_model
-        if cm is not None:
-            shapes = tuple(shape for shape, _ in key)
-            cm.observe(spec.name,
-                       variant if variant is not None else spec.base,
-                       shapes, lanes, measured, mesh=mesh)
-
-    def run(self) -> list[SolveJob]:
-        done: list[SolveJob] = []
-        groups: dict[tuple, list[SolveJob]] = collections.defaultdict(list)
-        for job in self.drain():
-            groups[job.shape_key()].append(job)
-        for key, jobs in groups.items():
-            variant, fn = self._dispatcher.resolve(key)
-            done.extend(self.dispatch_group(self.spec, fn, key, jobs,
-                                            variant=variant))
-        return done

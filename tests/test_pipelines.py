@@ -1,7 +1,7 @@
 """Fused solver pipelines vs oracles (shape/dtype/batch sweeps incl.
 non-power-of-two partial-vector tails, paper Feature 3), registry-driven
 auto-discovery checks, degenerate-input guard paths, inductive-domain
-masking (no garbage-lane reads), and the PipelineEngine service."""
+masking (no garbage-lane reads), and serving through SolverMux."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -14,7 +14,7 @@ from repro.pipelines import (cholesky_solve_pallas, cholesky_solve_unfused,
                              expand_complex_channel, mmse_equalize_composed,
                              mmse_equalize_pallas, qr_solve_pallas,
                              qr_solve_unfused)
-from repro.serve import PipelineEngine, SolveJob
+from repro.serve import SolverMux
 
 from conftest import assert_close
 
@@ -288,36 +288,31 @@ def test_trisolve_masked_lanes_never_read_garbage():
 
 # ---------------- serving ----------------
 
-def test_pipeline_engine_serves_and_pads():
+def test_mux_serves_and_pads():
     """Jobs of mixed shapes, lane-pool padding: every job gets its own
     answer; padded identity lanes never contaminate real ones."""
-    eng = PipelineEngine("cholesky_solve", lanes=4)
+    mux = SolverMux(lanes=4)
     jobs = []
     for n in (8, 8, 12):                  # 2 groups; both need padding
         a = spd(1, n)[0]
         b = RNG.standard_normal((n, 2)).astype(np.float32)
-        jobs.append(eng.submit(SolveJob(args=(a, b))))
-    done = eng.run()
-    assert len(done) == 3 and not eng._queue
+        jobs.append(mux.submit("cholesky_solve", a, b))
+    done = mux.run()
+    assert len(done) == 3 and mux.pending() == 0
     for j in jobs:
         a, b = j.args
         want = np.asarray(ref.cholesky_solve(a[None], b[None]))[0]
-        assert_close(j.out, want, rtol=1e-4, name="engine-job")
+        assert_close(j.out, want, rtol=1e-4, name="mux-job")
 
 
-def test_pipeline_engine_matches_direct_batch():
+def test_mux_matches_direct_batch():
     """One full lane group == a direct pallas call on the same stack."""
-    eng = PipelineEngine("mmse_equalize", lanes=4)
+    mux = SolverMux(lanes=4)
     h = RNG.standard_normal((4, 16, 12)).astype(np.float32)
     y = RNG.standard_normal((4, 16, 1)).astype(np.float32)
-    jobs = [eng.submit(SolveJob(args=(h[i], y[i]))) for i in range(4)]
-    eng.run()
+    jobs = [mux.submit("mmse_equalize", h[i], y[i]) for i in range(4)]
+    mux.run()
     direct = np.asarray(mmse_equalize_pallas(jnp.asarray(h),
                                              jnp.asarray(y)))
     np.testing.assert_allclose(np.stack([j.out for j in jobs]), direct,
                                rtol=0, atol=0)
-
-
-def test_pipeline_engine_rejects_non_pipeline():
-    with pytest.raises(ValueError):
-        PipelineEngine("gemm")

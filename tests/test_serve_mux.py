@@ -287,33 +287,3 @@ def test_mux_metrics_reset():
     mux.reset_metrics()
     snap = mux.metrics()
     assert snap.total_jobs == 0 and snap.total_launches == 0
-
-
-def test_engine_shim_exports_mux_and_deprecates():
-    """The legacy repro.serve.engine import path serves the new API but
-    warns: new code should import from repro.serve."""
-    import importlib
-    import sys
-    import warnings
-
-    sys.modules.pop("repro.serve.engine", None)
-    with pytest.warns(DeprecationWarning, match="repro.serve"):
-        engine = importlib.import_module("repro.serve.engine")
-    assert engine.SolverMux is SolverMux
-    for name in ("DecodeEngine", "PipelineEngine", "Request", "SolveJob"):
-        assert hasattr(engine, name)
-    # re-import of the cached module is silent (module-level warning)
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
-        from repro.serve.engine import PipelineEngine  # noqa: F401
-    # the launch-supervision seam rides along: the shim's SolverMux
-    # accepts an injector, and importing the faults module directly
-    # (as mux.py now does) never trips the deprecation warning
-    from repro.serve import FaultInjector
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
-        from repro.serve.faults import FaultInjector as direct
-    assert direct is FaultInjector
-    mux = engine.SolverMux(lanes=2, clock=ManualClock(),
-                           injector=FaultInjector({}))
-    assert isinstance(mux.injector, FaultInjector)

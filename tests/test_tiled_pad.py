@@ -19,7 +19,6 @@ from repro.pipelines import (cholesky_solve_tiled, mmse_equalize_tiled,
                              qr_solve_tiled, tiled_padded_n)
 from repro.pipelines.cholesky_solve import pad_identity, pad_rows
 from repro.serve import ManualClock, SolverMux
-from repro.serve.solver import PipelineEngine, SolveJob
 
 from conftest import assert_close
 from strategies import spd_system, tall_system
@@ -144,21 +143,15 @@ def _small_jobs(count, n=8):
     return [(a[i], b[i]) for i in range(count)]
 
 
-@pytest.mark.parametrize("engine", ["mux", "pipeline_engine"])
+@pytest.mark.parametrize("engine", ["mux"])
 def test_snapshot_totals_the_pad_flops(engine):
     """3 jobs on 2 lanes, one filler lane: it is counted whole, and the
     snapshot sums the launches' records."""
-    jobs = _small_jobs(3)
-    if engine == "mux":
-        eng = SolverMux(lanes=2, clock=ManualClock())
-        for a, b in jobs:
-            eng.submit("cholesky_solve", a, b)
-    else:
-        eng = PipelineEngine("cholesky_solve", lanes=2, clock=ManualClock())
-        for a, b in jobs:
-            eng.submit(SolveJob((a, b)))
-    eng.run()
-    snap = eng.metrics()
+    mux = SolverMux(lanes=2, clock=ManualClock())
+    for a, b in _small_jobs(3):
+        mux.submit("cholesky_solve", a, b)
+    mux.run()
+    snap = mux.metrics()
     assert sum(lr.padded for lr in snap.launches) == 1
     assert snap.total_pad_flops == sum(lr.pad_flops for lr in snap.launches)
     assert snap.total_pad_flops == pytest.approx(_chol_flops(8, 2))

@@ -18,7 +18,7 @@ from repro.pipelines import (cholesky_solve_blocked, cholesky_solve_pallas,
                              mmse_equalize_split_pallas, qr_solve_blocked,
                              qr_solve_pallas)
 from repro.roofline.hlo_costs import analyze_hlo
-from repro.serve import ManualClock, PipelineEngine, SolveJob, SolverMux
+from repro.serve import ManualClock, SolverMux
 
 from conftest import assert_close
 from strategies import channel_planes, floats, fuzzed, integers, spd_system
@@ -296,19 +296,19 @@ def test_mux_pads_split_complex_bucket_from_variant_filler():
     assert_close(job.out, want, rtol=1e-3, name="split-padded")
 
 
-def test_pipeline_engine_dispatches_blocked():
-    eng = PipelineEngine("cholesky_solve", lanes=2)
+def test_mux_dispatches_blocked():
+    mux = SolverMux(lanes=2)
     rng = np.random.default_rng(13)
-    jobs = [eng.submit(SolveJob(args=(
-        sample_spd(rng, 1, 128)[0],
-        rng.standard_normal((128, 2)).astype(np.float32))))
-        for _ in range(2)]
-    eng.run()
-    assert eng.metrics()["cholesky_solve"].dispatch_counts == \
+    jobs = [mux.submit("cholesky_solve",
+                       sample_spd(rng, 1, 128)[0],
+                       rng.standard_normal((128, 2)).astype(np.float32))
+            for _ in range(2)]
+    mux.run()
+    assert mux.metrics()["cholesky_solve"].dispatch_counts == \
         {"blocked": 1}
     for j in jobs:
         want = K.get("cholesky_solve").run_oracle_lane(*j.args)
-        assert_close(j.out, want, rtol=1e-3, name="engine-blocked")
+        assert_close(j.out, want, rtol=1e-3, name="mux-blocked")
 
 
 # ---------------- FFT chunked twiddle table ----------------

@@ -26,9 +26,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.cholesky import cholesky_pallas
-from repro.kernels.common import (dot, interpret_default, iota, put_col,
-                                  put_row, resolve_backend, take_col,
-                                  take_row)
+from repro.kernels.common import (dot, eye, interpret_default, iota,
+                                  put_col, put_row, resolve_backend,
+                                  take_col, take_row)
 from repro.kernels.trisolve import trisolve_pallas
 
 # Relative pivot threshold (LAPACK pstrf-style): a pivot below
@@ -170,13 +170,15 @@ def _panel_factor_forward_step(j, carry, *, o, thresh):
     substitution row it finishes (the blocked analog of
     ``factor_forward_step``).
 
-    carry: (c, y) with c the full-height (n, bs) column slab [cols
-    o..o+bs) of the working matrix] and y the (n, m) right-hand sides.
-    ``g = o + j`` is the global pivot; the rank-1 update is confined to
-    the REMAINING slab columns (cols > j) — trailing columns outside
-    the slab get their whole panel's contribution later in one SYRK.
+    carry: ``(c, *ys)`` with c an (r, bs) column slab of the working
+    matrix (columns o..o+bs; the full height, or the diagonal block alone
+    with o = 0) and each y an (r, m) block of right-hand sides on the
+    same rows, forward-solved alike.  ``g = o + j`` is the pivot row; the
+    rank-1 update is confined to the REMAINING slab columns (cols > j) —
+    trailing columns outside the slab get their whole panel's
+    contribution later in one SYRK.
     """
-    c, y = carry
+    c, *ys = carry
     n, bs = c.shape
     rows = iota((n, 1), 0)
     cols = iota((1, bs), 1)
@@ -197,10 +199,12 @@ def _panel_factor_forward_step(j, carry, *, o, thresh):
     c = c - jnp.where(live, newcol * w, 0.0)
     c = put_col(c, j, newcol)
     # fused forward substitution consuming the finished column
-    yg = take_row(y, g) * inv
-    y = put_row(y, g, yg)
-    y = y - jnp.where(live, newcol * yg, 0.0)
-    return c, y
+    out = [c]
+    for y in ys:
+        yg = take_row(y, g) * inv
+        y = put_row(y, g, yg)
+        out.append(y - jnp.where(live, newcol * yg, 0.0))
+    return tuple(out)
 
 
 def _trailing_update(slab, pan, pt, *, o, bs: int):
@@ -212,11 +216,16 @@ def _trailing_update(slab, pan, pt, *, o, bs: int):
     return slab - dot(pm, pt.T)
 
 
+def _rows(t, bs: int):
+    """Rows [t*bs, (t+1)*bs) at a traced ``t``, as a ref index."""
+    return pl.ds(pl.multiple_of(t * bs, bs), bs)
+
+
 def _row_block(ref, t, bs: int, *lead):
     """Rows [t*bs, (t+1)*bs) of the (n, c) slab ``ref[lead]`` at a traced
     ``t`` — a sublane-dynamic ref read, which Mosaic lowers (a value
     slice would not)."""
-    return ref[(*lead, pl.ds(pl.multiple_of(t * bs, bs), bs), slice(None))]
+    return ref[(*lead, _rows(t, bs), slice(None))]
 
 
 def _cholesky_solve_blocked_kernel(a_ref, b_ref, x_ref, a_scr, y_scr,
@@ -332,9 +341,14 @@ def cholesky_solve_blocked(a: jax.Array, b: jax.Array, *,
 # steps = tiles = n // bs:
 #
 #   cell (i, s, t) with s < steps, t == s   panel cell: factor panel s
-#     (bs fused factor+forward-subst columns) from the double-buffered
-#     panel carry, stash the factored panel for the trailing cells, and
-#     DMA it out to the HBM factor buffer.
+#     from the double-buffered panel carry as a blocked panel — the
+#     ordered column loop (factor with the forward substitution fused)
+#     runs on the (bs, bs) diagonal block alone, its right-hand sides
+#     y's own row block and the identity, which comes out as the
+#     block's (guarded) inverse; the rows below the block (refined
+#     once) and their forward update are then GEMMs on the MXU.  The
+#     factored panel is stashed for the trailing cells and DMA'd out to
+#     the HBM factor buffer.
 #   cell (i, s, t) with s < steps, t > s    trailing cell: DMA slab t in,
 #     apply the panel's rank-bs SYRK update, DMA it back out.  The slab
 #     for t == s + 1 is additionally stashed into the *other* half of the
@@ -399,9 +413,10 @@ def tiled_vmem_floats(n: int, bs: int, m: int) -> int:
     against :data:`TILED_VMEM_BUDGET_BYTES` at call time.
 
       slab scratch (n, bs) + double-buffered panel carry (2, n, bs)
+      + the panel's diagonal-block inverse (bs, bs)
       + rhs carry (n, m) + b block (n, m) + x block (n, m)
     """
-    return 3 * n * bs + 3 * n * m
+    return 3 * n * bs + bs * bs + 3 * n * m
 
 
 def _tiled_factor_cell(i, s2, t, *, first_hbm, work_hbm, slab_scr,
@@ -413,7 +428,20 @@ def _tiled_factor_cell(i, s2, t, *, first_hbm, work_hbm, slab_scr,
     comes from (the raw input for the Cholesky pipeline, the work buffer
     itself for MMSE, whose Gram phase already wrote it); every later
     read and every write go to ``work_hbm``.  ``pan_scr`` is the
-    double-buffered panel carry, indexed by the panel step's parity."""
+    double-buffered panel carry, indexed by the panel step's parity.
+
+    The panel is blocked: the ordered column loop, with its pivot guard
+    and fused forward substitution, runs on the (bs, bs) diagonal block
+    S11 and on y's own row block y11 only.  Carrying the identity as
+    extra right-hand sides makes the loop also return Z = L11^-1 (a
+    deficient column's row of Z is 0), so the rows below the block are
+    L21 = S21 Z^T and their forward update y2 -= L21 (Z y11) — GEMMs on
+    the MXU.  A product with an explicit inverse loses about
+    cond(L11) ulps, so L21 takes one refinement step,
+    L21 += (S21 - L21 L11^T) Z^T, which brings it back to the column
+    loop's accuracy (a rank-deficient matrix's small pivots need it).
+    The panel slab comes out as before: zero above the block, then L11,
+    then L21."""
     @pl.when(t == s2)
     def _panel():
         @pl.when(s2 == 0)                 # first panel: no stash yet
@@ -425,14 +453,21 @@ def _tiled_factor_cell(i, s2, t, *, first_hbm, work_hbm, slab_scr,
             pan_scr[0] = slab_scr[...]
 
         half = s2 % 2
-        c, y = jax.lax.fori_loop(         # from the pre-updated panel slab
+        l11, y11, z = jax.lax.fori_loop(  # from the pre-updated panel slab
             0, bs,
-            functools.partial(_panel_factor_forward_step, o=s2 * bs,
+            functools.partial(_panel_factor_forward_step, o=0,
                               thresh=thresh),
-            (pan_scr[half], y_scr[...]))
-        pan_scr[half] = c                 # trailing cells read this
-        y_scr[...] = y
-        slab_scr[...] = c
+            (_row_block(pan_scr, s2, bs, half), _row_block(y_scr, s2, bs),
+             eye(bs)))
+        below = jnp.where(iota((slab_scr.shape[0], 1), 0) >= (s2 + 1) * bs,
+                          pan_scr[half], 0.0)
+        l21 = dot(below, z.T)             # zero above the block's end
+        l21 = l21 + dot(below - dot(l21, l11.T), z.T)      # refinement
+        y_scr[...] = y_scr[...] - dot(l21, y11)
+        y_scr[_rows(s2, bs), :] = y11
+        pan_scr[half] = l21               # trailing cells read this
+        pan_scr[half, _rows(s2, bs), :] = l11
+        slab_scr[...] = pan_scr[half]
         cp = pltpu.make_async_copy(
             slab_scr, work_hbm.at[i, :, pl.ds(s2 * bs, bs)], sem)
         cp.start()
@@ -489,7 +524,7 @@ def _tiled_backsub_cell(i, t, *, steps: int, work_hbm, slab_scr, y_scr,
     lbt = _row_block(slab_scr, rt, bs).T
     xt = jax.lax.fori_loop(
         0, bs, lambda k, zz: back_substitution_step(k, lbt, zz, n=bs), zt)
-    y_scr[pl.ds(pl.multiple_of(o, bs), bs), :] = xt
+    y_scr[_rows(rt, bs), :] = xt
 
     @pl.when(t == steps - 1)
     def _finish():
@@ -530,11 +565,14 @@ def cholesky_solve_tiled(a: jax.Array, b: jax.Array, *,
     cell exactly one (N, bs) column slab is DMA'd in (plus the
     double-buffered panel carry), the trailing matrix stays HBM-resident
     in a ``pl.ANY`` work buffer, and the per-cell working set is
-    ``tiled_vmem_floats(n, bs, m)`` = O(N*bs).  The deficiency threshold
-    is precomputed host-side (one fused O(N) diagonal reduction) because
-    the first panel cell needs it before any other slab is seen.
-    Registered as the ``tiled`` variant of the ``cholesky_solve`` spec;
-    the dispatcher picks it for N >= 512.
+    ``tiled_vmem_floats(n, bs, m)`` = O(N*bs).  Each panel is blocked:
+    the ordered column loop runs on its (bs, bs) diagonal block alone,
+    and its lower rows, the forward update below it and every trailing
+    update are GEMMs on the MXU.  The deficiency threshold is
+    precomputed host-side (one fused O(N) diagonal reduction) because the
+    first panel cell needs it before any other slab is seen.  Registered
+    as the ``tiled`` variant of the ``cholesky_solve`` spec; the
+    dispatcher picks it for N >= 512.
 
     With ``bs`` unset the slabs are ``TILED_BS`` wide and an N they do
     not divide runs as ``[[A, 0], [0, I]]`` against ``[b; 0]`` at
@@ -561,8 +599,21 @@ def cholesky_solve_tiled(a: jax.Array, b: jax.Array, *,
         (n, bs, m)
     if interpret is None:
         interpret = interpret_default()
+    x, _ = _tiled_solve_and_factor(thr, a, b, bs=bs, interpret=interpret)
+    if n > n_job:
+        with jax.named_scope(TILED_PAD_SCOPE):
+            x = x[:, :n_job]
+    return x
+
+
+def _tiled_solve_and_factor(thr, a, b, *, bs: int, interpret: bool):
+    """The tiled kernel on whole ``bs``-wide slabs: ``(x, L)``, with L
+    the factor it leaves in its HBM work buffer (zero above the
+    diagonal), which ``cholesky_solve_tiled`` drops."""
+    bsz, n, _ = a.shape
+    m = b.shape[-1]
     steps = n // bs
-    x, _ = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_cholesky_solve_tiled_kernel, bs=bs, steps=steps),
         grid=(bsz, steps + 1, steps),
         in_specs=[
@@ -592,10 +643,6 @@ def cholesky_solve_tiled(a: jax.Array, b: jax.Array, *,
         interpret=interpret,
         name="cholesky_solve_tiled",
     )(thr, a, b)
-    if n > n_job:
-        with jax.named_scope(TILED_PAD_SCOPE):
-            x = x[:, :n_job]
-    return x
 
 
 def cholesky_solve_unfused(a: jax.Array, b: jax.Array, *,

@@ -218,9 +218,10 @@ def mmse_equalize_split(hr: jax.Array, hi: jax.Array, yr: jax.Array,
 def mmse_tiled_vmem_floats(m: int, n: int, bs: int, k: int) -> int:
     """Per-grid-cell VMEM working set of the tiled MMSE equalizer, in
     float32 elements — two (m, bs) channel slabs + Gram staging (bs, bs)
-    + Cholesky slab (n, bs) + panel carry (2, n, bs) + rhs carry (n, k)
-    + y block (m, k) + x block (n, k)."""
-    return 2 * m * bs + bs * bs + 3 * n * bs + m * k + 2 * n * k
+    + Cholesky slab (n, bs) + panel carry (2, n, bs) + the panel's
+    diagonal-block inverse (bs, bs) + rhs carry (n, k) + y block (m, k)
+    + x block (n, k)."""
+    return 2 * m * bs + 2 * bs * bs + 3 * n * bs + m * k + 2 * n * k
 
 
 def _mmse_tiled_kernel(h_hbm, y_ref, x_ref, g_hbm, hr_scr, ht_scr, gb_scr,

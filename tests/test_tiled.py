@@ -1,13 +1,18 @@
-"""HBM-scale tiled solver variants: equality-vs-oracle sweeps, VMEM
-working-set accounting (per-cell O(n*bs), never O(n^2)), rank-deficiency
-pivot-guard behavior at tile boundaries, F4 masking (NaN-poisoned upper
-triangle), dispatch routing at registry and mux level, and hypothesis
-fuzzing via the shared strategies harness.
+"""HBM-scale tiled solver variants: equality-vs-oracle sweeps, the
+tiled Cholesky's factor against NumPy's and its error at the STAP
+cell's conditioning, VMEM working-set accounting (per-cell O(n*bs),
+never O(n^2)), rank-deficiency pivot-guard behavior at tile boundaries,
+F4 masking (NaN-poisoned upper triangle), dispatch routing at registry
+and mux level, and hypothesis fuzzing via the shared strategies harness.
 
 The n in {512, 1024} x bs in {64, 128} interpret-mode sweeps are marked
 ``slow`` (the scheduled CI job runs them); tier-1 keeps the midrange
 shapes plus the no-compute dispatch assertions for the big buckets.
 """
+import importlib
+import os
+import sys
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -71,6 +76,49 @@ def test_tiled_matches_oracle_large(name, n, bs):
     got = _run_tiled(name, a, b, bs=bs)
     assert_close(got, _oracle(name, a, b), rtol=2e-3,
                  name=f"tiled-{name} n={n} bs={bs}")
+
+
+def _tiled_factor(a, b, bs):
+    """The factor the tiled Cholesky leaves in its HBM work buffer, for
+    one lane ``a`` (1, n, n) that ``bs`` tiles."""
+    cs = importlib.import_module("repro.pipelines.cholesky_solve")
+    thr = np.maximum(cs.DEFAULT_EPS * a.diagonal(axis1=1, axis2=2).max(-1),
+                     1e-30).astype(np.float32)
+    _, l = cs._tiled_solve_and_factor(jnp.asarray(thr), jnp.asarray(a),
+                                      jnp.asarray(b), bs=bs, interpret=True)
+    return np.asarray(l)[0]
+
+
+@pytest.mark.parametrize("n,bs", [(256, 64), (768, 128)])
+def test_tiled_cholesky_factor_matches_numpy(n, bs):
+    """The factor the kernel leaves in its HBM work buffer — each panel
+    blocked: the ordered loop on the diagonal block, the rows below it
+    by a GEMM against the block's inverse — is NumPy's Cholesky factor,
+    zero above the diagonal."""
+    a, b = spd_system(n + bs, 1, n, k=2)
+    l = _tiled_factor(a, b, bs)
+    assert not np.triu(l, 1).any()
+    assert_close(l, np.linalg.cholesky(a[0].astype(np.float64)),
+                 rtol=1e-5, name=f"factor n={n} bs={bs}")
+
+
+def test_tiled_cholesky_meets_the_stap_limit():
+    """One job of the STAP cell (KASSPER Data Set 1: n = 704, padded to
+    768, loaded condition number about 5e5) on one lane: the tiled
+    solve's error against the job kind's complex128 reference stays
+    under the configuration's limit."""
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from chipbench import harness
+    from chipbench.jobs import stap_smi
+    cfg = dict(harness.cell(harness.load_benchmark(), "stap.cpi_bulk")[1],
+               segments=1)
+    args = stap_smi.make_request(cfg, np.random.default_rng([2 ** 31 + 18,
+                                                             0]))
+    (a, b), = args
+    got = cholesky_solve_tiled(jnp.asarray(a[None]), jnp.asarray(b[None]))
+    err = harness.rel_errors(np.asarray(got), stap_smi.reference(cfg, args))
+    assert np.max(err) < cfg["limits"]["max_rel_err"], err
 
 
 @fuzzed(max_examples=6, n_tiles=integers(2, 4), bs=sampled(32, 64),
@@ -149,23 +197,32 @@ def test_tiled_rejects_over_budget_shapes():
 
 # ---------------- pivot guards at tile boundaries ----------------
 
-@pytest.mark.parametrize("rank", [40, 100, 129])
-def test_tiled_cholesky_deficiency_across_tile_boundaries(rank):
+@pytest.mark.parametrize("n,bs,rank", [
+    pytest.param(256, 64, 40, id="40"),
+    pytest.param(256, 64, 100, id="100"),
+    pytest.param(256, 64, 129, id="129"),
+    pytest.param(512, 128, 200, id="n512-bs128-200")])
+def test_tiled_cholesky_deficiency_across_tile_boundaries(n, bs, rank):
     """Rank-deficient SPD input whose numerical rank ends inside the
-    first, second, and third tile (bs=64): every lane stays finite, and
-    for a CONSISTENT right-hand side (b in range(A)) the guarded solve
-    still satisfies A x ~= b — the solution on the deficient subspace is
-    not unique, so elementwise equality with the fused kernel is not a
-    property; the residual is."""
-    n = 256
+    first, second, and third tile (bs=64), and in the middle of the
+    second at bs=128, so that the deficient columns' rows below the
+    diagonal block come out of the panel's GEMM: every lane stays
+    finite, each deficient column of the factor (unit pivot) is exactly
+    zero below it, and for a CONSISTENT right-hand side (b in range(A))
+    the guarded solve still satisfies A x ~= b — the solution on the
+    deficient subspace is not unique, so elementwise equality with the
+    fused kernel is not a property; the residual is."""
     a, _ = spd_system(rank, 1, n, k=2, rank=rank)
     rng = np.random.default_rng(rank + 1)
     b = (a @ rng.standard_normal((1, n, 2))).astype(np.float32)
     got = np.asarray(cholesky_solve_tiled(jnp.asarray(a),
-                                          jnp.asarray(b), bs=64))
+                                          jnp.asarray(b), bs=bs))
     assert np.isfinite(got).all()
     resid = np.abs(a @ got - b).max() / np.abs(b).max()
     assert resid < 1e-3, (rank, resid)
+    l = _tiled_factor(a, b, bs)
+    unit = np.flatnonzero(np.diag(l) == 1.0)
+    assert unit.size > 0 and not np.tril(l, -1)[:, unit].any(), (rank, unit)
 
 
 @pytest.mark.parametrize("col", [10, 70, 130])

@@ -120,9 +120,12 @@ class EngineCore:
         ``nan`` fault below, or a wrapper of this method); gathering it
         costs nothing.
 
-        ``device`` commits the inputs to one mesh shard's device before
-        the call (mesh-sharded muxes placing a non-spanning launch);
-        ``None`` keeps the legacy default-device path untouched.
+        ``device`` (a device or a sharding) is where a mesh-sharded mux
+        puts the inputs, straight from the host: one shard's device for
+        a placed launch, the mesh's ``NamedSharding`` for a spanning one,
+        so each shard's slab goes to its own device and none passes
+        through device 0.  ``None`` keeps the legacy default-device path
+        untouched.
 
         ``fault_ctx`` identifies the attempt to an attached
         :class:`repro.serve.faults.FaultInjector` (``self.injector``):
@@ -148,9 +151,10 @@ class EngineCore:
                 raise InjectedLaunchError(fault.reason)
         t0 = self.wall()
         with span("serve.core.copy_in"):
-            inputs = [jnp.asarray(p) for p in padded]
-            if device is not None:
-                inputs = [jax.device_put(x, device) for x in inputs]
+            if device is None:
+                inputs = [jnp.asarray(p) for p in padded]
+            else:
+                inputs = [jax.device_put(p, device) for p in padded]
         with span("serve.core.execute"):
             out = fn(*inputs)
             out.copy_to_host_async()

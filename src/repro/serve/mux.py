@@ -123,7 +123,20 @@ per-shard —
   * **placement** — non-spanning launches are committed to the shard
     with the most remaining per-poll budget (then least accumulated
     load; deterministic index tiebreak), so flushes land on the
-    least-loaded shard group.
+    least-loaded shard group.  A launch's priced cost, at its own
+    width, is held on its shard from the moment it is placed, and
+    settled into the shard's load when it finishes, so a launch placed
+    while another is in flight is placed knowing of it.
+  * **wide spanning flushes** — without a policy, a bucket flush on a
+    mesh whose shards are all healthy sends its G whole units of
+    ``lanes * mesh_size`` jobs as the spanning launches of
+    :func:`launch_plan` (G), p units (p lane groups a shard) each, then
+    the remaining groups per shard, one group a launch, the partial
+    last; two launches are in flight at once, as on one device.  A
+    spanning launch's planes go from the host straight to each shard's
+    slab (the mesh's ``NamedSharding``), and its answer comes back in
+    the layout the kernel writes (``Layout.AUTO``).  With a fault
+    injector the launches stay one unit or one group at a time.
   * **hot-bucket splitting (cross-shard work stealing)** — a bucket
     whose backlog reaches ``shard_split_pressure * lanes`` is offered
     as mesh-spanning flushes: one ``shard_map`` launch whose lane axis
@@ -285,6 +298,16 @@ def launch_plan(groups: int) -> list[int]:
     return plan
 
 
+def _merge(chunks: list, plan: list[int]) -> list[list]:
+    """The first ``sum(plan)`` of ``chunks``, joined in order into one
+    list of jobs per entry of ``plan``."""
+    merged, i = [], 0
+    for k in plan:
+        merged.append([job for chunk in chunks[i:i + k] for job in chunk])
+        i += k
+    return merged
+
+
 @dataclasses.dataclass
 class OverloadPolicy:
     """Overload-management knobs for :class:`SolverMux` (see the module
@@ -356,6 +379,7 @@ class _Started:
     answer: tuple | None = None
     reason: str = "launch_failed"
     groups: int = 1                 # lane groups merged into the launch
+    hold: int | None = None         # its LaneShards.hold, on a mesh
 
 
 @dataclasses.dataclass(eq=False)
@@ -433,10 +457,11 @@ class SolverMux(EngineCore):
 
     Parameters:
       lanes     lane-group width (per-pipeline pools all share it): the
-                unit a launch is padded to.  A launch carries one group,
-                except where a bucket flush with four or more full
-                groups ready sends them as a few launches of several
-                groups each (:func:`launch_plan`)
+                unit a launch is padded to.  A launch carries one group
+                (one a shard, when it spans a mesh), except where a
+                bucket flush with four or more full groups (or spanning
+                units) ready sends them as a few launches of several
+                each (:func:`launch_plan`)
       max_wait  seconds a partial bucket may age before ``poll`` flushes
                 it anyway (``None``: only deadlines/pressure flush
                 partials)
@@ -966,7 +991,12 @@ class SolverMux(EngineCore):
                riders: tuple = (), now: float | None = None,
                mesh: int = 1, shard: int | None = None) -> _Started:
         """Stack and pad the launch, place it, and start its first
-        attempt (:meth:`_start`); :meth:`_supervise` finishes it."""
+        attempt (:meth:`_start`); :meth:`_supervise` finishes it.
+
+        On a mesh the launch's priced cost, at its own width, is held on
+        the shard it is placed on (on every shard when it spans the
+        mesh) until it finishes, so a launch begun while this one is in
+        flight is placed knowing of it."""
         spec = pool.spec
         t = self.clock() if now is None else now
         with span("serve.mux.stack"):
@@ -996,24 +1026,37 @@ class SolverMux(EngineCore):
                 stacked = [np.stack([np.asarray(j.args[i]) for j in chunk])
                            for i in range(len(chunk[0].args))]
             padded, pad = pad_group(spec, stacked, width, variant=variant)
-        launch = _Started(pool, key, list(chunk), riders, padded, pad, t,
-                          mesh, shard,
-                          groups=(len(chunk) + len(riders) + pad) // width)
-        if mesh == 1 and self.shards is not None:
-            # a quarantined shard owed a probe gets this launch; else
-            # place on the least-loaded healthy shard
-            while self._probe_ready and launch.probing is None:
-                p = self._probe_ready.pop(0)
-                if self.shards.quarantined(p):
-                    launch.shard = launch.probing = p
-            if launch.probing is None and (
-                    launch.shard is None
-                    or self.shards.quarantined(launch.shard)):
-                launch.shard = self.shards.pick(
-                    among=self.shards.healthy())
-            launch.device = self.shards.devices[launch.shard]
+            launch = _Started(pool, key, list(chunk), riders, padded, pad,
+                              t, mesh, shard,
+                              groups=(len(chunk) + len(riders) + pad)
+                              // width)
+            if self.shards is not None:
+                self._place(launch)
         self._start(launch)
         return launch
+
+    def _place(self, launch: _Started) -> None:
+        """Put ``launch`` on the mesh and hold its cost there: a spanning
+        launch on every shard, through the mesh's sharding; else a
+        quarantined shard owed a probe, the shard admission chose, or
+        the least-loaded healthy shard."""
+        cost = launch.pool.dispatcher.price(
+            launch.key, launch.groups * self.lanes * launch.mesh,
+            mesh=launch.mesh)
+        if launch.mesh > 1:
+            launch.device = self.shards.sharding
+            launch.hold = self.shards.hold(None, cost)
+            return
+        while self._probe_ready and launch.probing is None:
+            p = self._probe_ready.pop(0)
+            if self.shards.quarantined(p):
+                launch.shard = launch.probing = p
+        if launch.probing is None and (
+                launch.shard is None
+                or self.shards.quarantined(launch.shard)):
+            launch.shard = self.shards.pick(among=self.shards.healthy())
+        launch.device = self.shards.devices[launch.shard]
+        launch.hold = self.shards.hold(launch.shard, cost)
 
     def _start(self, launch: _Started) -> None:
         """Start one attempt of ``launch``: resolve its entry point and
@@ -1069,32 +1112,31 @@ class SolverMux(EngineCore):
             done.append(job)
         return done
 
+    def _release(self, launch: _Started) -> None:
+        """Drop ``launch``'s held shard load, unsettled (a mesh only)."""
+        if self.shards is not None:
+            self.shards.release(launch.hold)
+            launch.hold = None
+
     def _flush_event(self, pool: _LanePool, key: tuple, chunk: list,
                      riders: tuple, variant, t: float, mesh: int,
-                     rec_shard: int, shard: int | None,
+                     rec_shard: int, hold: int | None,
                      groups: int = 1) -> None:
-        """Shard load accounting + the ``flush`` event.  mesh/shard
-        fields only appear on sharded muxes, and ``groups`` only on a
-        launch of several lane groups, so the single-device event
-        stream of one group a launch (golden traces) is unchanged."""
+        """Shard load accounting + the ``flush`` event: the launch's
+        hold is settled at its priced cost, at its own width, under the
+        variant it ran.  mesh/shard fields only appear on sharded muxes,
+        and ``groups`` only on a launch of several lane groups, so the
+        event stream of one group a launch (golden traces) is
+        unchanged."""
+        wide = {"groups": groups} if groups > 1 else {}
         if self.shards is not None:
-            cost = pool.dispatcher.price(key, self.lanes * max(1, mesh),
-                                         mesh=mesh)
-            if mesh > 1:
-                self.shards.note_all(cost)
-            else:
-                self.shards.note(shard, cost)
-            self._event("flush", t=t, pipeline=pool.spec.name,
-                        variant=variant.name, shape=_shape_label(key),
-                        jobs=[j.seq for j in chunk],
-                        coalesced=[j.seq for j in riders],
-                        mesh=mesh, shard=rec_shard)
-        else:
-            wide = {"groups": groups} if groups > 1 else {}
-            self._event("flush", t=t, pipeline=pool.spec.name,
-                        variant=variant.name, shape=_shape_label(key),
-                        jobs=[j.seq for j in chunk],
-                        coalesced=[j.seq for j in riders], **wide)
+            self.shards.settle(hold, pool.dispatcher.price(
+                key, groups * self.lanes * mesh, mesh=mesh))
+            wide.update(mesh=mesh, shard=rec_shard)
+        self._event("flush", t=t, pipeline=pool.spec.name,
+                    variant=variant.name, shape=_shape_label(key),
+                    jobs=[j.seq for j in chunk],
+                    coalesced=[j.seq for j in riders], **wide)
 
     def _watchdog(self, pool: _LanePool, key: tuple, variant, width: int,
                   mesh: int, measured: float, t: float) -> None:
@@ -1185,15 +1227,17 @@ class SolverMux(EngineCore):
                                        measured, t)
                         self._flush_event(pool, key, chunk, riders,
                                           variant, t, mesh, rec_shard,
-                                          shard, groups)
+                                          launch.hold, groups)
                         return done
             if not failed:
                 reason = "nonfinite_output"
             if groups > 1:
                 # a launch of several lane groups decomposes into its
                 # groups at once, each running the ladder below at the
-                # one-group width: a poisoned lane fails only its own
-                # job, and the fault path compiles no new batch size
+                # one-group width (per shard, on a mesh): a poisoned
+                # lane fails only its own job, and the fault path
+                # compiles no new batch size
+                self._release(launch)
                 self._event("retry", t=t, pipeline=spec.name,
                             shape=_shape_label(key),
                             jobs=[j.seq for j in chunk],
@@ -1234,9 +1278,11 @@ class SolverMux(EngineCore):
                             attempt=attempt + 1, reason=reason,
                             backoff=_round(backoff))
                 if mesh == 1 and self.shards is not None and failed:
-                    # re-place away from the shard that just failed
+                    # re-place away from the shard that just failed,
+                    # moving the launch's held load with it
                     launch.probing = None
                     tried.add(shard)
+                    cost = self.shards.release(launch.hold)
                     pickable = ([s for s in self.shards.healthy()
                                  if s not in tried]
                                 or self.shards.healthy()
@@ -1244,6 +1290,7 @@ class SolverMux(EngineCore):
                     launch.shard = rec_shard = self.shards.pick(
                         among=pickable)
                     launch.device = self.shards.devices[launch.shard]
+                    launch.hold = self.shards.hold(launch.shard, cost)
         # ---- retries exhausted: contain, never propagate ----
         if not failed and bad:
             # executed fine but some real lanes are persistently
@@ -1258,8 +1305,10 @@ class SolverMux(EngineCore):
                 done = self._scatter(pool, chunk, riders, res, t,
                                      bad=set(bad))
                 self._flush_event(pool, key, chunk, riders, variant, t,
-                                  mesh, rec_shard, shard)
+                                  mesh, rec_shard, launch.hold)
             return done
+        # the launch's jobs go on to launches of their own, or fail
+        self._release(launch)
         if riders:
             # a poisoned donor must never sink its host: detach the
             # riders (they stay queued) and relaunch the host alone
@@ -1305,25 +1354,24 @@ class SolverMux(EngineCore):
                       now: float | None = None) -> list[SolveJob]:
         """Dispatch a bucket in lane-group chunks.  ``full_only`` leaves
         the trailing partial chunk queued (continuous-batching path).
-        On a mesh, a backlog of at least ``lanes * mesh_size`` drains in
-        mesh-spanning launches first; the remainder goes per-shard.
+        On a mesh whose shards are all healthy, the backlog's first
+        whole multiple of ``lanes * mesh_size`` jobs goes as spanning
+        units of that many jobs, one lane group a shard each; the
+        remaining groups go per shard.
 
-        The full chunks go out as a few launches of several groups each
-        (:func:`launch_plan`), and the launches overlap two deep
-        (:meth:`_launch_chunks`): launch k's answer comes back to the
-        host while launch k+1 is stacked, copied in and dispatched.
-        Every launch is finished before this returns, so callers never
-        see a job in flight."""
+        The launches overlap two deep (:meth:`_launch_chunks`): launch
+        k's answer comes back to the host while launch k+1 is stacked,
+        copied in and dispatched.  Every launch is finished before this
+        returns, so callers never see a job in flight."""
         jobs = pool.buckets[key]
-        done: list[SolveJob] = []
+        units: list = []
         if self.shards is not None and self.shards.all_healthy():
             # spanning launches execute on every shard, so any
             # quarantine degrades the mux to per-shard launches
             total = self.lanes * self.shards.size
-            while len(jobs) >= total:
-                chunk, jobs = jobs[:total], jobs[total:]
-                done.extend(self._launch(pool, key, chunk, now=now,
-                                         mesh=self.shards.size))
+            whole = len(jobs) // total * total
+            units = [jobs[i:i + total] for i in range(0, whole, total)]
+            jobs = jobs[whole:]
         chunks = []
         while len(jobs) >= self.lanes:
             chunk, jobs = jobs[:self.lanes], jobs[self.lanes:]
@@ -1331,7 +1379,7 @@ class SolverMux(EngineCore):
         if jobs and not full_only:
             chunks.append(jobs)
             jobs = []
-        done.extend(self._launch_chunks(pool, key, chunks, now))
+        done = self._launch_chunks(pool, key, chunks, now, units)
         if jobs:
             pool.buckets[key] = jobs
         else:
@@ -1340,47 +1388,55 @@ class SolverMux(EngineCore):
         return done
 
     def _launch_chunks(self, pool: _LanePool, key: tuple, chunks: list,
-                       now: float | None) -> list[SolveJob]:
-        """Launch ``chunks`` of one bucket in order, each supervised.
-        Every chunk is one lane group; all but the last are full.
+                       now: float | None,
+                       units: list = ()) -> list[SolveJob]:
+        """Launch the spanning ``units`` of one bucket (a mesh only),
+        then its ``chunks``, in order, each supervised.  Every chunk is
+        one lane group; all but the last are full.
 
-        Where nothing reads the order of the host steps, the full groups
-        go out as the few launches of :func:`launch_plan`, in job order,
-        and the partial group last.  Each lane's arithmetic, the filler
-        and the answers are those of one group a launch; a launch of
-        several groups pays the launch's fixed host costs (copy in,
+        The G spanning units go out as the launches of
+        :func:`launch_plan` (G), p units each, so p lane groups a shard.
+        Without a mesh the full groups do too, and the partial group
+        goes last; on a mesh each group is a launch of its own, placed
+        on a shard of its own choosing, so a degraded mesh still spreads
+        its groups over the healthy shards.  Each lane's arithmetic, the
+        filler and the answers are those of one group a launch; a launch
+        of several groups pays the launch's fixed host costs (copy in,
         dispatch, gather) once.  A failure in such a launch decomposes
-        it into its groups (:meth:`_supervise`).
+        it into its groups, each run per shard on a mesh
+        (:meth:`_supervise`).
 
         Two launches are in flight at once: launch k+1 is begun
-        (stacked, copied in, dispatched, its copy back started) before
-        launch k is gathered and finished, so k's copy back lands while
-        the host works on k+1.  Each ``LaunchRecord.measured`` is still
-        its own launch's steps, and the events come in launch order.  A
-        failure found at k's gather runs k's ladder there, with k+1 in
-        flight.
+        (stacked, placed, copied in, dispatched, its copy back started)
+        before launch k is gathered and finished, so k's copy back lands
+        while the host works on k+1.  Each launch holds its priced cost
+        on its shard from its placement on, so k+1 is placed knowing of
+        k.  Each ``LaunchRecord.measured`` is still its own launch's
+        steps, and the events come in launch order.  A failure found at
+        k's gather runs k's ladder there, with k+1 in flight.
 
-        Launches stay one group at a time, one after another, where the
-        serial order is part of what the mux promises: with a fault
-        injector (its seeded draws are replayed in launch order) and on
-        a mesh (placement reads the load the previous launch noted as it
-        finished).  The overload policy's rounds never come here: they
+        With a fault injector, whose seeded draws are replayed in launch
+        order, launches stay one unit or one group at a time, one after
+        another.  The overload policy's rounds never come here: they
         launch one admitted candidate at a time (:meth:`_poll_policy`)."""
-        if self.injector is not None or self.shards is not None:
-            return [job for chunk in chunks
-                    for job in self._launch(pool, key, chunk, now=now)]
-        full = sum(len(chunk) == self.lanes for chunk in chunks)
-        merged, i = [], 0
-        for k in launch_plan(full):
-            merged.append([job for chunk in chunks[i:i + k]
-                           for job in chunk])
-            i += k
-        chunks = merged + chunks[i:]
+        mesh = self.shards.size if self.shards is not None else 1
+        if self.injector is not None:
+            return [job for unit in units
+                    for job in self._launch(pool, key, unit, now=now,
+                                            mesh=mesh)] \
+                + [job for chunk in chunks
+                   for job in self._launch(pool, key, chunk, now=now)]
+        launches = [(unit, mesh)
+                    for unit in _merge(units, launch_plan(len(units)))]
+        if self.shards is None:
+            full = sum(len(chunk) == self.lanes for chunk in chunks)
+            chunks = _merge(chunks, launch_plan(full)) + chunks[full:]
+        launches += [(chunk, 1) for chunk in chunks]
         done: list[SolveJob] = []
         held = None
         try:
-            for chunk in chunks:
-                started = self._begin(pool, key, chunk, now=now)
+            for chunk, m in launches:
+                started = self._begin(pool, key, chunk, now=now, mesh=m)
                 prev, held = held, started
                 if prev is not None:
                     done.extend(self._supervise(prev))

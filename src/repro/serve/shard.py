@@ -13,12 +13,19 @@ the serve-side handle on that mesh:
     Because lanes are independent, the sharded program is bit-identical
     to the single-device launch on the same batch — the property the
     sharded-serve tests pin.
-  * **placement** — non-spanning launches are committed to one shard's
-    device (:attr:`devices`); :meth:`pick` chooses the least-loaded
-    shard (optionally budget-first, for the mux's per-shard admission).
-  * **load accounting** — :meth:`note` / :meth:`note_all` accumulate
-    priced launch cost per shard; :meth:`imbalance` is the max/mean
-    skew the metrics snapshot reports.
+  * **placement** — a spanning launch's inputs go from the host straight
+    to each shard's slab (:attr:`sharding`); non-spanning launches are
+    committed to one shard's device (:attr:`devices`); :meth:`pick`
+    chooses the least-loaded shard (optionally budget-first, for the
+    mux's per-shard admission).
+  * **load accounting** — a launch's priced cost is *held* on its shard
+    (every shard, when spanning) from the moment it is placed
+    (:meth:`hold`) and *settled* into :attr:`load` when it finishes
+    (:meth:`settle`), or released if it is abandoned (:meth:`release`),
+    so a launch placed while others are in flight sees theirs.
+    :meth:`note` / :meth:`note_all` add settled cost directly;
+    :meth:`imbalance` is the max/mean skew of the settled load the
+    metrics snapshot reports.
   * **health** — per-shard consecutive-failure streaks
     (:meth:`note_failure` / :meth:`note_success`).  A shard whose
     streak reaches the quarantine threshold is **quarantined**: it
@@ -36,9 +43,11 @@ stays exactly the code it always was.
 """
 from __future__ import annotations
 
+import itertools
 import math
 
 import numpy as np
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed.sharding import shard_map
@@ -55,7 +64,12 @@ class LaneShards:
         self.axis = axis
         self.devices = tuple(np.ravel(mesh.devices))
         self.size = len(self.devices)
+        self.sharding = NamedSharding(mesh, P(axis))
         self.load = [0.0] * self.size
+        # launches placed and not yet finished: token -> (shard or None
+        # for all shards, priced cost)
+        self._held: dict[int, tuple[int | None, float]] = {}
+        self._tokens = itertools.count()
         # per-shard health: consecutive launch-failure streaks and
         # quarantine state (see the module docstring)
         self.fail_streak = [0] * self.size
@@ -144,11 +158,43 @@ class LaneShards:
         accumulated load second, lowest index last — deterministic, so
         replayed traces place identically.  ``among`` restricts the
         candidates (the mux passes :meth:`healthy` while any shard is
-        quarantined; an empty restriction falls back to all shards)."""
+        quarantined; an empty restriction falls back to all shards).
+        The load it reads counts the launches still held in flight."""
         shards = among if among else range(self.size)
         if budgets is None:
-            return max(shards, key=lambda s: (-self.load[s], -s))
-        return max(shards, key=lambda s: (budgets[s], -self.load[s], -s))
+            return max(shards, key=lambda s: (-self.seen(s), -s))
+        return max(shards, key=lambda s: (budgets[s], -self.seen(s), -s))
+
+    def seen(self, shard: int) -> float:
+        """Settled load of ``shard`` plus the cost held on it by launches
+        in flight (exactly the settled load when none is)."""
+        return self.load[shard] + sum(
+            cost for s, cost in self._held.values()
+            if s is None or s == shard)
+
+    def hold(self, shard: int | None, cost: float) -> int:
+        """Hold ``cost`` on ``shard`` (``None``: every shard, a spanning
+        launch) for a launch just placed; returns the token that
+        :meth:`settle` or :meth:`release` takes."""
+        token = next(self._tokens)
+        self._held[token] = (shard, cost)
+        return token
+
+    def settle(self, token: int, cost: float) -> None:
+        """The held launch finished: its ``cost``, priced now, joins the
+        settled load of the shard it is held on."""
+        shard, _ = self._held.pop(token)
+        if shard is None:
+            self.note_all(cost)
+        else:
+            self.note(shard, cost)
+
+    def release(self, token: int | None) -> float:
+        """Drop a hold without settling it (the launch is being
+        re-placed, or handed its jobs to launches of their own) and
+        return the cost it held; 0.0 for a token already settled or
+        released."""
+        return self._held.pop(token, (None, 0.0))[1]
 
     def note(self, shard: int, cost: float) -> None:
         self.load[shard] += cost

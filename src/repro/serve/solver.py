@@ -195,7 +195,11 @@ class VariantDispatcher:
     def resolve_sharded(self, key: tuple):
         """Mesh-spanning counterpart of :meth:`resolve`: the same
         dispatched variant, wrapped over the lane mesh so the batch dim
-        splits across shards.  Requires ``shards``."""
+        splits across shards.  Requires ``shards``.
+
+        As in :meth:`resolve`, the answer comes back in ``Layout.AUTO``
+        (split over the mesh like the inputs), so a wide spanning launch
+        costs no relayout copy of each shard's answer."""
         if self.shards is None:
             raise ValueError(
                 f"{self.spec.name!r} dispatcher has no lane shards; "
@@ -205,7 +209,8 @@ class VariantDispatcher:
         fn = self._sharded_fns.get(cache_key)
         if fn is None:
             fn = jax.jit(self.shards.wrap(
-                functools.partial(variant.fn, **self.options), len(key)))
+                functools.partial(variant.fn, **self.options), len(key)),
+                out_shardings=Format(Layout.AUTO, self.shards.sharding))
             self._sharded_fns[cache_key] = fn
         return variant, fn
 

@@ -19,10 +19,14 @@ The six spans are leaves: none contains another, so their totals add.
                       finite admission scan, enqueue, the tuner's note
   serve.mux.stack     ``SolverMux._begin``: variant resolve, rider
                       embedding, one ``np.stack`` per argument, filler
-                      padding (``pad_group``)
+                      padding (``pad_group``); on a mesh, the launch's
+                      placement and the cost it holds on its shard
   serve.core.copy_in  ``EngineCore._timed_call``: each padded plane to a
-                      device array (``jnp.asarray``, ``device_put`` when
-                      the launch is placed on a shard)
+                      device array (``jnp.asarray``; on a mesh,
+                      ``jax.device_put`` straight from the host, to the
+                      placed shard's device or, for a spanning launch,
+                      to every shard's slab through the mesh's
+                      ``NamedSharding``)
   serve.core.execute  the call of the jitted entry point until it
                       returns (dispatch, not the kernel's completion),
                       and the start of the answer's copy back to the host
@@ -36,12 +40,13 @@ The six spans are leaves: none contains another, so their totals add.
 
 A launch's three ``serve.core`` spans lie inside the wall that
 ``LaunchRecord.measured`` takes, so together they come to that wall.
-They do not always run back to back.  In a bucket flush of several lane
-groups (``SolverMux._launch_chunks``) launch k+1's stack, copy_in and
+They do not always run back to back.  In a bucket flush of several
+launches (``SolverMux._launch_chunks``: lane groups, or on a mesh
+spanning units and per-shard groups) launch k+1's stack, copy_in and
 execute come before launch k's copy_out and finish, so k's copy back
 lands while the host prepares k+1; ``measured`` leaves k+1's steps out.
-A flush of one launch, a mux with a fault injector or a mesh, and the
-overload policy's rounds keep the order above, launch by launch.
+A flush of one launch, a mux with a fault injector, and the overload
+policy's rounds keep the order above, launch by launch.
 
 To see them for a running server::
 

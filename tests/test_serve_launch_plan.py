@@ -6,8 +6,9 @@ group last, padded to ``lanes`` as before.  Each lane's arithmetic, the
 filler and the order of the jobs are those of one group a launch, so the
 answers are bit-equal; only the number of launches changes.  A failed
 merged launch decomposes into its groups, each of which runs the one-group
-ladder.  The serial paths (fault injector, mesh, overload policy) keep one
-group a launch.
+ladder.  The serial paths (fault injector, overload policy) keep one group
+a launch; on a mesh the spanning units go as ``launch_plan`` and the groups
+left over one a launch, each on its own shard.
 """
 import jax
 import numpy as np
@@ -195,7 +196,14 @@ def test_injector_and_policy_keep_one_group_a_launch():
 @pytest.mark.skipif(len(jax.devices()) < 2,
                     reason="mesh tests need the 8-virtual-device session")
 def test_mesh_keeps_one_group_a_launch():
-    mux, jobs = _serve(_chol(12), mesh_size=2)
-    assert [j.state for j in jobs] == ["done"] * 12
-    assert {lr.groups for lr in mux.metrics().launches} == {1}
-    assert max(lr.real + lr.padded for lr in mux.metrics().launches) == 4
+    """Per shard, a mesh still launches one group at a time: 18 jobs on
+    2 lanes and 2 shards are 4 spanning units, sent as ``launch_plan(4)``
+    (two launches of 2 units, 2 groups a shard), then the group left over
+    on one shard."""
+    mux, jobs = _serve(_chol(18), mesh_size=2)
+    assert [j.state for j in jobs] == ["done"] * 18
+    assert [(lr.mesh, lr.groups, lr.real + lr.padded)
+            for lr in mux.metrics().launches] == [(2, 2, 8), (2, 2, 8),
+                                                  (1, 1, 2)]
+    # the spanning launches hold 4 lanes a shard, the per-shard one 2
+    assert {lr.shard for lr in mux.metrics().launches} == {-1, 0}

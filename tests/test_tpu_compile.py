@@ -162,3 +162,43 @@ def test_served_mmse_split_entry_point_holds_one_kernel_op(one_chip, lanes):
     assert re.search(r'%mmse_split\.\d+ = f32\[' + str(lanes)
                      + r',32,144\][^\n]*custom_call_target="tpu_custom_call"',
                      naming[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(one_chip):
+    """The described v5e:2x2 host's four chips as a ``SolverMux`` lane
+    mesh."""
+    from jax.experimental import topologies
+    from jax.sharding import Mesh
+
+    from repro.serve import LaneShards
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    return LaneShards(Mesh(np.asarray(topo.devices[:4]), ("data",)))
+
+
+@pytest.mark.parametrize("lanes", (8, 32, 256))
+def test_spanning_mmse_split_entry_point_holds_one_kernel_op(four_chips,
+                                                             lanes):
+    """The NR bucket's mesh-spanning entry point on four chips, at the
+    lanes a chip of the four-carrier cell's launches (one unit, and the
+    plan's 4 and 32 units): each chip's program names the kernel as the
+    one-chip program does, and no relayout copy of the answer takes its
+    name, so the benchmark's device trace finds one kernel op."""
+    from repro.serve.solver import VariantDispatcher
+    spec = K.get("mmse_equalize")
+    key = tuple((s, "float32") for s in NR_SHAPES)
+    variant, fn = VariantDispatcher(
+        spec, {"interpret": False, "sigma2": 0.1},
+        shards=four_chips).resolve_sharded(key)
+    assert variant.name == "split_complex"
+    args = [jax.ShapeDtypeStruct((4 * lanes, *s), np.float32,
+                                 sharding=four_chips.sharding)
+            for s in NR_SHAPES]
+    text = fn.lower(*args).compile().as_text()
+    naming = [line for line in text.splitlines()
+              if "mmse_split" in line and " = " in line]
+    assert len(naming) == 1, naming
+    assert re.search(r'%mmse_split\.1 = f32\[' + str(lanes)
+                     + r',32,144\][^\n]*custom_call_target="tpu_custom_call"',
+                     naming[0])
